@@ -1,7 +1,7 @@
 """tpulint — JAX/TPU-aware static analysis for this tree, whole-program.
 
 Three rule families, all distilled from bugs this repo actually shipped
-(VERDICT.md) or could only catch probabilistically at runtime:
+or could only catch probabilistically at runtime:
 
 - ``TPU1xx`` (rules_jax, rules_sharding): closure-captured arrays in
   jitted programs, host syncs inside traced functions, import-time

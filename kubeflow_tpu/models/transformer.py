@@ -932,7 +932,14 @@ def _build(name: str, **overrides):
             cfg_kw[k] = overrides.pop(k)
     if overrides:
         raise ValueError(f"unknown transformer kwargs {sorted(overrides)}")
-    return TransformerLM(TransformerConfig(**cfg_kw))
+    cfg = TransformerConfig(**cfg_kw)
+    # "auto" is settled here, once per model and in the log, not layer
+    # by layer at trace time
+    from kubeflow_tpu.ops.attention import resolve_impl
+
+    cfg = dataclasses.replace(cfg, attention_impl=resolve_impl(
+        cfg.attention_impl, cfg.head_dim, who=name))
+    return TransformerLM(cfg)
 
 
 @register_model("transformer-test")

@@ -3,9 +3,13 @@
 The reference platform never owns attention math (it ships TF images);
 for the TPU build it is in-scope. `attention()` routes to:
 
-- the Pallas flash-attention kernel on TPU (fused, O(L) memory, MXU-tiled);
+- the Pallas flash-attention kernel on TPU (fused, O(L) memory, MXU-tiled),
+  inside `jax.shard_map` when the ambient mesh has more than one device;
 - a plain XLA einsum path elsewhere (tests on the virtual CPU mesh) and
-  for shapes the kernel doesn't support.
+  for head sizes the kernel doesn't tile.
+
+`impl="auto"` is resolved by `resolve_impl`, by one rule (backend and
+head size), and it logs its choice.
 
 All shapes are [batch, length, heads, head_dim] ("BLHD"), GQA supported by
 passing fewer KV heads than Q heads.
@@ -14,9 +18,15 @@ passing fewer KV heads than Q heads.
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, PartitionSpec as P
+
+from kubeflow_tpu.parallel.mesh import AXIS_MODEL, BATCH_AXES, current_mesh
+
+log = logging.getLogger("kubeflow_tpu.attention")
 
 
 def _repeat_kv(k: jax.Array, num_q_heads: int) -> jax.Array:
@@ -63,9 +73,88 @@ def reference_attention(
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+FLASH_HEAD_DIMS = (64, 128, 256)
+
+
+def resolve_impl(impl: str, head_dim: int, who: str = "attention") -> str:
+    """Turn ``auto`` into ``flash`` or ``reference`` and log which, with
+    the reason: the flash kernel on a TPU backend for the head sizes it
+    tiles, the reference path (whose [B, H, L, L] float32 scores do not
+    fit at training lengths) otherwise. That is the whole rule: a
+    sequence length the kernel cannot tile raises in `flash_attention`
+    rather than quietly taking the reference path. Other values pass
+    through."""
+    if impl != "auto":
+        return impl
+    backend = jax.default_backend()
+    if backend != "tpu":
+        choice, why = "reference", f"default backend is {backend!r}, not tpu"
+    elif head_dim not in FLASH_HEAD_DIMS:
+        choice, why = "reference", (
+            f"head_dim {head_dim} not in {FLASH_HEAD_DIMS}")
+    else:
+        choice, why = "flash", f"tpu backend, head_dim {head_dim}"
+    log.info("%s: attention impl auto -> %s (%s)", who, choice, why)
+    return choice
+
+
+def mesh_head_axis(mesh: Mesh, n_heads: int) -> str | None:
+    """The mesh axis attention heads shard over inside a shard_map:
+    `model` when it is wider than 1 and divides the heads, else None
+    (heads replicated)."""
+    model_size = mesh.shape.get(AXIS_MODEL, 1)
+    return AXIS_MODEL if model_size > 1 and n_heads % model_size == 0 else None
+
+
 @functools.partial(jax.jit,
                    static_argnames=("causal", "impl", "block_q", "block_k",
                                     "window"))
+def local_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    *,
+    causal: bool = True,
+    impl: str,
+    segment_ids: jax.Array | None = None,
+    block_q: int = 0,
+    block_k: int = 0,
+    window: int = 0,
+) -> jax.Array:
+    """Attention over arrays that live on ONE device (or inside a
+    shard_map body). impl: flash | reference — `auto` is settled by the
+    caller (`resolve_impl`), before any shard_map is entered.
+
+    segment_ids (sequence-packing masks) run through the Pallas kernel
+    too — the reference path's [B, H, L, L] scores are unusable at
+    training lengths.
+    """
+    if impl == "reference":
+        return reference_attention(q, k, v, causal=causal,
+                                   segment_ids=segment_ids, window=window)
+    if impl != "flash":
+        raise ValueError(f"unknown attention impl {impl!r} (flash|reference; "
+                         "auto is for attention() or the model config)")
+    import os
+
+    from kubeflow_tpu.ops.flash_attention import (
+        DEFAULT_BLOCK_Q,
+        DEFAULT_BLOCK_K,
+        flash_attention,
+    )
+
+    # kernel tile sizes: explicit args win (config-plumbed operating
+    # points), else the env override (autotuning sweeps set it per
+    # subprocess; read at trace time), else the default
+    bq = block_q or int(os.environ.get("KFTPU_FLASH_BLOCK_Q",
+                                       DEFAULT_BLOCK_Q))
+    bk = block_k or int(os.environ.get("KFTPU_FLASH_BLOCK_K",
+                                       DEFAULT_BLOCK_K))
+    return flash_attention(q, k, v, causal=causal,
+                           block_q=bq, block_k=bk,
+                           segment_ids=segment_ids, window=window)
+
+
 def attention(
     q: jax.Array,
     k: jax.Array,
@@ -78,40 +167,41 @@ def attention(
     block_k: int = 0,
     window: int = 0,
 ) -> jax.Array:
-    """Dispatching attention. impl: auto | flash | reference.
+    """Dispatching attention for the model. impl: auto | flash | reference.
 
-    segment_ids (sequence-packing masks) run through the Pallas kernel
-    too — the reference path's [B, H, L, L] scores are unusable at
-    training lengths (58 GB at seq 2048, BASELINE.md round 2).
+    Under an ambient mesh with more than one device the flash kernel
+    runs inside `jax.shard_map`: a Mosaic kernel is opaque to GSPMD
+    ("Mosaic kernels cannot be automatically partitioned"), so each
+    device gets its batch rows (over BATCH_AXES) and its heads (over
+    `model`, where they divide) and runs the kernel on that block. The
+    sequence is whole on every device: a `seq` axis wider than 1 costs
+    an all-gather here, which is what ring/ulysses exist to avoid. The
+    reference path is plain jnp and is left to GSPMD.
+
+    `auto` reaches here only from callers that did not go through the
+    model registry, which settles it at build (`resolve_impl`).
     """
-    if impl == "reference":
-        return reference_attention(q, k, v, causal=causal,
-                                   segment_ids=segment_ids, window=window)
-    on_tpu = jax.devices()[0].platform == "tpu"
-    if impl == "flash" or (impl == "auto" and on_tpu and _flash_supported(q, k)):
-        import os
+    impl = resolve_impl(impl, q.shape[-1])
+    local = functools.partial(local_attention, causal=causal, impl=impl,
+                              block_q=block_q, block_k=block_k, window=window)
+    mesh = current_mesh()
+    if impl != "flash" or mesh is None or mesh.size == 1:
+        return local(q, k, v, segment_ids=segment_ids)
+    head_axis = mesh_head_axis(mesh, q.shape[2])
+    if head_axis and k.shape[2] % mesh.shape[AXIS_MODEL]:
+        # GQA with fewer KV heads than `model` is wide: repeat them up
+        # to the Q heads so all three operands shard alike
+        k = _repeat_kv(k, q.shape[2])
+        v = _repeat_kv(v, q.shape[2])
+    qkv_spec = P(BATCH_AXES, None, head_axis, None)
+    args, in_specs = (q, k, v), (qkv_spec,) * 3
+    if segment_ids is not None:
+        args, in_specs = args + (segment_ids,), in_specs + (P(BATCH_AXES, None),)
 
-        from kubeflow_tpu.ops.flash_attention import (
-            DEFAULT_BLOCK_Q,
-            DEFAULT_BLOCK_K,
-            flash_attention,
-        )
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
+                       out_specs=qkv_spec, check_vma=False)
+    def _sharded(q_blk, k_blk, v_blk, *maybe_seg):
+        return local(q_blk, k_blk, v_blk,
+                     segment_ids=maybe_seg[0] if maybe_seg else None)
 
-        # kernel tile sizes: explicit args win (config-plumbed operating
-        # points), else the env override (autotuning sweeps set it per
-        # subprocess; read at trace time), else the swept default
-        bq = block_q or int(os.environ.get("KFTPU_FLASH_BLOCK_Q",
-                                           DEFAULT_BLOCK_Q))
-        bk = block_k or int(os.environ.get("KFTPU_FLASH_BLOCK_K",
-                                           DEFAULT_BLOCK_K))
-        return flash_attention(q, k, v, causal=causal,
-                               block_q=bq, block_k=bk,
-                               segment_ids=segment_ids, window=window)
-    return reference_attention(q, k, v, causal=causal,
-                               segment_ids=segment_ids, window=window)
-
-
-def _flash_supported(q: jax.Array, k: jax.Array) -> bool:
-    # kernel wants seq multiples of its block size and head_dim % 128 == 0
-    d = q.shape[-1]
-    return q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0 and d in (64, 128, 256)
+    return _sharded(*args)

@@ -19,39 +19,51 @@ The hot op of the transformer path, built for the MXU:
   O(BLOCK_Q x BLOCK_K); all matmuls on the MXU in f32. A blockwise XLA
   backward (`_flash_bwd_xla`) remains as the differential-test oracle.
 
-On non-TPU platforms the kernel runs in Pallas interpret mode (tests on
-the virtual CPU mesh exercise the same code path).
+Off the TPU the kernel runs in Pallas interpret mode (tests on the
+virtual CPU mesh exercise the same code path). That choice is made once
+per process, in `interpret_mode()`, and logged.
 """
 
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu is importable on CPU builds too; guard for safety
-    from jax.experimental.pallas import tpu as pltpu
+log = logging.getLogger("kubeflow_tpu.flash_attention")
 
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
-
-# Hardware-swept defaults (BASELINE.md round 3): on a v5e, 512x512
-# blocks more than double train MFU vs 128x128 (llama-1b bs16 seq2048:
-# 0.227 -> 0.467) — bigger blocks amortize the per-block HBM re-reads of
-# K/V across 4x more MXU work and still fit VMEM comfortably. Blocks
-# clamp to the sequence length, so short-seq callers are unaffected;
-# override per-run with KFTPU_FLASH_BLOCK_Q/K.
+# 512x512 blocks amortize the per-block HBM re-reads of K/V across 4x
+# more MXU work than 128x128 and still fit VMEM comfortably; what that
+# buys in step time is not measured on the chip. Blocks clamp to the
+# sequence length, so short-seq callers are unaffected; override per-run
+# with KFTPU_FLASH_BLOCK_Q/K.
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30
 
+# None = not decided yet: the first flash_attention() call decides from
+# the default backend and logs it. A test that lowers for the TPU from a
+# CPU host sets False before tracing.
+INTERPRET: bool | None = None
 
-def _interpret_default() -> bool:
-    return jax.default_backend() not in ("tpu",)
+
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run interpreted (plain jnp, any
+    backend) or compiled by Mosaic (TPU only). Decided once per process."""
+    global INTERPRET
+    if INTERPRET is None:
+        backend = jax.default_backend()
+        INTERPRET = backend != "tpu"
+        if INTERPRET:
+            log.warning(
+                "flash attention: default backend is %r, not tpu; the Pallas "
+                "kernels run in INTERPRET mode (correct, slow, not the "
+                "compiled kernel)", backend)
+    return INTERPRET
 
 
 def _vmem_spec(shape, imap) -> "pl.BlockSpec":
@@ -247,11 +259,6 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
         block_q=block_q, block_k=block_k, offset=offset, has_seg=has_seg,
         window=window, nk_total=nk, pruned=pruned,
     )
-    if not _HAS_PLTPU:
-        raise ImportError(
-            "jax.experimental.pallas.tpu unavailable in this JAX build; "
-            "use attention(impl='reference') instead of the flash kernel"
-        )
     scratch = [
         pltpu.VMEM((block_q, 1), jnp.float32),   # running max
         pltpu.VMEM((block_q, 1), jnp.float32),   # running sum
@@ -575,19 +582,18 @@ def _flash_bwd_xla(q, k, v, out, lse, g, scale, causal, block_k):
 # qseg/kseg are None (empty pytrees) on the unsegmented hot path —
 # has_seg resolves statically at trace time, so the compiled kernel is
 # bit-identical to the pre-segments one.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _flash(q, k, v, qseg, kseg, scale, causal, block_q, block_k, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _flash(q, k, v, qseg, kseg, scale, causal, block_q, block_k, window,
+           interpret):
     out, _ = _flash_fwd(q, k, v, scale, causal, block_q, block_k,
-                        _interpret_default(), qseg=qseg, kseg=kseg,
-                        window=window)
+                        interpret, qseg=qseg, kseg=kseg, window=window)
     return out
 
 
 def _flash_vjp_fwd(q, k, v, qseg, kseg, scale, causal, block_q, block_k,
-                   window):
+                   window, interpret):
     out, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k,
-                          _interpret_default(), qseg=qseg, kseg=kseg,
-                          window=window)
+                          interpret, qseg=qseg, kseg=kseg, window=window)
     # jax.checkpoint partial-eval looks THROUGH custom_vjp fwd rules, so
     # these residuals are policy-visible equations: naming them lets a
     # remat policy keep exactly (out, lse) — and with q/k/v anchored by
@@ -601,13 +607,14 @@ def _flash_vjp_fwd(q, k, v, qseg, kseg, scale, causal, block_q, block_k,
     return out, (q, k, v, qseg, kseg, out_r, lse_r)
 
 
-def _flash_vjp_bwd(scale, causal, block_q, block_k, window, res, g):
+def _flash_vjp_bwd(scale, causal, block_q, block_k, window, interpret, res,
+                   g):
     import numpy as np
 
     q, k, v, qseg, kseg, out, lse = res
     dq, dk, dv = _flash_bwd_pallas(
         q, k, v, out, lse, g, scale, causal, block_q, block_k,
-        _interpret_default(), qseg=qseg, kseg=kseg, window=window)
+        interpret, qseg=qseg, kseg=kseg, window=window)
     # integer segment ids take float0 cotangents (None stays None)
     zero = lambda a: (None if a is None  # noqa: E731
                       else np.zeros(a.shape, jax.dtypes.float0))
@@ -686,5 +693,5 @@ def flash_attention(
         kseg = jnp.repeat(kv_segment_ids.astype(jnp.int32)[:, None], h, axis=1
                           ).reshape(b * h, 1, lk)
     out = _flash(qt, kt, vt, qseg, kseg, scale, causal, block_q, block_k,
-                 window)
+                 window, interpret_mode())
     return out.reshape(b, h, lq, d).transpose(0, 2, 1, 3)

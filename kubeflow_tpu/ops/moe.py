@@ -223,7 +223,7 @@ class MoEBlock(nn.Module):
         ce = assign_pre.sum(axis=2).mean(axis=(0, 1))
         aux = e * jnp.sum(me * ce)
         self.sow("losses", "moe_aux", aux)
-        # dispatch diagnostics (VERDICT r3 #5): how much of the capacity
+        # dispatch diagnostics: how much of the capacity
         # buffer is padding, and how much routing overflowed. `slots` is
         # reported by the path that allocated them — the sparse path's
         # per-shard capacity (cf*t_local*k/e) rounds differently from the

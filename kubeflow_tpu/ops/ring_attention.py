@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from kubeflow_tpu.parallel.mesh import AXIS_MODEL, AXIS_SEQ, BATCH_AXES
+from kubeflow_tpu.parallel.mesh import AXIS_SEQ, BATCH_AXES
 
 NEG_INF = -1e30
 
@@ -121,8 +121,9 @@ def ring_attention(
         assert h % k.shape[2] == 0, (h, k.shape[2])
         k = jnp.repeat(k, h // k.shape[2], axis=2)
         v = jnp.repeat(v, h // v.shape[2], axis=2)
-    model_size = mesh.shape.get(AXIS_MODEL, 1) if AXIS_MODEL in mesh.axis_names else 1
-    head_axis = AXIS_MODEL if h % max(model_size, 1) == 0 and model_size > 1 else None
+    from kubeflow_tpu.ops.attention import mesh_head_axis
+
+    head_axis = mesh_head_axis(mesh, h)
     qkv_spec = P(BATCH_AXES, axis_name, head_axis, None)
     seg_spec = P(BATCH_AXES, axis_name)
     has_seg = segment_ids is not None

@@ -80,13 +80,18 @@ def ulysses_attention(
         k = jnp.repeat(k, h // k.shape[2], axis=2)
         v = jnp.repeat(v, h // v.shape[2], axis=2)
 
-    model_size = mesh.shape.get(AXIS_MODEL, 1) if AXIS_MODEL in mesh.axis_names else 1
-    head_axis = AXIS_MODEL if h % max(model_size, 1) == 0 and model_size > 1 else None
-    h_local = h // model_size if head_axis else h
+    from kubeflow_tpu.ops.attention import (
+        local_attention, mesh_head_axis, resolve_impl,
+    )
+
+    impl = resolve_impl(impl, q.shape[-1], who="ulysses")
+    head_axis = mesh_head_axis(mesh, h)
+    h_local = h // mesh.shape[AXIS_MODEL] if head_axis else h
     if h_local % sp != 0:
         raise ValueError(
             f"ulysses needs heads-per-device {h_local} divisible by "
-            f"seq-axis size {sp} (H={h}, model={model_size})"
+            f"seq-axis size {sp} (H={h}, "
+            f"model={mesh.shape.get(AXIS_MODEL, 1)})"
         )
     assert q.shape[1] % sp == 0, (q.shape, sp)
 
@@ -117,11 +122,10 @@ def ulysses_attention(
             seg_full = jax.lax.all_gather(
                 maybe_seg[0], axis_name, axis=1, tiled=True)
 
-        from kubeflow_tpu.ops.attention import attention
-
-        out = attention(q_g, k_g, v_g, causal=causal, impl=impl,
-                        segment_ids=seg_full,
-                        block_q=block_q, block_k=block_k, window=window)
+        # already inside a shard_map: the single-device dispatch
+        out = local_attention(q_g, k_g, v_g, causal=causal, impl=impl,
+                              segment_ids=seg_full, block_q=block_q,
+                              block_k=block_k, window=window)
 
         # [b, L, h_loc/sp, d] -> [b, L/sp, h_loc, d]: scatter sequence,
         # gather heads.

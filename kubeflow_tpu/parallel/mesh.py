@@ -140,9 +140,11 @@ def build_mesh(
 ) -> Mesh:
     """Build a `jax.sharding.Mesh` from a logical spec.
 
-    Uses `mesh_utils.create_device_mesh` so the physical assignment follows
-    the slice's ICI topology (it understands TPU coords); falls back to a
-    plain reshape for CPU/interpreter devices.
+    Uses `mesh_utils.create_device_mesh`: on TPU devices the physical
+    assignment follows the slice's ICI topology (it understands TPU
+    coords) and an impossible shape raises; on CPU devices it is a plain
+    reshape, which keeps the dcn axis outermost — the contiguous-rank
+    layout the JAXJob controller assigns slices by.
     """
     if devices is None:
         devices = jax.devices()
@@ -166,14 +168,8 @@ def build_mesh(
         dev_array = mesh_utils.create_hybrid_device_mesh(
             ici_shape, dcn_shape, devices=dev_np)
         return Mesh(dev_array, _AXIS_ORDER)
-    try:
-        dev_array = mesh_utils.create_device_mesh(shape, devices=dev_np)
-    except (ValueError, AssertionError, NotImplementedError):
-        # CPU/interpreter devices (no slice topology): plain reshape keeps
-        # the dcn axis outermost, which is exactly the contiguous-rank
-        # layout the JAXJob controller assigns slices by
-        dev_array = dev_np.reshape(shape)
-    return Mesh(dev_array, _AXIS_ORDER)
+    return Mesh(mesh_utils.create_device_mesh(shape, devices=dev_np),
+                _AXIS_ORDER)
 
 
 def batch_spec(mesh: Mesh, extra_dims: int = 0) -> P:

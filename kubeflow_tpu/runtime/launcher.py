@@ -9,8 +9,11 @@ This launcher:
   - decodes JAXJOB_* env (parallel/dist.py) and joins the jax.distributed
     cluster, with a TCP readiness gate on the coordinator instead of
     sleep-based ordering;
-  - waits for TPU devices to be visible (the libtpu analogue of the
-    openmpi sidecar's /proc/driver/nvidia/version poll, controller.py:73-90);
+  - with --wait-devices PLATFORM, waits for devices of that platform to
+    be visible (the libtpu analogue of the openmpi sidecar's
+    /proc/driver/nvidia/version poll, controller.py:73-90) and exits
+    EX_UNAVAILABLE (69) if none appear — CPU devices never stand in
+    for an accelerator;
   - runs either a built-in trainer (--config JSON/YAML → TrainConfig) or a
     user command;
   - exits 0 on success, 1 on failure, and EX_TEMPFAIL (75) when a
@@ -38,21 +41,29 @@ from kubeflow_tpu.obs import trace as obs_trace
 log = logging.getLogger("kubeflow_tpu.launcher")
 
 
-def wait_for_devices(timeout_s: float = 300.0, expect_platform: str | None = None) -> int:
-    """Block until jax sees accelerator devices (libtpu ready)."""
+# sysexits.h "service unavailable": the platform asked for is not there
+EX_UNAVAILABLE = 69
+
+
+def wait_for_devices(platform: str, timeout_s: float = 300.0) -> int:
+    """Block until jax sees devices of `platform` (e.g. "tpu": libtpu
+    ready). Devices of another platform never satisfy the wait — in
+    particular not the CPU devices jax.devices() falls back to when no
+    chip is found. Raises TimeoutError carrying JAX's own reason."""
     import jax
 
     deadline = time.monotonic() + timeout_s
     while True:
         try:
-            devs = jax.devices(expect_platform) if expect_platform else jax.devices()
-            if devs:
-                log.info("devices ready: %d x %s", len(devs), devs[0].device_kind)
-                return len(devs)
-        except RuntimeError:
-            pass
-        if time.monotonic() > deadline:
-            raise TimeoutError(f"no {expect_platform or 'accelerator'} devices after {timeout_s}s")
+            devs = jax.devices(platform)
+            log.info("devices ready: %d x %s (%s)", len(devs),
+                     devs[0].device_kind, platform)
+            return len(devs)
+        except RuntimeError as e:  # backend absent or failed to initialize
+            why = str(e).splitlines()[0]
+        if time.monotonic() >= deadline:
+            raise TimeoutError(
+                f"no {platform} devices after {timeout_s:g}s: {why}")
         time.sleep(2.0)
 
 
@@ -150,8 +161,10 @@ def main(argv: list[str] | None = None) -> int:
 
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--config", help="TrainConfig JSON/YAML for the built-in trainer")
-    p.add_argument("--wait-devices", action="store_true",
-                   help="block until accelerator devices are visible before starting")
+    p.add_argument("--wait-devices", metavar="PLATFORM",
+                   help="block until devices of this platform (e.g. tpu) are "
+                        "visible before starting; exit 69 if none appear "
+                        "within --device-timeout")
     p.add_argument("--device-timeout", type=float, default=300.0)
     args = p.parse_args(argv)
 
@@ -160,16 +173,11 @@ def main(argv: list[str] | None = None) -> int:
         format="%(asctime)s %(name)s %(levelname)s %(message)s",
     )
 
-    # Honor JAX_PLATFORMS even when a sitecustomize imported jax before this
-    # process's env was consulted (jax snapshots the var at import time).
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        import jax
-
-        jax.config.update("jax_platforms", plat)
-
     from kubeflow_tpu.parallel import backends as B
     from kubeflow_tpu.parallel import dist as D
+    from kubeflow_tpu.utils import compile_cache
+
+    log.info("compile cache: %s", compile_cache.configure())
 
     log.info("collectives backend: %s", B.get_backend().name)
 
@@ -206,7 +214,11 @@ def main(argv: list[str] | None = None) -> int:
         log.info("process %d/%d (job=%s)", cfg.process_id, cfg.num_processes, cfg.job_name or "-")
 
     if args.wait_devices:
-        wait_for_devices(args.device_timeout)
+        try:
+            wait_for_devices(args.wait_devices, args.device_timeout)
+        except TimeoutError as e:
+            log.error("%s", e)
+            return EX_UNAVAILABLE
 
     if args.config:
         # On-demand xprof capture server (JAXRT_PROFILER_PORT) so

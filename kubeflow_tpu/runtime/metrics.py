@@ -25,7 +25,6 @@ PEAK_FLOPS = {
     "TPU v6 lite": 918e12,
     "TPU v6e": 918e12,
 }
-_DEFAULT_PEAK = 197e12
 
 # Peak HBM bandwidth per chip (bytes/s), same doc tables (v4: 1.2TB/s,
 # v5e: 819GB/s, v5p: 2.77TB/s, v6e: 1.64TB/s). Drives the roofline
@@ -38,22 +37,41 @@ PEAK_HBM_BW = {
     "TPU v6 lite": 1.64e12,
     "TPU v6e": 1.64e12,
 }
-_DEFAULT_BW = 819e9
 
 
-def _lookup(table: dict, device_kind: str, default: float) -> float:
+class UnknownDeviceError(LookupError):
+    """A device_kind with no entry in the peak tables. A utilization
+    against some other chip's peak is not a utilization."""
+
+
+def _lookup(table: dict, device_kind: str) -> float:
     for prefix, val in sorted(table.items(), key=lambda kv: -len(kv[0])):
         if device_kind.startswith(prefix):
             return val
-    return default
+    raise UnknownDeviceError(
+        f"no published peak for device_kind {device_kind!r} "
+        f"(known: {sorted(table)})")
 
 
 def peak_flops(device_kind: str) -> float:
-    return _lookup(PEAK_FLOPS, device_kind, _DEFAULT_PEAK)
+    return _lookup(PEAK_FLOPS, device_kind)
 
 
 def peak_hbm_bw(device_kind: str) -> float:
-    return _lookup(PEAK_HBM_BW, device_kind, _DEFAULT_BW)
+    return _lookup(PEAK_HBM_BW, device_kind)
+
+
+def device_info(devices=None) -> dict:
+    """`devices` (default: every device this process sees) as JAX names
+    them — stamped on every summary and result so a number can never be
+    read apart from the platform that produced it."""
+    if devices is None:
+        import jax
+
+        devices = jax.devices()
+    devs = list(devices)
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
 
 
 class StepMeter:
@@ -68,7 +86,12 @@ class StepMeter:
                  tracer=None, span_name: str = "train.step", step_base: int = 0):
         self.flops_per_step = float(flops_per_step)
         self.n_chips = max(1, n_chips)
-        self.peak = peak_flops(device_kind) * self.n_chips if device_kind else None
+        # no peak (mfu = nan) for a device the tables do not know: the CPU
+        # of a test run, or a chip nobody has entered yet
+        try:
+            self.peak = peak_flops(device_kind) * self.n_chips
+        except UnknownDeviceError:
+            self.peak = None
         self._times: deque[float] = deque(maxlen=window)
         self._t0: float | None = None
         self.steps = 0

@@ -161,7 +161,7 @@ def make_optimizer(cfg: TrainConfig) -> optax.GradientTransformation:
         # The TPU-native memory-light optimizer (T5 lineage): second moment
         # factored into row+col statistics, so optimizer state is ~0 bytes
         # per param instead of 8 — what lets llama-1b-class models train on
-        # a single 16 GB v5e chip (BASELINE.md round-2 note).
+        # a single 16 GB v5e chip.
         return optax.adafactor(
             learning_rate=sched,
             multiply_by_parameter_scale=True,
@@ -376,6 +376,37 @@ class Trainer:
             lambda _: batch_sharding(mesh), self._example_batch()
         )
 
+        # The train state's layout, decided once: init_state builds to it,
+        # the train step is pinned to return it, and an ahead-of-time
+        # compile (tools/aot_tpu.py) traces with it. The optimizer state
+        # is built from zeros, so nothing ties it to the params' devices:
+        # left to itself jit puts ALL of it on the first device, and a
+        # model that needs fsdp to fit dies there. Moments shaped like
+        # their param take that param's sharding; the rest (counts,
+        # factored statistics) is small and replicated.
+        def sds(tree, shardings):
+            return jax.tree.map(
+                lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+                tree, shardings)
+
+        rep = NamedSharding(mesh, P())
+        variables = sds(unbox(abstract), self.var_shardings)
+        opt_abstract = jax.eval_shape(self.tx.init, variables["params"])
+        self.abstract_state = TrainState(
+            step=jax.ShapeDtypeStruct((), jnp.int32, sharding=rep),
+            params=variables["params"],
+            batch_stats=variables.get("batch_stats", {}),
+            opt_state=sds(opt_abstract, optax.tree_utils.tree_map_params(
+                self.tx,
+                lambda leaf, p: p.sharding if leaf.shape == p.shape else rep,
+                opt_abstract, variables["params"],
+                transform_non_params=lambda _: rep)),
+            tx=self.tx)
+        self.abstract_batch = sds(jax.eval_shape(self._example_batch),
+                                  self.batch_shardings)
+        self.state_shardings = jax.tree.map(lambda a: a.sharding,
+                                            self.abstract_state)
+
         # Positional-only closure so jax.checkpoint sees pure pytree args
         # (it rejects string kwargs like mutable=[...]). seg is the
         # optional [B, L] sequence-packing ids (LM batches only) — the
@@ -567,8 +598,14 @@ class Trainer:
             return _apply_update(state, grads, new_stats,
                                  loss_sum / n, acc_sum / n, diag)
 
+        # out_shardings: the new state comes back laid out as it went in.
+        # Left to the compiler it re-shards what it likes (on a v5e 2x2
+        # it spreads adafactor's row statistics over fsdp), step 2 then
+        # arrives with other shardings than step 1 — a new cache key —
+        # and the whole step compiles a second time.
         self._train_step = jax.jit(
-            train_step_accum if accum > 1 else train_step, donate_argnums=(0,))
+            train_step_accum if accum > 1 else train_step, donate_argnums=(0,),
+            out_shardings=(self.state_shardings, None))
 
         def eval_step(state: TrainState, batch):
             variables = {"params": state.params,
@@ -597,12 +634,16 @@ class Trainer:
             variables = self._init_jit(rng)
         params = variables["params"]
         batch_stats = variables.get("batch_stats", {})
-        opt_state = jax.jit(
-            self.tx.init,
-        )(params)
+        # the step counter is made on the mesh like the rest of the state:
+        # an uncommitted leaf comes back committed from step 1, which is a
+        # new jit cache key, and the whole train step compiles twice
+        step, opt_state = jax.jit(
+            lambda p: (jnp.zeros((), jnp.int32), self.tx.init(p)),
+            out_shardings=(self.state_shardings.step,
+                           self.state_shardings.opt_state))(params)
         log.info("model %s: %.2fM params", self.cfg.model, self.n_params / 1e6)
         return TrainState(
-            step=jnp.zeros((), jnp.int32),
+            step=step,
             params=params,
             batch_stats=batch_stats,
             opt_state=opt_state,
@@ -618,6 +659,23 @@ class Trainer:
         batch = shard_batch(batch, next(iter(jax.tree.leaves(self.batch_shardings))))
         with self.mesh:
             return self._eval_step(state, batch)
+
+    def _log_placement(self, state: TrainState, batch: dict) -> None:
+        """Say where the state and the batch really live. shard_constraint
+        is a no-op without an ambient mesh and a sharding that did not
+        take is silent, so four chips can quietly do one chip's work; the
+        per-device byte counts make that visible in the log."""
+        per_dev: dict[int, int] = {}
+        for leaf in jax.tree.leaves((state.params, state.opt_state)):
+            for sh in leaf.addressable_shards:
+                per_dev[sh.device.id] = per_dev.get(sh.device.id, 0) + sh.data.nbytes
+        x = next(iter(jax.tree.leaves(batch)))
+        log.info(
+            "placement: params+optimizer MB per device %s; batch %s over %d "
+            "devices in shards of %s",
+            {d: round(n / 2**20, 1) for d, n in sorted(per_dev.items())},
+            tuple(x.shape), len(x.sharding.device_set),
+            tuple(x.sharding.shard_shape(x.shape)))
 
     def flops_per_step(self) -> float:
         """Analytic train-step FLOPs for the MFU meter.
@@ -708,18 +766,20 @@ class Trainer:
             if ckpt:
                 ckpt.close()
             return state, {"steps": steps, "start_step": start_step,
-                           "step_time_s": None,
-                           "examples_per_sec": 0.0, "mfu": 0.0, "final": {}}
+                           "device": rt_metrics.device_info(
+                               self.mesh.devices.flat),
+                           "first_step_s": None, "step_time_s": None,
+                           "examples_per_sec": 0.0, "mfu": None, "final": {}}
 
         from kubeflow_tpu.obs import trace as obs_trace
 
         data = None
-        kind = next(iter(self.mesh.devices.flat)).device_kind
+        device = rt_metrics.device_info(self.mesh.devices.flat)
         # tracer=: each metered step emits a train.step span under the
         # ambient context — linked to the gang-admission span when the
         # launcher attached the pod's TRACEPARENT. Metering starts after
         # the compile step, hence the +1 global-step base.
-        meter = rt_metrics.StepMeter(self.flops_per_step(), self.mesh.devices.size, kind,
+        meter = rt_metrics.StepMeter(self.flops_per_step(), device["count"], device["kind"],
                                      tracer=obs_trace.TRACER,
                                      step_base=start_step + 1)
         last = {}
@@ -825,6 +885,7 @@ class Trainer:
                 trace.step(start_step + i)
                 batch = next(data)
                 if i == 0:
+                    self._log_placement(state, batch)
                     # Step 0 pays XLA compile; keep it out of the meter window
                     # so step_time/throughput/MFU reflect steady state.
                     t0 = _time.perf_counter()
@@ -851,13 +912,14 @@ class Trainer:
                     rt_metrics.REGISTRY.gauge("jaxrt_examples_per_sec",
                                               meter.throughput(cfg.global_batch),
                                               "training throughput")
-                    rt_metrics.REGISTRY.gauge("jaxrt_mfu", meter.mfu, "model FLOPs utilization")
+                    if meter.peak:  # a utilization needs a known chip
+                        rt_metrics.REGISTRY.gauge("jaxrt_mfu", meter.mfu, "model FLOPs utilization")
                     rt_metrics.REGISTRY.gauge("jaxrt_loss", last["loss"], "training loss")
                     log.info(
-                        "step %d loss=%.4f acc=%.3f %.1f ex/s step=%.1fms mfu=%.1f%%",
+                        "step %d loss=%.4f acc=%.3f %.1f ex/s step=%.1fms%s",
                         i + 1, last["loss"], last.get("accuracy", float("nan")),
                         meter.throughput(cfg.global_batch), meter.step_time * 1e3,
-                        meter.mfu * 100,
+                        f" mfu={meter.mfu * 100:.1f}%" if meter.peak else "",
                     )
                 maybe_save(start_step + i + 1, state)
                 maybe_eval(start_step + i + 1, state)
@@ -898,6 +960,10 @@ class Trainer:
         summary = {
             "steps": steps,
             "start_step": start_step,
+            "device": device,
+            # the first step pays tracing and compilation: set-up, kept
+            # apart from the steady step time below
+            "first_step_s": _finite(first_dt),
             "step_time_s": _finite(meter.step_time),
             "examples_per_sec": _finite(meter.throughput(cfg.global_batch)),
             "mfu": _finite(meter.mfu),

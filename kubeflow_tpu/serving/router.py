@@ -1,7 +1,7 @@
 """Token-aware serving router — the JAXService front door.
 
 A single replica server (``serving/server.py``) saturates at one
-decoder's throughput (BENCH_r05: 1.07 req/s); the serving plane runs N
+decoder's throughput (not measured on the chip); the serving plane runs N
 replicas behind this router. Replica choice is least-outstanding-TOKENS,
 not least-connections: decode cost scales with tokens (prompt prefill +
 requested continuation), so one 2k-token request weighs as much as

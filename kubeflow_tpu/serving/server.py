@@ -28,6 +28,7 @@ from typing import Any, Callable
 import numpy as np
 
 from kubeflow_tpu.runtime.metrics import REGISTRY as METRICS_REGISTRY
+from kubeflow_tpu.runtime.metrics import device_info
 from kubeflow_tpu.serving.router import (DeadlineExceeded, HEADER_DEADLINE,
                                          _retry_after_headers)
 from kubeflow_tpu.utils import httpd
@@ -502,7 +503,10 @@ class ModelServer:
     def metadata(self, req: HttpReq):
         m = self._get(req.params["model"])
         return {"model_spec": {"name": m.name, "version": str(m.version)},
-                "metadata": {"signature_def": m.signature}}
+                "metadata": {"signature_def": m.signature},
+                # which device answers: a client timing this server must
+                # be able to tell a chip from a CPU
+                "device": device_info()}
 
     def predict(self, req: HttpReq):
         name = req.params["model"]
@@ -1116,6 +1120,15 @@ def main() -> None:  # pragma: no cover - container entry
                         "'model=4,fsdp=2' — required for models whose "
                         "state exceeds one chip's HBM")
     args = p.parse_args()
+    logging.basicConfig(
+        level=logging.INFO,
+        format="%(asctime)s %(name)s %(levelname)s %(message)s")
+    from kubeflow_tpu.utils import compile_cache
+
+    log.info("compile cache: %s", compile_cache.configure())
+    # take the device now: a server that cannot reach its chip must die
+    # here, not answer 400s from inside the first request
+    log.info("device: %(count)d x %(kind)s (%(platform)s)" % device_info())
     mesh_spec = None
     if args.mesh:
         try:
@@ -1158,10 +1171,18 @@ def main() -> None:  # pragma: no cover - container entry
                if args.rolling_kv_cache else {})))
     svc = server.serve(port=args.port)
     log.info("serving on :%d", svc.port)
+    # SIGTERM (pod termination, chip_smoke.py) stops the listener from
+    # another thread — shutdown() blocks until serve_forever returns —
+    # so the decoders close and the process gives the chip back cleanly
+    import signal
+
+    signal.signal(signal.SIGTERM, lambda *_: threading.Thread(
+        target=svc.shutdown, name="sigterm-shutdown", daemon=True).start())
     try:
         svc.serve_forever()
     finally:
         server.close()
+    log.info("stopped")
 
 
 if __name__ == "__main__":  # pragma: no cover

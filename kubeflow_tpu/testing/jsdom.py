@@ -14,7 +14,7 @@ and a `fetch` bridged straight into a platform Router.
 
 Tests execute the REAL `<script>` payloads served by
 webapps/dashboard_ui.py and jwa_ui.py against the real backends: a test
-fails when the registration-flow JS breaks — the VERDICT #5 bar.
+fails when the registration-flow JS breaks.
 
 This is NOT a general JS engine. Unsupported syntax raises JSError at
 parse time, loudly; growing the subset is preferable to silently
@@ -87,7 +87,7 @@ class JSFunction:
 
 
 class EventLoop:
-    """Microtask + macrotask queues (VERDICT r4 weak #5: the round-3
+    """Microtask + macrotask queues (the round-3
     harness resolved promises eagerly, so `await`/`then` ordering races
     in the very fetch-then-render flows the UI tests exercise were
     untestable by construction). The harness drains at every entry point

@@ -1,4 +1,4 @@
-"""Real multi-process gang e2e (VERDICT r1 weak #4).
+"""Real multi-process gang e2e.
 
 JAXJob controller on FakeCluster + LocalPodExecutor running the worker
 pods as ACTUAL subprocesses: each joins a jax.distributed CPU world via
@@ -294,7 +294,7 @@ def make_node(name: str, ready: bool = True) -> dict:
 
 class TestSliceHealthE2E:
     def test_taint_drives_proactive_gang_restart_and_resume(self, tmp_path):
-        """VERDICT r2 weak #7: the node under a LIVE gang gets the
+        """The node under a LIVE gang gets the
         impending-TPU-maintenance taint; the controller must restart the
         gang proactively (preemption budget, not crash budget) without
         any worker dying first, the executor reschedules onto a healthy

@@ -1,6 +1,6 @@
-"""GkeTpuPlatform against an offline gcloud CLI double (VERDICT r2 weak
-#8: the one provider that touches real TPUs had no offline test of its
-gcloud contract).
+"""GkeTpuPlatform against an offline gcloud CLI double (the one
+provider that touches real TPUs had no offline test of its gcloud
+contract).
 
 The double is a real executable placed first on PATH and run through the
 provider's DEFAULT subprocess path — argv parsing, exit codes, and the

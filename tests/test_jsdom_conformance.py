@@ -5,7 +5,7 @@ kubeflow_tpu/testing/jsdom.py — a second implementation of JS semantics
 (the reference uses Selenium against real browsers,
 testing/test_jwa.py:17-24; this container has no browser). A divergence
 between this harness and a real engine is invisible to every UI test, so
-this file is the contract (VERDICT r3 #8): each test pins a spec edge
+this file is the contract: each test pins a spec edge
 case the UIs rely on, and each KNOWN DEVIATION from real-engine behavior
 is asserted AS the deviant behavior — if the harness's semantics drift,
 these tests fail loudly instead of the UI tests silently meaning
@@ -21,7 +21,7 @@ Guaranteed (spec-conformant):
     return value resolves the caller's promise; Promise chaining maps
     values through .then.
   - Promise.all resolves with ordered results.
-  - microtask queue (round 5, VERDICT r4 #7): .then callbacks defer to
+  - microtask queue (round 5): .then callbacks defer to
     the microtask checkpoint ('sync,then' order, as real engines);
     fetch settles on the macrotask queue in request order.
 
@@ -133,7 +133,7 @@ class TestAsync:
         assert b.eval("log.join(',')") == "a+b"
 
     def test_microtask_queue_defers_then(self):
-        """The regression VERDICT r4 #7 asked for: under round-4's EAGER
+        """The regression the round-4 review asked for: under round-4's EAGER
         resolution this ordered 'then,sync' and the real-engine order
         was untestable by construction; the event loop restores
         'sync,then' (script to completion, then microtask checkpoint)."""

@@ -19,7 +19,7 @@ PACKAGE = REPO / "kubeflow_tpu"
 PY_FILES = sorted(
     p for p in PACKAGE.rglob("*.py")
     if "__pycache__" not in p.parts
-) + [REPO / "bench.py", REPO / "__graft_entry__.py"]
+) + [REPO / "bench.py", REPO / "__graft_entry__.py", REPO / "chip_smoke.py"]
 
 # the test corpus and round tooling are lint-gated for the
 # syntax/marker/debugger checks (not the docstring rule: helpers and

@@ -67,7 +67,7 @@ def test_mesh_summary(devices8):
 
 
 class TestDcnAxis:
-    """Multislice: the outer `dcn` axis (VERDICT #2 / SURVEY §2.5 "DCN
+    """Multislice: the outer `dcn` axis (SURVEY §2.5 "DCN
     across slices")."""
 
     def test_dcn_in_resolve_and_batch_axes(self):

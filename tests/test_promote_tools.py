@@ -121,7 +121,7 @@ class TestPromoteBest:
         assert _load(tooldir / "lm_best.json")["mfu"] == 0.4936
 
     def test_promoted_record_drops_stale_remat_policy(self, tmp_path):
-        """Ledger hygiene (VERDICT r4 weak #4): a winning point with
+        """Ledger hygiene: a winning point with
         remat=false must not carry a remat_policy field — the knob never
         ran, and recording it invites reading the number as
         remat-verified."""

@@ -421,7 +421,7 @@ def test_list_models_inventory(server):
 
 
 class TestMeshShardedServing:
-    """VERDICT #6: a model whose params are sharded over the device mesh
+    """A model whose params are sharded over the device mesh
     (2 fsdp x 4 model on the virtual 8-device CPU mesh) answers the same
     REST contract — predict AND generate — with GSPMD inserting the
     collectives. This is the only way a model too big for one chip's HBM

@@ -26,12 +26,25 @@ def tiny_resnet_cfg(**over):
     return TrainConfig.from_dict(cfg)
 
 
-def test_resnet_dp_training_runs(devices8):
+def test_resnet_dp_training_runs(devices8, caplog):
     trainer = Trainer(tiny_resnet_cfg())
-    state, summary = trainer.fit(steps=3)
+    with caplog.at_level("INFO", logger="kubeflow_tpu.trainer"):
+        state, summary = trainer.fit(steps=3)
     assert summary["steps"] == 3
     assert jnp.isfinite(summary["final"]["loss"])
     assert int(state.step) == 3
+    # the device is named, set-up is apart from the steady step, and a
+    # CPU has no peak: no MFU in the summary, the log or the gauges
+    assert summary["device"] == {"platform": "cpu", "kind": "cpu", "count": 8}
+    assert summary["first_step_s"] > 0 and summary["step_time_s"] > 0
+    assert summary["mfu"] is None
+    assert "mfu" not in caplog.text
+    # the optimizer state is spread like the params, not left on device 0,
+    # and the state keeps its layout from step to step: one compile, not
+    # a second one when step 1's outputs come back laid out otherwise
+    assert all(len(leaf.sharding.device_set) == 8
+               for leaf in jax.tree.leaves((state.opt_state, state.step)))
+    assert trainer._train_step._cache_size() == 1
 
 
 def test_resnet_loss_decreases_on_fixed_batch(devices8):

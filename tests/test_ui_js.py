@@ -6,7 +6,7 @@ container has no browser, so kubeflow_tpu/testing/jsdom.py rebuilds the
 capability: the interpreter runs the exact `<script>` payloads served by
 dashboard_ui.py / jwa_ui.py, with fetch() bridged into the same Router
 objects production serves. Every flow below fails if the corresponding
-UI JS breaks — the VERDICT #5 bar ("a test fails when the
+UI JS breaks — the bar ("a test fails when the
 registration-flow JS breaks").
 """
 

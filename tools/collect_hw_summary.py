@@ -2,7 +2,7 @@
 
 Reads the watcher's stage outputs (tools/r{N}_stages/*.out — each holds a
 bench.py or serve_bench.py JSON line) plus the promoted
-serve_table.json, and prints markdown ready for BASELINE.md: one LM
+serve_table.json, and prints markdown: one LM
 table (model / batch / policy / MFU / tok/s), one ResNet row set, one
 serving table. Stages that never ran or failed are listed as such, so
 the ledger distinguishes "didn't fit / didn't run" from "never

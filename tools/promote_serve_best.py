@@ -1,7 +1,7 @@
 """Promote the serving sweep's best measured operating point.
 
 Same promotion discipline as promote_best.py, for the decode side
-(VERDICT r3 #4: serving numbers as a first-class ledger): parse files of
+(serving numbers as a first-class ledger): parse files of
 serve_bench.py JSON lines, keep the best CONTINUOUS-mode point per
 (model, max_new_tokens, slots, param_dtype, kv_cache_dtype) config in
 tools/serve_table.json (the A/B ledger), and write the best
@@ -59,7 +59,7 @@ def main() -> int:
         except (ValueError, OSError):
             pass
     # per-config bests (every measured geometry/dtype keeps its own row —
-    # the A/B ledger for BASELINE.md)
+    # the A/B ledger)
     table: dict = {}
     if os.path.exists(table_path):
         try:
