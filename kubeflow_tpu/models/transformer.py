@@ -905,10 +905,13 @@ class TransformerLM(nn.Module):
         return LMHead(cfg, name="lm_head")(x)
 
     def flops_per_token(self, seq_len: int | None = None) -> float:
-        """Train FLOPs per token: 6*N over the dense params, plus the
-        attention score/value matmuls when seq_len is given — per token
-        per layer that's 12*h*d_head*T (QK^T + PV, fwd+bwd), halved for
-        causal masking (the PaLM-appendix accounting)."""
+        """Train FLOPs per token as the work needs them (nothing
+        recomputed): 6*N over the weights of the matrix products (the
+        layers and the vocabulary head; the embedding is a look-up and
+        counts nothing), plus the attention score/value products when
+        seq_len is given: per token per layer 12*h*d_head*keys (QK^T +
+        PV, fwd+bwd), `keys` the mean number of keys a query of a causal
+        sequence of seq_len sees, cut off at cfg.attention_window."""
         cfg = self.cfg
         attn = cfg.d_model * cfg.head_dim * (cfg.n_heads * 2 + cfg.n_kv_heads * 2)
         mlp = 3 * cfg.d_model * cfg.d_ff          # SwiGLU: gate+up+down
@@ -916,11 +919,17 @@ class TransformerLM(nn.Module):
         n_dense = cfg.n_layers - n_moe
         # MoE layer: top_k expert MLPs execute per token, plus the router
         moe = cfg.expert_top_k * mlp + cfg.d_model * cfg.n_experts
-        emb = cfg.vocab_size * cfg.d_model
+        head = cfg.vocab_size * cfg.d_model
         flops = 6.0 * (cfg.n_layers * attn + n_dense * mlp + n_moe * moe
-                       + 2 * emb)
+                       + head)
         if seq_len:
-            flops += 12.0 * cfg.n_layers * cfg.n_heads * cfg.head_dim * seq_len / 2
+            w = cfg.attention_window
+            if w and seq_len > w:   # the first w queries see 1..w keys
+                seen = w * (w + 1) / 2 + (seq_len - w) * w
+            else:
+                seen = seq_len * (seq_len + 1) / 2
+            flops += (12.0 * cfg.n_layers * cfg.n_heads * cfg.head_dim
+                      * seen / seq_len)
         return flops
 
 

@@ -17,6 +17,13 @@ Propagation uses the W3C trace-context wire format
 (``00-<32 hex trace id>-<16 hex span id>-<2 hex flags>``) carried in
 the ``TRACEPARENT`` env var across processes and in the
 ``obs.kubeflow.org/traceparent`` annotation across k8s objects.
+
+**The bridge to the device trace.** Spans run on this module's own
+clock. Where JAX is already imported, ``Tracer.span`` and ``PhaseClock``
+also enter a ``jax.profiler.TraceAnnotation``, so that the same work is
+a host event in the profiler's trace, on the clock the device planes
+use. JAX is looked up in ``sys.modules`` and never imported from here:
+the control plane, the launcher and CI images pay and need nothing.
 """
 
 from __future__ import annotations
@@ -26,6 +33,7 @@ import contextvars
 import dataclasses
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -36,6 +44,73 @@ from typing import Iterator
 # them, scheduler/launcher/trainer read them).
 TRACEPARENT_ENV = "TRACEPARENT"
 TRACEPARENT_ANNOTATION = "obs.kubeflow.org/traceparent"
+
+# Every annotation the program writes into the profiler's trace starts
+# with this prefix, so a reduction of the trace can prefer the program's
+# own names over the runtime's when it names an idle gap. A layer's
+# phases are listed beside the loop that owns them.
+ANNOTATION_PREFIX = "kftpu."
+
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def annotation(name: str, **attrs):
+    """A ``jax.profiler.TraceAnnotation(name, **attrs)`` where JAX is
+    already imported, else a context manager that does nothing. About a
+    microsecond while no profiler session is open."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return _NO_ANNOTATION
+    return profiler.TraceAnnotation(name, **attrs)
+
+
+class _Phase:
+    """One phase of a PhaseClock: the context manager it hands out."""
+
+    __slots__ = ("name", "attrs", "_table", "_key", "_note", "_t0")
+
+    def __init__(self, table: dict, layer: str, phase: str):
+        self.name = f"{ANNOTATION_PREFIX}{layer}.{phase}"
+        self.attrs: dict = {}
+        self._table = table
+        self._key = f"phase_s.{phase}"
+        table[self._key] = 0.0
+
+    def __enter__(self):
+        self._note = annotation(self.name, **self.attrs)
+        self._note.__enter__()
+        self._t0 = time.perf_counter()  # tpulint: disable=DET601  phase timing is observability payload, not a decision input
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0  # tpulint: disable=DET601  phase timing is observability payload, not a decision input
+        self._note.__exit__(*exc)
+        self._table[self._key] += dt
+        return False
+
+
+class PhaseClock:
+    """Host phases of a hot loop, for work too frequent for a Span each
+    (a decode round runs 40 times a second; the ring would last 30 s).
+    ``with clock("tick", fused=1):`` enters a TraceAnnotation named
+    ``kftpu.<layer>.tick`` and adds the perf_counter delta to
+    ``table["phase_s.tick"]``.
+
+    Every key is made here, at construction, and the values are plain
+    numbers: the table is copied with ``dict(...)`` from other threads
+    while the loop runs, so a key that appeared later would raise
+    "dictionary changed size", and a nested dict would alias an earlier
+    copy. One thread enters the phases, and a phase does not nest in
+    itself."""
+
+    def __init__(self, layer: str, phases, table: dict):
+        self._phases = {p: _Phase(table, layer, p) for p in phases}
+
+    def __call__(self, phase: str, **attrs) -> _Phase:
+        ph = self._phases[phase]
+        ph.attrs = attrs
+        return ph
+
 
 # Wall-clock anchor: epoch seconds at the instant perf_counter read 0.
 # Span timestamps are anchor + perf_counter — one wall reading at
@@ -222,15 +297,34 @@ class Tracer:
     @contextlib.contextmanager
     def span(self, name: str, parent: SpanContext | None = None,
              **attrs) -> Iterator[Span]:
+        """The lexically scoped form, and the only one that is also a
+        host event in the profiler's trace (module docstring): a span
+        held open across calls has no one stack to annotate."""
         sp = self.begin(name, parent=parent, **attrs)
         try:
-            yield sp
+            with annotation(name, **attrs):
+                yield sp
         except BaseException as e:
             sp.status = "ERROR"
             sp.error = f"{type(e).__name__}: {e}"
             raise
         finally:
             self.finish(sp)
+
+    def record(self, name: str, t0: float, t1: float,
+               parent: SpanContext | None = None, **attrs) -> Span:
+        """A finished span from two ``perf_counter`` readings taken
+        elsewhere (a request's submit and done stamps, written by
+        another thread). ``parent`` defaults to the ambient context;
+        the span never becomes it."""
+        up = parent if parent is not None else _CURRENT.get()
+        span = Span(name=name,
+                    trace_id=up.trace_id if up is not None else new_trace_id(),
+                    span_id=new_span_id(),
+                    parent_id=up.span_id if up is not None else None,
+                    start=_EPOCH + t0, end=_EPOCH + t1, attrs=dict(attrs))
+        self.collector.add(span)
+        return span
 
 
 COLLECTOR = TraceCollector()

@@ -106,6 +106,12 @@ def mesh_head_axis(mesh: Mesh, n_heads: int) -> str | None:
     return AXIS_MODEL if model_size > 1 and n_heads % model_size == 0 else None
 
 
+# The name of this nested jit is a contract: the flash kernels carry no
+# name of their own, so their custom calls appear in the device trace as
+# `%local_attention.N`, and the benchmark's flash_roofline.train finds
+# them by that (benchmarks/metrics/flash_roofline.train.json; pinned by
+# tests/test_trace_names.py). A `name=` on the pallas_calls would replace
+# it (the instruction becomes `%<name>.N`) and silence the metric.
 @functools.partial(jax.jit,
                    static_argnames=("causal", "impl", "block_q", "block_k",
                                     "window"))

@@ -115,14 +115,16 @@ class StepMeter:
                 self._span_name, step=self.step_base + self.steps)
         self._t0 = time.perf_counter()
 
-    def stop(self) -> float:
+    def stop(self, **attrs) -> float:
+        """`attrs` go onto the step's span beside `step_time_s` (the
+        trainer's host split of the step)."""
         assert self._t0 is not None, "stop() without start()"
         dt = time.perf_counter() - self._t0
         self._times.append(dt)
         self.steps += 1
         self._t0 = None
         if self._span is not None:
-            self._span.attrs["step_time_s"] = round(dt, 6)
+            self._span.attrs.update(attrs, step_time_s=round(dt, 6))
             self._tracer.finish(self._span)
             self._span = None
         return dt

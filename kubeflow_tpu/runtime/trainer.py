@@ -544,6 +544,9 @@ class Trainer:
             )
             return new_state, {"loss": loss, "accuracy": acc, **(diag or {})}
 
+        # (the name is read: the device trace has the step as the module
+        # `jit_train_step`, and PERF.md and the ledger's breakdown name
+        # idle gaps by it; pinned by tests/test_trace_names.py)
         def train_step(state: TrainState, batch):
             (loss, (new_stats, acc, _, diag)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(
@@ -629,18 +632,24 @@ class Trainer:
     # ---- public API ------------------------------------------------------
 
     def init_state(self) -> TrainState:
+        from kubeflow_tpu.obs import trace as obs_trace
+
         rng = jax.random.PRNGKey(self.cfg.seed)
-        with self.mesh:
-            variables = self._init_jit(rng)
-        params = variables["params"]
-        batch_stats = variables.get("batch_stats", {})
-        # the step counter is made on the mesh like the rest of the state:
-        # an uncommitted leaf comes back committed from step 1, which is a
-        # new jit cache key, and the whole train step compiles twice
-        step, opt_state = jax.jit(
-            lambda p: (jnp.zeros((), jnp.int32), self.tx.init(p)),
-            out_shardings=(self.state_shardings.step,
-                           self.state_shardings.opt_state))(params)
+        # model and optimizer init, a part of set-up: traced, compiled
+        # and enqueued here (the first step waits for what still runs)
+        with obs_trace.TRACER.span("train.init", model=self.cfg.model):
+            with self.mesh:
+                variables = self._init_jit(rng)
+            params = variables["params"]
+            batch_stats = variables.get("batch_stats", {})
+            # the step counter is made on the mesh like the rest of the
+            # state: an uncommitted leaf comes back committed from step 1,
+            # which is a new jit cache key, and the whole train step
+            # compiles twice
+            step, opt_state = jax.jit(
+                lambda p: (jnp.zeros((), jnp.int32), self.tx.init(p)),
+                out_shardings=(self.state_shardings.step,
+                               self.state_shardings.opt_state))(params)
         log.info("model %s: %.2fM params", self.cfg.model, self.n_params / 1e6)
         return TrainState(
             step=step,
@@ -841,6 +850,27 @@ class Trainer:
         trace = TraceWindow(cfg.profile_dir, cfg.profile_start_step,
                             cfg.profile_steps)
 
+        # the host's share of a pass: kftpu.train.<phase> annotations in
+        # the profiler's trace (data, dispatch, wait, save, eval,
+        # callback), and the first three as attributes of the step's span
+        def phase(p: str):
+            return obs_trace.annotation(
+                f"{obs_trace.ANNOTATION_PREFIX}train.{p}")
+
+        def run_step(state, batch, data_s: float):
+            """Dispatch a step and wait for its loss; with the seconds
+            `next(data)` took, the host split of the step."""
+            t0 = _time.perf_counter()
+            with phase("dispatch"):
+                state, m = self.train_step(state, batch)
+            t1 = _time.perf_counter()
+            with phase("wait"):
+                jax.block_until_ready(m["loss"])
+            t2 = _time.perf_counter()
+            return state, m, {"data_wait_s": round(data_s, 6),
+                              "dispatch_s": round(t1 - t0, 6),
+                              "device_wait_s": round(t2 - t1, 6)}
+
         ok = False
         preempted = False
         # Fit span: nest under the caller's ambient span when one is
@@ -883,48 +913,56 @@ class Trainer:
                                 "exiting early", int(state.step))
                     break
                 trace.step(start_step + i)
-                batch = next(data)
-                if i == 0:
-                    self._log_placement(state, batch)
-                    # Step 0 pays XLA compile; keep it out of the meter window
-                    # so step_time/throughput/MFU reflect steady state.
-                    t0 = _time.perf_counter()
-                    with obs_trace.TRACER.span("train.step", step=start_step,
-                                               compile=True):
-                        state, m = self.train_step(state, batch)
-                        jax.block_until_ready(m["loss"])
-                    first_dt = _time.perf_counter() - t0
-                    log.info("first step (incl. compile): %.2fs", first_dt)
-                    last = {k: float(v) for k, v in m.items()}
-                    maybe_save(start_step + 1, state)
-                    maybe_eval(start_step + 1, state)
+                # one pass = one step in the profiler's own step view
+                with jax.profiler.StepTraceAnnotation(
+                        "train", step_num=start_step + i):
+                    t_data = _time.perf_counter()
+                    with phase("data"):
+                        batch = next(data)
+                    data_s = _time.perf_counter() - t_data
+                    if i == 0:
+                        self._log_placement(state, batch)
+                        # Step 0 pays XLA compile; keep it out of the meter
+                        # window so step_time/throughput/MFU reflect steady
+                        # state.
+                        t0 = _time.perf_counter()
+                        with obs_trace.TRACER.span(
+                                "train.step", step=start_step,
+                                compile=True) as sp:
+                            state, m, split = run_step(state, batch, data_s)
+                            sp.attrs.update(split)
+                        first_dt = _time.perf_counter() - t0
+                        log.info("first step (incl. compile): %.2fs", first_dt)
+                        last = {k: float(v) for k, v in m.items()}
+                    else:
+                        # the span's extent stays dispatch to
+                        # block_until_ready: obs/goodput.py classifies by it
+                        meter.start()
+                        state, m, split = run_step(state, batch, data_s)
+                        meter.stop(**split)
+                        if (i + 1) % cfg.log_every == 0 or i == steps - start_step - 1:
+                            last = {k: float(v) for k, v in m.items()}
+                            rt_metrics.REGISTRY.gauge("jaxrt_step_seconds", meter.step_time,
+                                                      "mean step wall time")
+                            rt_metrics.REGISTRY.gauge("jaxrt_examples_per_sec",
+                                                      meter.throughput(cfg.global_batch),
+                                                      "training throughput")
+                            if meter.peak:  # a utilization needs a known chip
+                                rt_metrics.REGISTRY.gauge("jaxrt_mfu", meter.mfu, "model FLOPs utilization")
+                            rt_metrics.REGISTRY.gauge("jaxrt_loss", last["loss"], "training loss")
+                            log.info(
+                                "step %d loss=%.4f acc=%.3f %.1f ex/s step=%.1fms%s",
+                                i + 1, last["loss"], last.get("accuracy", float("nan")),
+                                meter.throughput(cfg.global_batch), meter.step_time * 1e3,
+                                f" mfu={meter.mfu * 100:.1f}%" if meter.peak else "",
+                            )
+                    with phase("save"):
+                        maybe_save(start_step + i + 1, state)
+                    with phase("eval"):
+                        maybe_eval(start_step + i + 1, state)
                     if callback:
-                        callback(i, m)
-                    continue
-                meter.start()
-                state, m = self.train_step(state, batch)
-                jax.block_until_ready(m["loss"])
-                meter.stop()
-                if (i + 1) % cfg.log_every == 0 or i == steps - start_step - 1:
-                    last = {k: float(v) for k, v in m.items()}
-                    rt_metrics.REGISTRY.gauge("jaxrt_step_seconds", meter.step_time,
-                                              "mean step wall time")
-                    rt_metrics.REGISTRY.gauge("jaxrt_examples_per_sec",
-                                              meter.throughput(cfg.global_batch),
-                                              "training throughput")
-                    if meter.peak:  # a utilization needs a known chip
-                        rt_metrics.REGISTRY.gauge("jaxrt_mfu", meter.mfu, "model FLOPs utilization")
-                    rt_metrics.REGISTRY.gauge("jaxrt_loss", last["loss"], "training loss")
-                    log.info(
-                        "step %d loss=%.4f acc=%.3f %.1f ex/s step=%.1fms%s",
-                        i + 1, last["loss"], last.get("accuracy", float("nan")),
-                        meter.throughput(cfg.global_batch), meter.step_time * 1e3,
-                        f" mfu={meter.mfu * 100:.1f}%" if meter.peak else "",
-                    )
-                maybe_save(start_step + i + 1, state)
-                maybe_eval(start_step + i + 1, state)
-                if callback:
-                    callback(i, m)
+                        with phase("callback"):
+                            callback(i, m)
             ok = True
         finally:
             meter.close()  # a step that raised still exports, as ERROR

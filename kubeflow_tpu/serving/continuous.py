@@ -41,11 +41,13 @@ whatever mesh the variables are sharded over.
 
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
 import time
 from typing import Any
 
+from kubeflow_tpu.obs import trace as obs_trace
 from kubeflow_tpu.runtime.metrics import REGISTRY as METRICS_REGISTRY
 # the ONE spelling of the 504 across the serving plane (router.py is
 # jax-free, so this import costs nothing)
@@ -115,6 +117,26 @@ class _DecodeMeter:
               "prompt positions actually computed by prefill",
               labelnames=("model",)).labels(self.model).inc(n)
 
+    def request_waits(self, queue_wait_s: float, first_token_s: float) -> None:
+        """Once a request, from the thread that submitted it."""
+        import prometheus_client as prom
+
+        self.registry.histogram(
+            "serving_queue_wait_seconds", queue_wait_s,
+            help_="submit to admission (the request's prefill dispatched)",
+            model=self.model)
+        _prom("serving_queue_wait_seconds", prom.Histogram,
+              "submit to admission (the request's prefill dispatched)",
+              labelnames=("model",)).labels(self.model).observe(queue_wait_s)
+        self.registry.histogram(
+            "serving_first_token_seconds", first_token_s,
+            help_="submit to the first read-back after admission: the "
+                  "first moment a token could have been streamed",
+            model=self.model)
+        _prom("serving_first_token_seconds", prom.Histogram,
+              "submit to the first read-back after admission",
+              labelnames=("model",)).labels(self.model).observe(first_token_s)
+
     def spec_round(self, slots: int, accepted: int) -> None:
         import prometheus_client as prom
 
@@ -135,6 +157,56 @@ class _DecodeMeter:
         _prom("serving_spec_tokens_accepted_total", prom.Counter,
               "draft tokens accepted by the target verify",
               labelnames=("model",)).labels(self.model).inc(accepted)
+
+
+# The scheduler loop's host phases: `kftpu.sched.<phase>` in the
+# profiler's trace, `phase_s.<phase>` in stats(). The loop thread is
+# always in one of them.
+SCHED_PHASES = ("admit", "prefill", "pages", "tick", "readback",
+                "complete", "idle")
+
+# A request's stamps, and the waits summed from them, are observability
+# payload on the spans' clock; no decision reads them (deadlines run on
+# the injectable self.clock).
+_stamp = time.perf_counter
+
+
+class _Request:
+    """One submitted request: what the loop needs to serve it, and its
+    four stamps on perf_counter. The submitting thread makes it and
+    stamps `t_submit`; the loop thread writes the other numbers (plain
+    floats and ints, never a span) and fires `ev` last, after which the
+    submitting thread reads them."""
+
+    __slots__ = ("prompt", "pad", "req", "ev", "sink", "deadline",
+                 "t_submit", "t_admit", "t_first", "t_done", "slot",
+                 "prefill_tokens")
+
+    def __init__(self, prompt, pad: int, req: int, deadline):
+        self.prompt, self.pad, self.req = prompt, pad, req
+        self.deadline = deadline
+        self.ev = threading.Event()
+        self.sink: list = []
+        self.t_submit = _stamp()
+        self.t_admit = self.t_first = self.t_done = 0.0
+        self.slot = -1
+        self.prefill_tokens = 0
+
+    def finish(self, result) -> None:
+        """The one exit: tokens or the error into the sink, the done
+        stamp, then wake the submitter."""
+        if isinstance(result, Exception):
+            self.sink.append(result)
+        else:
+            self.sink.extend(result)
+        self.t_done = _stamp()
+        self.ev.set()
+
+    def outcome(self) -> str:
+        if self.sink and isinstance(self.sink[0], Exception):
+            return ("canceled" if isinstance(self.sink[0], DeadlineExceeded)
+                    else "failed")
+        return "ok"
 
 
 class SlotDecoder:
@@ -224,6 +296,7 @@ class SlotDecoder:
         else:
             self.alloc = None
         self.meter = _DecodeMeter(metrics_name) if metrics_name else None
+        self._pages_published = -1   # free pages at the last publish
 
         # host-truth counters (stats(); the meter mirrors into sinks)
         self._counters = {
@@ -232,7 +305,18 @@ class SlotDecoder:
             "spec_rounds": 0, "spec_tokens_emitted": 0,
             "spec_tokens_accepted": 0, "spec_drafted": 0,
             "deadline_canceled": 0,
+            # passes of the loop that dispatched a step program
+            "rounds": 0,
+            # per request: submit to admission, submit to the first
+            # read-back after it (seconds, summed; `admitted` and
+            # `first_tokens` are the counts)
+            "queue_wait_s_sum": 0.0, "first_token_s_sum": 0.0,
+            "first_tokens": 0,
         }
+        # the loop's host phases: phase_s.* in stats(), and kftpu.sched.*
+        # annotations in the profiler's trace
+        self._phase = obs_trace.PhaseClock(
+            "sched", SCHED_PHASES, self._counters)
 
         # Params are jit ARGUMENTS everywhere below, never closure
         # captures: a closed-over weight tree is serialized into the
@@ -293,7 +377,10 @@ class SlotDecoder:
 
         # -- compiled: paged prefill of ONE request's uncached prompt
         #    suffix + install (the suffix length is one of a bounded
-        #    set of page-aligned sizes, so compiles stay bounded) -------
+        #    set of page-aligned sizes, so compiles stay bounded). The
+        #    function's name is a contract: the benchmark finds the XLA
+        #    module `jit__paged_prefill_install` by it
+        #    (benchmarks/metrics/*.json; tests/test_trace_names.py) ------
         def _paged_prefill_install(params, state, toks, start, pt_row,
                                    pad, slot, req_n):
             cache, last, pos, remaining, out, pads, req, rng = state
@@ -323,7 +410,9 @@ class SlotDecoder:
 
         self._apply_copies = jax.jit(_apply_copies, donate_argnums=(0,))
 
-        # -- compiled: one lockstep decode tick for all S slots ----------
+        # -- compiled: one lockstep decode tick for all S slots. Its name
+        #    is a contract too: the paged decoder's module is `jit__tick`
+        #    in the device trace, and the benchmark reads it by that ------
         def _tick(params, state, page_table=None):
             cache, last, pos, remaining, out, pads, req, rng = state
             from kubeflow_tpu.runtime.generate import _sample
@@ -363,8 +452,13 @@ class SlotDecoder:
         #    dispatch costs a host round-trip (launch, the readback of
         #    `remaining`, the loop's bookkeeping); where that exceeds the
         #    tick's own compute, decode is bound by the host. Fusing
-        #    amortizes the round-trip FUSE-fold. What a round-trip costs
-        #    against a tick on the chip is not measured. Correctness is
+        #    amortizes the round-trip FUSE-fold. Measured on a v5e (PERF.md
+        #    section 5, `sched.host_ms_per_round.*`): without an admission
+        #    the host's share of a round is about 1.3 ms (page bookkeeping
+        #    and the table's upload 0.6-0.7, the dispatch 0.5-0.6, completion
+        #    0.1) against a 25-26 ms tick of an 8-layer Mistral-7B, so at
+        #    that size the round-trip is 5% of a tick and fusing buys
+        #    little; an admission adds 6-15 ms of host time. Correctness is
         #    unchanged — the tick body masks on remaining>0, so a slot
         #    finishing mid-window just idles until the window ends; the
         #    cost is admission/completion latency bounded at FUSE ticks,
@@ -372,6 +466,7 @@ class SlotDecoder:
         #    and every active slot has >= FUSE tokens to go. ------------
         FUSE = 8
 
+        # (`jit__step_fused` in the device trace: read by the benchmark)
         def _step_fused(params, state, page_table=None):
             def body(st, _):
                 return _tick(params, st, page_table), None
@@ -504,28 +599,46 @@ class SlotDecoder:
         req = self.N if max_new is None else int(max_new)
         if not 1 <= req <= self.N:
             raise ValueError(f"max_new must be in 1..{self.N}, got {req}")
-        prompt = np.asarray(padded_row, dtype=np.int32)
-        ev = threading.Event()
-        sink: list = []
+        r = _Request(np.asarray(padded_row, dtype=np.int32), pad, req,
+                     deadline)
         with self._lock:  # enqueue-before-drain or fail fast, atomically
             if self._stop:
                 raise RuntimeError("decoder shut down")
-            self._pending.put((prompt, pad, req, ev, sink, deadline))
+            self._pending.put(r)
         self._wake.set()
         if deadline is None:
             # the loop fires ev on EVERY exit path (complete, cancel,
             # fail_all, shutdown drain), so the unbounded park is safe
-            ev.wait()  # tpulint: disable=NET501  loop guarantees ev.set
+            r.ev.wait()  # tpulint: disable=NET501  loop guarantees ev.set
         else:
             # bounded wait: the loop cancels the slot at the next round
             # boundary; the grace poll only guards a wedged loop thread
-            while not ev.wait(timeout=0.25):
+            while not r.ev.wait(timeout=0.25):
                 if self.clock() >= deadline + 30.0:
                     raise DeadlineExceeded(
                         "decoder unresponsive past request deadline")
-        if sink and isinstance(sink[0], Exception):
-            raise sink[0]
-        return sink
+        self._note_request(r)
+        if r.sink and isinstance(r.sink[0], Exception):
+            raise r.sink[0]
+        return r.sink
+
+    def _note_request(self, r: _Request) -> None:
+        """From the submitting thread, once woken: the request's
+        `serve.request` span (submit to done, under the submitter's
+        ambient context) and its two waits to the meter. None where the
+        request never got that far."""
+        outcome = r.outcome()
+        wait = r.t_admit - r.t_submit if r.t_admit else None
+        first = r.t_first - r.t_submit if r.t_first else None
+        obs_trace.TRACER.record(
+            "serve.request", r.t_submit, r.t_done,
+            queue_wait_s=wait, first_token_s=first,
+            prompt_tokens=self.P - r.pad,
+            prefill_tokens_computed=r.prefill_tokens,
+            new_tokens=len(r.sink) if outcome == "ok" else 0, slot=r.slot,
+            outcome=outcome)
+        if self.meter and first is not None:
+            self.meter.request_waits(wait, first)
 
     def close(self) -> None:
         with self._lock:
@@ -565,8 +678,15 @@ class SlotDecoder:
             self._counters["peak_active"] = len(owners)
 
     def _publish_pages(self) -> None:
+        """The page gauges, where the pool moved since they were last
+        set (admission, completion, cancel, a sequence crossing a page
+        boundary): four writes through two sinks are not worth a
+        round's time for nothing."""
         if self.meter and self.paged:
-            self.meter.pages(self.alloc.free_pages, self.alloc.used_pages)
+            free = self.alloc.free_pages
+            if free != self._pages_published:
+                self._pages_published = free
+                self.meter.pages(free, self.alloc.used_pages)
 
     def _cow_arrays(self, copies):
         """[(src, dst)] page clones -> traced index arrays; the ONE
@@ -576,18 +696,14 @@ class SlotDecoder:
                 jnp.asarray([c[1] for c in copies], jnp.int32))
 
     def _drain_shutdown(self, owners: dict) -> None:
-        for ev, sink, _req, _dl in list(owners.values()):
-            sink.append(RuntimeError("decoder shut down"))
-            ev.set()
+        err = RuntimeError("decoder shut down")
+        for r in list(owners.values()):
+            r.finish(err)
         if self._carry is not None:
-            _p, _pad, _req, ev, sink, _dl = self._carry
-            sink.append(RuntimeError("decoder shut down"))
-            ev.set()
+            self._carry.finish(err)
             self._carry = None
         while not self._pending.empty():
-            _p, _pad, _req, ev, sink, _dl = self._pending.get_nowait()
-            sink.append(RuntimeError("decoder shut down"))
-            ev.set()
+            self._pending.get_nowait().finish(err)
 
     def _next_pending(self):
         """FIFO head: the page-gated carry first, then the queue."""
@@ -598,39 +714,58 @@ class SlotDecoder:
             return self._pending.get_nowait()
         return None
 
-    def _validate(self, item) -> bool:
+    def _validate(self, r: _Request) -> bool:
         """Row-shape validation; a malformed row fails ONLY its caller
         and never reaches a slot. Also the queue-side deadline gate: a
         request that expired while waiting (or carried at the page gate)
         is shed here, BEFORE it costs a prefill."""
-        prompt, _pad, _req, ev, sink, dl = item
-        if dl is not None and self.clock() >= dl:
-            sink.append(DeadlineExceeded(
-                "deadline elapsed before admission"))
-            ev.set()
+        if r.deadline is not None and self.clock() >= r.deadline:
+            r.finish(DeadlineExceeded("deadline elapsed before admission"))
             self._counters["deadline_canceled"] += 1
             return False
-        if prompt.shape != (self.P,):
-            sink.append(ValueError(
+        if r.prompt.shape != (self.P,):
+            r.finish(ValueError(
                 f"padded row must have length {self.P}, "
-                f"got {prompt.shape}"))
-            ev.set()
+                f"got {r.prompt.shape}"))
             return False
         return True
+
+    def _note_admitted(self, r: _Request, slot: int, prefill_tokens: int,
+                       owners: dict) -> None:
+        """Admission's bookkeeping, once the request's prefill has been
+        dispatched: the stamp, the counters, the slot's owner."""
+        r.t_admit = _stamp()
+        r.slot, r.prefill_tokens = slot, prefill_tokens
+        owners[slot] = r
+        c = self._counters
+        c["admitted"] += 1
+        c["queue_wait_s_sum"] += r.t_admit - r.t_submit
+        c["prefill_tokens_computed"] += prefill_tokens
+        c["prompt_tokens_submitted"] += self.P
+
+    def _note_first_tokens(self, requests) -> None:
+        """The first read-back after an admission has just ended: the
+        first moment a token of these requests could have been sent."""
+        now = _stamp()
+        c = self._counters
+        for r in requests:
+            if not r.t_first:
+                r.t_first = now
+                c["first_token_s_sum"] += now - r.t_submit
+                c["first_tokens"] += 1
 
     def _expired_slots(self, owners: dict) -> list[int]:
         """Active slots whose request deadline has passed."""
         now = self.clock()
-        return [s_ for s_, own in owners.items()
-                if own[3] is not None and now >= own[3]]
+        return [s_ for s_, r in owners.items()
+                if r.deadline is not None and now >= r.deadline]
 
     def _cancel_slot(self, owners: dict, slot: int) -> None:
         """Cancel ONE mid-decode slot: waiter gets DeadlineExceeded, the
         slot and (paged) its KV pages go back to the pool. Zero-leak is
         the contract — alloc.check() stays clean after any cancel."""
-        ev, sink, _req, _dl = owners.pop(slot)
-        sink.append(DeadlineExceeded("deadline exceeded during decode"))
-        ev.set()
+        owners.pop(slot).finish(
+            DeadlineExceeded("deadline exceeded during decode"))
         self._free.append(slot)
         self._counters["deadline_canceled"] += 1
         if self.paged:
@@ -639,12 +774,11 @@ class SlotDecoder:
     # -- scheduler loop (plain greedy/sampled decode) ----------------------
 
     def _loop(self) -> None:
-        import contextlib
-
         import numpy as np
 
         jnp = self._jnp
-        owners: dict[int, tuple] = {}   # slot -> (ev, sink, req, deadline)
+        phase = self._phase
+        owners: dict[int, _Request] = {}   # slot -> the request it serves
         ctx = self.mesh if self.mesh is not None else None
 
         def fail_all(err, batch=()):
@@ -652,12 +786,8 @@ class SlotDecoder:
             failed donated call the old buffers are dead — continuing on
             them would turn the decoder into a zombie that errors every
             future request while still accepting submits."""
-            for _p, _pad, _req, ev, sink, _dl in batch:
-                sink.append(err)
-                ev.set()
-            for s_, (ev, sink, _req, _dl) in list(owners.items()):
-                sink.append(err)
-                ev.set()
+            for r in (*batch, *owners.values()):
+                r.finish(err)
             owners.clear()
             self._free = list(range(self.S))
             if self.alloc is not None:
@@ -672,74 +802,83 @@ class SlotDecoder:
                     self._admit_paged(owners, fail_all, last_rem, last_pos)
                 else:
                     self._admit_dense(owners, fail_all, last_rem)
-                # cancel expired slots at the round boundary: zero their
-                # remaining (the masked step then treats them as idle)
-                # and return slot + pages to the pool before the next
-                # admission pass can want them
-                expired = self._expired_slots(owners)
-                if expired:
-                    self.state = self._clear_slots(
-                        self.state, jnp.asarray(expired, jnp.int32))
-                    for s_ in expired:
-                        self._cancel_slot(owners, s_)
-                        last_rem[s_] = 0
-                    self._publish_pages()
-                self._note_active(owners)
+                with phase("admit"):
+                    # cancel expired slots at the round boundary: zero
+                    # their remaining (the masked step then treats them
+                    # as idle) and return slot + pages to the pool before
+                    # the next admission pass can want them
+                    expired = self._expired_slots(owners)
+                    if expired:
+                        self.state = self._clear_slots(
+                            self.state, jnp.asarray(expired, jnp.int32))
+                        for s_ in expired:
+                            self._cancel_slot(owners, s_)
+                            last_rem[s_] = 0
+                        self._publish_pages()
+                    self._note_active(owners)
                 if not owners:
-                    self._wake.wait(timeout=0.05)
-                    self._wake.clear()
+                    with phase("idle"):
+                        self._wake.wait(timeout=0.05)
+                        self._wake.clear()
                     continue
-                # fuse ticks when every active slot has a full window of
-                # tokens left AND no waiter could be admitted any sooner
-                # by single-stepping: with all remaining >= FUSE no slot
-                # can complete inside the window, so when the decoder is
-                # SATURATED (no free slot) a queued request loses zero
-                # ticks to fusion — that saturated case is exactly the
-                # latency-bound regime the fusion exists for (host-side
-                # remaining mirror: last readback, req for fresh installs)
-                waiting = (self._carry is not None
-                           or not self._pending.empty())
-                fuse = ((not waiting or not self._free)
-                        and all(int(last_rem[s_]) >= self._fuse
-                                for s_ in owners))
-                ticks = self._fuse if fuse else 1
-                if self.paged:
-                    # decode writes march forward: hand out the pages
-                    # the window will cross (reserved at admission) and
-                    # run the COW barrier over the write range
-                    for s_ in owners:
-                        start = int(last_pos[s_])
-                        self.alloc.append(s_, start + ticks)
-                        copies = self.alloc.write_barrier(
-                            s_, start, start + ticks)
-                        if copies:
-                            self.state = self._apply_copies(
-                                self.state, *self._cow_arrays(copies))
-                    pt = jnp.asarray(self.alloc.table)
-                    args = (self._params, self.state, pt)
-                else:
-                    args = (self._params, self.state)
-                with (ctx or contextlib.nullcontext()):
+                with phase("pages"):
+                    # fuse ticks when every active slot has a full window
+                    # of tokens left AND no waiter could be admitted any
+                    # sooner by single-stepping: with all remaining >=
+                    # FUSE no slot can complete inside the window, so
+                    # when the decoder is SATURATED (no free slot) a
+                    # queued request loses zero ticks to fusion — that
+                    # saturated case is exactly the latency-bound regime
+                    # the fusion exists for (host-side remaining mirror:
+                    # last readback, req for fresh installs)
+                    waiting = (self._carry is not None
+                               or not self._pending.empty())
+                    fuse = ((not waiting or not self._free)
+                            and all(int(last_rem[s_]) >= self._fuse
+                                    for s_ in owners))
+                    ticks = self._fuse if fuse else 1
+                    if self.paged:
+                        # decode writes march forward: hand out the pages
+                        # the window will cross (reserved at admission)
+                        # and run the COW barrier over the write range
+                        for s_ in owners:
+                            start = int(last_pos[s_])
+                            self.alloc.append(s_, start + ticks)
+                            copies = self.alloc.write_barrier(
+                                s_, start, start + ticks)
+                            if copies:
+                                self.state = self._apply_copies(
+                                    self.state, *self._cow_arrays(copies))
+                        pt = jnp.asarray(self.alloc.table)
+                        args = (self._params, self.state, pt)
+                    else:
+                        args = (self._params, self.state)
+                with phase("tick", fused=int(fuse)), \
+                        (ctx or contextlib.nullcontext()):
                     self.state = (self._step_fused if fuse else
                                   self._step)(*args)
-                remaining = np.asarray(self.state[3])
-                # writable copies: admission writes fresh slots' mirrors
-                last_rem = np.array(remaining)
-                last_pos = np.array(self.state[2])
-                out = None
-                for s_ in list(owners):
-                    if remaining[s_] <= 0:
-                        if out is None:  # one readback per tick, lazily
-                            out = np.asarray(self.state[4])
-                        ev, sink, req, _dl = owners.pop(s_)
-                        sink.extend(int(t) for t in out[s_][:req])
-                        ev.set()
+                self._counters["rounds"] += 1
+                with phase("readback"):
+                    # the host blocks here until the device has caught up
+                    remaining = np.asarray(self.state[3])
+                    # writable copies: admission writes fresh slots' mirrors
+                    last_rem = np.array(remaining)
+                    last_pos = np.array(self.state[2])
+                    done = [s_ for s_ in owners if remaining[s_] <= 0]
+                    # one readback of the tokens per round, and only
+                    # where a slot finished
+                    out = np.asarray(self.state[4]) if done else None
+                with phase("complete"):
+                    self._note_first_tokens(owners.values())
+                    for s_ in done:
+                        r = owners.pop(s_)
+                        r.finish(int(t) for t in out[s_][:r.req])
                         self._free.append(s_)
                         self._counters["completed"] += 1
                         if self.paged:
                             self.alloc.free(s_)
-                self._publish_pages()
-                self._note_active(owners)
+                    self._publish_pages()
+                    self._note_active(owners)
             except Exception as e:  # a broken step: poison + rebuild
                 log.exception("slot-decoder loop failed")
                 fail_all(e)
@@ -750,50 +889,50 @@ class SlotDecoder:
     # -- admission: dense (batched idle-burst prefill) ---------------------
 
     def _admit_dense(self, owners, fail_all, last_rem) -> None:
-        import contextlib
-
         import numpy as np
 
         jnp = self._jnp
+        phase = self._phase
         ctx = self.mesh if self.mesh is not None else None
         if not (self._free and not self._pending.empty()):
             return
-        # admit pending requests into free slots (step boundary).
-        # Idle decoder: take a BATCH of waiting prompts (padded
-        # up to the next supported prefill size) so an idle
-        # burst prefills together. Anything mid-generation:
-        # admit at most ONE per tick — a burst must not stall
-        # in-flight decodes.
-        want = 1 if owners else len(self._free)
-        batch = []
-        while len(batch) < want and not self._pending.empty():
-            batch.append(self._pending.get_nowait())
-        # validate rows FIRST; a wrong-length row (the submit_padded
-        # caller's bug) fails THAT caller only and never enters the
-        # batch, so row indices below stay aligned with the prefill
-        # outputs
-        batch = [item for item in batch if self._validate(item)]
-        if not batch:
-            return
-        k = next(n for n in self._PREFILL_SIZES if n >= len(batch))
-        prompts = np.zeros((k, self.P), np.int32)
-        pads = np.zeros((k,), np.int32)
-        news = np.zeros((k,), np.int32)
-        for i, (prompt, pad, req, _ev, _sink, _dl) in enumerate(batch):
-            prompts[i] = prompt
-            pads[i] = pad
-            news[i] = req
-        slots = [self._free.pop() for _ in range(len(batch))]
-        # dummy rows (k > len(batch)) target REMAINING free slots: they
-        # hold no generation, and any future real install fully
-        # overwrites the row. Idle admission guarantees enough free
-        # slots (batch <= free == S >= k); active admission is always
-        # k == batch == 1.
-        dummies = self._free[:k - len(slots)]
-        pad_slots = slots + dummies
-        assert len(pad_slots) == k, (k, slots, dummies)
+        with phase("admit"):
+            # admit pending requests into free slots (step boundary).
+            # Idle decoder: take a BATCH of waiting prompts (padded
+            # up to the next supported prefill size) so an idle
+            # burst prefills together. Anything mid-generation:
+            # admit at most ONE per tick — a burst must not stall
+            # in-flight decodes.
+            want = 1 if owners else len(self._free)
+            batch = []
+            while len(batch) < want and not self._pending.empty():
+                batch.append(self._pending.get_nowait())
+            # validate rows FIRST; a wrong-length row (the submit_padded
+            # caller's bug) fails THAT caller only and never enters the
+            # batch, so row indices below stay aligned with the prefill
+            # outputs
+            batch = [r for r in batch if self._validate(r)]
+            if not batch:
+                return
+            k = next(n for n in self._PREFILL_SIZES if n >= len(batch))
+            prompts = np.zeros((k, self.P), np.int32)
+            pads = np.zeros((k,), np.int32)
+            news = np.zeros((k,), np.int32)
+            for i, r in enumerate(batch):
+                prompts[i] = r.prompt
+                pads[i] = r.pad
+                news[i] = r.req
+            slots = [self._free.pop() for _ in range(len(batch))]
+            # dummy rows (k > len(batch)) target REMAINING free slots:
+            # they hold no generation, and any future real install fully
+            # overwrites the row. Idle admission guarantees enough free
+            # slots (batch <= free == S >= k); active admission is always
+            # k == batch == 1.
+            dummies = self._free[:k - len(slots)]
+            pad_slots = slots + dummies
+            assert len(pad_slots) == k, (k, slots, dummies)
         try:
-            with (ctx or contextlib.nullcontext()):
+            with phase("prefill"), (ctx or contextlib.nullcontext()):
                 cache_k, logits_k = self._prefill(
                     self._params, jnp.asarray(prompts), jnp.asarray(pads))
                 new_state = self._install(
@@ -804,52 +943,50 @@ class SlotDecoder:
             self._free.extend(slots)
             fail_all(e, batch)
             return
-        self.state = new_state
-        # dummy installs left remaining>0 on their free slots: zero
-        # them so the step loop never decodes an unowned slot
-        if dummies:
-            self.state = self._clear_slots(
-                self.state, jnp.asarray(dummies, jnp.int32))
-        self._counters["admitted"] += len(batch)
-        self._counters["prefill_tokens_computed"] += len(batch) * self.P
-        self._counters["prompt_tokens_submitted"] += len(batch) * self.P
-        if self.meter:
-            self.meter.prefill_tokens(len(batch) * self.P)
-        for s_, (prompt, pad, req, ev, sink, dl) in zip(slots, batch):
-            owners[s_] = (ev, sink, req, dl)
-            last_rem[s_] = req
+        with phase("admit"):
+            self.state = new_state
+            # dummy installs left remaining>0 on their free slots: zero
+            # them so the step loop never decodes an unowned slot
+            if dummies:
+                self.state = self._clear_slots(
+                    self.state, jnp.asarray(dummies, jnp.int32))
+            if self.meter:
+                self.meter.prefill_tokens(len(batch) * self.P)
+            for s_, r in zip(slots, batch):
+                self._note_admitted(r, s_, self.P, owners)
+                last_rem[s_] = r.req
 
     # -- admission: paged (per-request suffix prefill, page-gated) ---------
 
     def _admit_paged(self, owners, fail_all, last_rem, last_pos) -> None:
-        import contextlib
-
         import numpy as np
 
         jnp = self._jnp
+        phase = self._phase
         ctx = self.mesh if self.mesh is not None else None
         want = 1 if owners else self.S
         admitted = 0
         while admitted < want and self._free:
-            item = self._next_pending()
-            if item is None:
-                return
-            if not self._validate(item):
-                continue
-            prompt, pad, req, ev, sink, dl = item
-            row = [int(t) for t in prompt]
-            total = self.P + req + self.draft_k
-            if not self.alloc.can_admit(row, pad, total):
-                # head-of-line page gate: FIFO order is preserved (no
-                # bypass) — the request waits for completions to free
-                # pages, and everything behind it waits too
-                self._carry = item
-                return
-            slot = self._free.pop()
+            with phase("admit"):
+                r = self._next_pending()
+                if r is None:
+                    return
+                if not self._validate(r):
+                    continue
+                row = [int(t) for t in r.prompt]
+                total = self.P + r.req + self.draft_k
+                if not self.alloc.can_admit(row, r.pad, total):
+                    # head-of-line page gate: FIFO order is preserved (no
+                    # bypass) — the request waits for completions to free
+                    # pages, and everything behind it waits too
+                    self._carry = r
+                    return
+                slot = self._free.pop()
             try:
-                plan = self.alloc.admit(slot, row, pad, total)
-                suffix = np.asarray(row[plan.compute_start:], np.int32)
-                with (ctx or contextlib.nullcontext()):
+                with phase("admit"):
+                    plan = self.alloc.admit(slot, row, r.pad, total)
+                    suffix = np.asarray(row[plan.compute_start:], np.int32)
+                with phase("prefill"), (ctx or contextlib.nullcontext()):
                     if plan.copies:
                         self.state = self._apply_copies(
                             self.state, *self._cow_arrays(plan.copies))
@@ -857,8 +994,8 @@ class SlotDecoder:
                         self._params, self.state, suffix[None, :],
                         jnp.asarray([plan.compute_start], jnp.int32),
                         jnp.asarray(self.alloc.table[slot:slot + 1]),
-                        jnp.asarray([pad], jnp.int32),
-                        jnp.int32(slot), jnp.int32(req))
+                        jnp.asarray([r.pad], jnp.int32),
+                        jnp.int32(slot), jnp.int32(r.req))
             except Exception as e:
                 # the slot's PAGES go back before the slot id does —
                 # recycling the slot while the allocator still holds
@@ -866,34 +1003,31 @@ class SlotDecoder:
                 # RES701); free() is a no-op when admit itself raised
                 self.alloc.free(slot)
                 self._free.append(slot)
-                fail_all(e, [item])
+                fail_all(e, [r])
                 return
-            owners[slot] = (ev, sink, req, dl)
-            last_rem[slot] = req
-            last_pos[slot] = self.P
-            self._counters["admitted"] += 1
-            self._counters["prefill_tokens_computed"] += len(suffix)
-            self._counters["prompt_tokens_submitted"] += self.P
-            if self.meter:
-                self.meter.prefill_tokens(len(suffix))
-                self.meter.prefix_hits(plan.shared_pages)
-            self._publish_pages()
-            admitted += 1
+            with phase("admit"):
+                self._note_admitted(r, slot, len(suffix), owners)
+                last_rem[slot] = r.req
+                last_pos[slot] = self.P
+                if self.meter:
+                    self.meter.prefill_tokens(len(suffix))
+                    self.meter.prefix_hits(plan.shared_pages)
+                self._publish_pages()
+                admitted += 1
 
     # -- scheduler loop (speculative lockstep) -----------------------------
 
     def _loop_spec(self) -> None:
-        import contextlib
-
         import numpy as np
 
         from kubeflow_tpu.runtime.speculative import (
             greedy_accept, lockstep_propose, lockstep_verify)
 
         jnp = self._jnp
+        phase = self._phase
         k = self.draft_k
         K1 = k + 1
-        owners: dict[int, tuple] = {}    # slot -> (ev, sink, req, deadline)
+        owners: dict[int, _Request] = {}  # slot -> the request it serves
         out_h: dict[int, list] = {}      # slot -> emitted tokens
         ebuf: dict[int, list] = {}       # slot -> last round's emissions
         pos_h = np.zeros(self.S, np.int64)   # position of each cur token
@@ -902,12 +1036,8 @@ class SlotDecoder:
         ctx = self.mesh if self.mesh is not None else None
 
         def fail_all(err, batch=()):
-            for _p, _pad, _req, ev, sink, _dl in batch:
-                sink.append(err)
-                ev.set()
-            for s_, (ev, sink, _req, _dl) in list(owners.items()):
-                sink.append(err)
-                ev.set()
+            for r in (*batch, *owners.values()):
+                r.finish(err)
             owners.clear()
             out_h.clear()
             ebuf.clear()
@@ -918,10 +1048,8 @@ class SlotDecoder:
             self.d_cache = self._fresh_d_cache()
 
         def complete(slot) -> None:
-            ev, sink, _req, _dl = owners.pop(slot)
-            sink.extend(out_h.pop(slot))
+            owners.pop(slot).finish(out_h.pop(slot))
             ebuf.pop(slot, None)
-            ev.set()
             self._free.append(slot)
             self._counters["completed"] += 1
             if self.paged:
@@ -932,23 +1060,23 @@ class SlotDecoder:
             want = 1 if owners else self.S
             admitted = 0
             while admitted < want and self._free:
-                item = self._next_pending()
-                if item is None:
-                    return
-                if not self._validate(item):
-                    continue
-                prompt, pad, req, ev, sink, dl = item
-                row = [int(t) for t in prompt]
-                total = self.P + req + k
-                if self.paged:
-                    if not self.alloc.can_admit(row, pad, total):
-                        self._carry = item
+                with phase("admit"):
+                    r = self._next_pending()
+                    if r is None:
                         return
-                slot = self._free.pop()
+                    if not self._validate(r):
+                        continue
+                    row = [int(t) for t in r.prompt]
+                    total = self.P + r.req + k
+                    if self.paged:
+                        if not self.alloc.can_admit(row, r.pad, total):
+                            self._carry = r
+                            return
+                    slot = self._free.pop()
                 try:
-                    with (ctx or contextlib.nullcontext()):
+                    with phase("prefill"), (ctx or contextlib.nullcontext()):
                         if self.paged:
-                            plan = self.alloc.admit(slot, row, pad, total)
+                            plan = self.alloc.admit(slot, row, r.pad, total)
                             if plan.copies:
                                 from kubeflow_tpu.runtime.kvcache import \
                                     copy_pages
@@ -967,7 +1095,7 @@ class SlotDecoder:
                                     jnp.asarray(
                                         self.alloc.table[slot:slot + 1]),
                                     jnp.asarray([row], jnp.int32),
-                                    jnp.asarray([pad], jnp.int32),
+                                    jnp.asarray([r.pad], jnp.int32),
                                     jnp.int32(slot))
                             n_pref = len(suffix)
                             hits = plan.shared_pages
@@ -977,79 +1105,86 @@ class SlotDecoder:
                                     self._params, self._d_params,
                                     self.t_cache, self.d_cache,
                                     jnp.asarray([row], jnp.int32),
-                                    jnp.asarray([pad], jnp.int32),
+                                    jnp.asarray([r.pad], jnp.int32),
                                     jnp.int32(slot))
                             n_pref = self.P
                             hits = 0
                 except Exception as e:
                     self._free.append(slot)
-                    fail_all(e, [item])
+                    fail_all(e, [r])
                     return
-                cur = int(first)
-                owners[slot] = (ev, sink, req, dl)
-                out_h[slot] = [cur]
-                ebuf[slot] = [cur]
-                pos_h[slot] = self.P
-                rem_h[slot] = req - 1
-                pads_h[slot] = pad
-                self._counters["admitted"] += 1
-                self._counters["prefill_tokens_computed"] += n_pref
-                self._counters["prompt_tokens_submitted"] += self.P
-                if self.meter:
-                    self.meter.prefill_tokens(n_pref)
-                    if self.paged:
-                        self.meter.prefix_hits(hits)
-                self._publish_pages()
-                if rem_h[slot] <= 0:
-                    # the prefill logits already satisfied a 1-token
-                    # budget
-                    complete(slot)
-                else:
-                    admitted += 1
+                self._note_admitted(r, slot, n_pref, owners)
+                with phase("readback"):
+                    # the prefill's own first token: the host blocks on it
+                    cur = int(first)
+                with phase("admit"):
+                    self._note_first_tokens([r])
+                    out_h[slot] = [cur]
+                    ebuf[slot] = [cur]
+                    pos_h[slot] = self.P
+                    rem_h[slot] = r.req - 1
+                    pads_h[slot] = r.pad
+                    if self.meter:
+                        self.meter.prefill_tokens(n_pref)
+                        if self.paged:
+                            self.meter.prefix_hits(hits)
+                    self._publish_pages()
+                    if rem_h[slot] <= 0:
+                        # the prefill logits already satisfied a 1-token
+                        # budget
+                        complete(slot)
+                    else:
+                        admitted += 1
 
         while not self._stop:
             try:
                 admit()
-                # round-boundary deadline sweep: the canceled slot's
-                # host mirrors are dropped, so the next round simply
-                # never emits for it (caches hold only dead rows)
-                expired = self._expired_slots(owners)
-                if expired:
-                    for s_ in expired:
-                        self._cancel_slot(owners, s_)
-                        out_h.pop(s_, None)
-                        ebuf.pop(s_, None)
-                        rem_h[s_] = 0
-                    self._publish_pages()
-                self._note_active(owners)
+                with phase("admit"):
+                    # round-boundary deadline sweep: the canceled slot's
+                    # host mirrors are dropped, so the next round simply
+                    # never emits for it (caches hold only dead rows)
+                    expired = self._expired_slots(owners)
+                    if expired:
+                        for s_ in expired:
+                            self._cancel_slot(owners, s_)
+                            out_h.pop(s_, None)
+                            ebuf.pop(s_, None)
+                            rem_h[s_] = 0
+                        self._publish_pages()
+                    self._note_active(owners)
                 if not owners:
-                    self._wake.wait(timeout=0.05)
-                    self._wake.clear()
+                    with phase("idle"):
+                        self._wake.wait(timeout=0.05)
+                        self._wake.clear()
                     continue
                 # ---- one propose/verify round over every active slot
-                order = sorted(owners)
-                emitted = np.zeros((self.S, K1), np.int32)
-                starts = np.zeros(self.S, np.int32)
-                elen = np.ones(self.S, np.int32)
-                curv = np.zeros(self.S, np.int32)
-                for s_ in order:
-                    e = ebuf[s_]
-                    emitted[s_, :len(e)] = e
-                    starts[s_] = pos_h[s_] - len(e) + 1
-                    elen[s_] = len(e)
-                    curv[s_] = e[-1]
-                    if self.paged:
-                        # verify rewrites positions pos..pos+k
-                        self.alloc.append(s_, int(pos_h[s_]) + K1)
-                        copies = self.alloc.write_barrier(
-                            s_, int(pos_h[s_]), int(pos_h[s_]) + K1)
-                        if copies:
-                            from kubeflow_tpu.runtime.kvcache import \
-                                copy_pages
-                            self.t_cache = copy_pages(
-                                self.t_cache, *self._cow_arrays(copies))
-                pads_dev = jnp.asarray(pads_h)
-                with (ctx or contextlib.nullcontext()):
+                with phase("pages"):
+                    order = sorted(owners)
+                    emitted = np.zeros((self.S, K1), np.int32)
+                    starts = np.zeros(self.S, np.int32)
+                    elen = np.ones(self.S, np.int32)
+                    curv = np.zeros(self.S, np.int32)
+                    for s_ in order:
+                        e = ebuf[s_]
+                        emitted[s_, :len(e)] = e
+                        starts[s_] = pos_h[s_] - len(e) + 1
+                        elen[s_] = len(e)
+                        curv[s_] = e[-1]
+                        if self.paged:
+                            # verify rewrites positions pos..pos+k
+                            self.alloc.append(s_, int(pos_h[s_]) + K1)
+                            copies = self.alloc.write_barrier(
+                                s_, int(pos_h[s_]), int(pos_h[s_]) + K1)
+                            if copies:
+                                from kubeflow_tpu.runtime.kvcache import \
+                                    copy_pages
+                                self.t_cache = copy_pages(
+                                    self.t_cache, *self._cow_arrays(copies))
+                    pads_dev = jnp.asarray(pads_h)
+                # the draft's proposals are read back between the two
+                # dispatches: the verify chunk is built from them
+                with phase("tick", fused=0), \
+                        (ctx or contextlib.nullcontext()):
                     self.d_cache, props = lockstep_propose(
                         self.draft, self._d_params, self.d_cache,
                         jnp.asarray(emitted), jnp.asarray(starts),
@@ -1064,30 +1199,33 @@ class SlotDecoder:
                         jnp.asarray(pos_h, np.int32), pad_len=pads_dev,
                         **({"page_table": jnp.asarray(self.alloc.table)}
                            if self.paged else {}))
-                y_h = np.asarray(y)
-                round_slots = 0
-                round_accepted = 0
-                for s_ in order:
-                    a = greedy_accept(props_h[s_], y_h[s_], k)
-                    emit = [int(t) for t in props_h[s_][:a]]
-                    emit.append(int(y_h[s_][a]))
-                    take = min(len(emit), int(rem_h[s_]))
-                    emit = emit[:take]
-                    out_h[s_].extend(emit)
-                    ebuf[s_] = emit
-                    pos_h[s_] += take
-                    rem_h[s_] -= take
-                    round_slots += 1
-                    round_accepted += min(a, take)
-                    self._counters["spec_rounds"] += 1
-                    self._counters["spec_tokens_emitted"] += take
-                    self._counters["spec_tokens_accepted"] += min(a, take)
-                    self._counters["spec_drafted"] += k
-                    if rem_h[s_] <= 0:
-                        complete(s_)
-                if self.meter:
-                    self.meter.spec_round(round_slots, round_accepted)
-                self._note_active(owners)
+                self._counters["rounds"] += 1
+                with phase("readback"):
+                    y_h = np.asarray(y)
+                with phase("complete"):
+                    round_slots = 0
+                    round_accepted = 0
+                    for s_ in order:
+                        a = greedy_accept(props_h[s_], y_h[s_], k)
+                        emit = [int(t) for t in props_h[s_][:a]]
+                        emit.append(int(y_h[s_][a]))
+                        take = min(len(emit), int(rem_h[s_]))
+                        emit = emit[:take]
+                        out_h[s_].extend(emit)
+                        ebuf[s_] = emit
+                        pos_h[s_] += take
+                        rem_h[s_] -= take
+                        round_slots += 1
+                        round_accepted += min(a, take)
+                        self._counters["spec_rounds"] += 1
+                        self._counters["spec_tokens_emitted"] += take
+                        self._counters["spec_tokens_accepted"] += min(a, take)
+                        self._counters["spec_drafted"] += k
+                        if rem_h[s_] <= 0:
+                            complete(s_)
+                    if self.meter:
+                        self.meter.spec_round(round_slots, round_accepted)
+                    self._note_active(owners)
             except Exception as e:
                 log.exception("speculative slot-decoder loop failed")
                 fail_all(e)

@@ -1651,7 +1651,12 @@ class RouterFrontend:
                 if member is None:  # shed mid-wait; loop waits again
                     continue
                 hdrs: dict[str, str] = {}
-                if req.header("traceparent"):
+                # the replica's serve.predict parents on THIS dispatch
+                # (a re-dispatch is its own span), not beside it
+                span = ticket._span
+                if span is not None:
+                    hdrs["traceparent"] = span.context().to_traceparent()
+                elif req.header("traceparent"):
                     hdrs["traceparent"] = req.header("traceparent")
                 if band != BAND_DEFAULT:
                     hdrs[HEADER_BAND] = band

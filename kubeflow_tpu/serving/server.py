@@ -27,6 +27,7 @@ from typing import Any, Callable
 
 import numpy as np
 
+from kubeflow_tpu.obs import trace as obs_trace
 from kubeflow_tpu.runtime.metrics import REGISTRY as METRICS_REGISTRY
 from kubeflow_tpu.runtime.metrics import device_info
 from kubeflow_tpu.serving.router import (DeadlineExceeded, HEADER_DEADLINE,
@@ -535,7 +536,15 @@ class ModelServer:
         token = _REQUEST_DEADLINE.set(deadline)
         t0 = _time.perf_counter()
         try:
-            preds = model.predict(instances)
+            # under the request's own traceparent where it sent one, so
+            # that router.dispatch -> serve.predict -> serve.request is
+            # one tree, found by the trace id the client chose
+            with obs_trace.TRACER.span(
+                    "serve.predict",
+                    parent=obs_trace.parse_traceparent(
+                        req.headers.get("traceparent")),
+                    model=name, instances=len(instances)):
+                preds = model.predict(instances)
         except ApiHttpError:
             predict_errors().labels(name).inc()
             raise
@@ -626,36 +635,41 @@ class _ServingMesh:
         self.pad_multiple = dp
 
     def get_variables(self, model, example):
+        with self._lock:
+            if self.variables is not None:
+                return self.variables
+            with obs_trace.TRACER.span(
+                    "serve.materialize",
+                    source="checkpoint" if self.checkpoint_dir else "init",
+                    mesh=str(dict(self.mesh.shape))):
+                self.variables = self._make_variables(model, example)
+            return self.variables
+
+    def _make_variables(self, model, example):
         import jax
 
         from kubeflow_tpu.parallel import shardings as S
 
-        with self._lock:
-            if self.variables is not None:
-                return self.variables
-            rng = jax.random.PRNGKey(self.seed)
-            abstract = jax.eval_shape(
-                lambda: model.init(rng, example, train=False))
-            shardings = S.infer_shardings(abstract, self.mesh)
-            if self.checkpoint_dir:
-                from kubeflow_tpu.runtime.checkpoint import restore_variables
+        rng = jax.random.PRNGKey(self.seed)
+        abstract = jax.eval_shape(
+            lambda: model.init(rng, example, train=False))
+        shardings = S.infer_shardings(abstract, self.mesh)
+        if self.checkpoint_dir:
+            from kubeflow_tpu.runtime.checkpoint import restore_variables
 
-                host_vars, step = restore_variables(self.checkpoint_dir)
-                log.info("restored variables from %s step %d (sharded %s)",
-                         self.checkpoint_dir, step, dict(self.mesh.shape))
-                if self.param_dtype:
-                    host_vars = cast_params(host_vars, self.param_dtype)
-                self.variables = jax.device_put(S.unbox(host_vars), shardings)
-            else:
-                with self.mesh:
-                    def init_fn(r):
-                        v = S.unbox(model.init(r, example, train=False))
-                        return (cast_params(v, self.param_dtype)
-                                if self.param_dtype else v)
+            host_vars, step = restore_variables(self.checkpoint_dir)
+            log.info("restored variables from %s step %d (sharded %s)",
+                     self.checkpoint_dir, step, dict(self.mesh.shape))
+            if self.param_dtype:
+                host_vars = cast_params(host_vars, self.param_dtype)
+            return jax.device_put(S.unbox(host_vars), shardings)
+        with self.mesh:
+            def init_fn(r):
+                v = S.unbox(model.init(r, example, train=False))
+                return (cast_params(v, self.param_dtype)
+                        if self.param_dtype else v)
 
-                    self.variables = jax.jit(
-                        init_fn, out_shardings=shardings)(rng)
-            return self.variables
+            return jax.jit(init_fn, out_shardings=shardings)(rng)
 
 
 def serve_flax_classifier(name: str, model_name: str, input_key: str | None = None,
@@ -833,20 +847,35 @@ def serve_lm_generator(name: str, model_name: str, *, prompt_len: int = 128,
         # checkpoint (wrong model/vocab) crashes registration, not the
         # first routed request
         sm.get_variables(model, jnp.zeros((1, 1), jnp.int32))
+
+    # The set-up spans (serve.materialize, serve.quantize,
+    # serve.decoder_build) add no block: each ends where the host goes
+    # on, so device work still in flight then is counted where the host
+    # next waits for it (the decoder's build, or the first read-back).
+    def _quantize(v):
+        with obs_trace.TRACER.span("serve.quantize", model=name,
+                                   param_dtype=str(param_dtype)):
+            return _prepare_serving_params(v, param_dtype)
+
     variables = None
     if sm is None and checkpoint_dir:
         from kubeflow_tpu.runtime.checkpoint import restore_variables
 
-        variables, step = restore_variables(checkpoint_dir)
-        variables = _prepare_serving_params(variables, param_dtype)
+        with obs_trace.TRACER.span("serve.materialize", model=name,
+                                   source="checkpoint"):
+            variables, step = restore_variables(checkpoint_dir)
+        variables = _quantize(variables)
         log.info("model %s: restored variables from %s step %d", name,
                  checkpoint_dir, step)
 
     def _materialize(prompt_col):
         """Non-mesh variables: lazy init + serving cast/quantize — the
         ONE place uncast f32 weights could otherwise leak from."""
-        v = model.init(jax.random.PRNGKey(seed), prompt_col, train=False)
-        return _prepare_serving_params(v, param_dtype)
+        with obs_trace.TRACER.span("serve.materialize", model=name,
+                                   source="init"):
+            v = model.init(jax.random.PRNGKey(seed), prompt_col,
+                           train=False)
+        return _quantize(v)
 
     draft_box: list = []
 
@@ -954,15 +983,18 @@ def serve_lm_generator(name: str, model_name: str, *, prompt_len: int = 128,
                     dm = dv = None
                     if draft_model:
                         dm, dv = _draft()
-                    decoder_box.append(SlotDecoder(
-                        model, use_vars, slots=decode_slots,
-                        prompt_len=prompt_len,
-                        max_new_tokens=max_new_tokens,
-                        temperature=temperature, top_k=top_k, seed=seed,
-                        mesh=sm.mesh if sm is not None else None,
-                        prefix_cache=prefix_cache,
-                        draft_model=dm, draft_variables=dv,
-                        draft_k=draft_k, metrics_name=name))
+                    with obs_trace.TRACER.span("serve.decoder_build",
+                                               model=name,
+                                               slots=decode_slots):
+                        decoder_box.append(SlotDecoder(
+                            model, use_vars, slots=decode_slots,
+                            prompt_len=prompt_len,
+                            max_new_tokens=max_new_tokens,
+                            temperature=temperature, top_k=top_k, seed=seed,
+                            mesh=sm.mesh if sm is not None else None,
+                            prefix_cache=prefix_cache,
+                            draft_model=dm, draft_variables=dv,
+                            draft_k=draft_k, metrics_name=name))
             dec = decoder_box[0]
             # capture the handler thread's deadline HERE: pool.map runs
             # submit_padded on worker threads that don't inherit the
@@ -975,10 +1007,14 @@ def serve_lm_generator(name: str, model_name: str, *, prompt_len: int = 128,
             else:
                 import concurrent.futures as cf
 
+                # each row's serve.request span parents on this thread's
+                # ambient span: a copy of its context a pool thread
+                # (a Context is entered by one thread at a time)
+                ctxs = [contextvars.copy_context() for _ in rows]
                 with cf.ThreadPoolExecutor(max_workers=len(rows)) as pool:
-                    outs = list(pool.map(dec.submit_padded, rows,
-                                         pad_lens, maxnews,
-                                         [dl] * len(rows)))
+                    outs = list(pool.map(
+                        lambda c, *a: c.run(dec.submit_padded, *a),
+                        ctxs, rows, pad_lens, maxnews, [dl] * len(rows)))
             # per-request budgets produce ragged rows; pad the response
             # rows only when a caller actually mixed budgets
             if len({len(o) for o in outs}) > 1:

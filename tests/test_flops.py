@@ -71,8 +71,41 @@ class TestTransformerAnalytic:
         base = m.flops_per_token()
         with_attn = m.flops_per_token(seq_len=2048)
         cfg = m.cfg
-        want_attn = 12.0 * cfg.n_layers * cfg.n_heads * cfg.head_dim * 2048 / 2
+        # a causal query at position p sees p + 1 keys: 2049 / 2 on average
+        want_attn = 12.0 * cfg.n_layers * cfg.n_heads * cfg.head_dim * 2049 / 2
         assert with_attn - base == pytest.approx(want_attn)
+
+    def test_embedding_is_a_lookup_and_the_window_cuts_the_keys(self):
+        from kubeflow_tpu.models.registry import get_model
+
+        m = get_model("gpt-125m")
+        cfg = m.cfg
+        layer = cfg.d_model * cfg.head_dim * (2 * cfg.n_heads + 2 * cfg.n_kv_heads) \
+            + 3 * cfg.d_model * cfg.d_ff
+        assert m.flops_per_token() == pytest.approx(
+            6.0 * (cfg.n_layers * layer + cfg.vocab_size * cfg.d_model))
+        win = get_model("gpt-125m", attention_window=512)
+        full = m.flops_per_token(seq_len=2048) - m.flops_per_token()
+        cut = win.flops_per_token(seq_len=2048) - win.flops_per_token()
+        # 512 queries see 1..512 keys, the other 1536 see 512 each
+        assert cut / full == pytest.approx(
+            (512 * 513 / 2 + 1536 * 512) / (2048 * 2049 / 2))
+        # a window no shorter than the sequence changes nothing
+        assert win.flops_per_token(seq_len=512) == m.flops_per_token(seq_len=512)
+
+    def test_agrees_with_the_benchmarks_count_at_the_train_cell(self):
+        """benchmarks/lib/opcount.py states what the work needs; the
+        trainer's own MFU gauge must not disagree with model.mfu.train.
+        The widths of mistral-7b-train: 8 layers, 8,192 tokens, window
+        4,096."""
+        from benchmarks.lib import opcount, spec
+        from kubeflow_tpu.models.registry import get_model
+
+        d = spec.cell("ft-8k-1chip").dims
+        assert (d.layers, d.window) == (8, 4096)
+        m = get_model("transformer-test", max_seq_len=8192, **d.model_kwargs())
+        assert m.flops_per_token(seq_len=8192) == pytest.approx(
+            opcount.train_flops_per_token(d, 8192), rel=0.01)
 
     def test_trainer_uses_seq_aware_flops(self):
         from kubeflow_tpu.parallel.mesh import MeshSpec

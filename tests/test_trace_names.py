@@ -1,0 +1,102 @@
+"""Names the benchmark reads in the device trace are a contract: the
+readers in benchmarks/metrics/*.json find XLA modules and the flash
+kernels' custom calls by regular expressions that no program PR may
+edit. A rename must fail here, on the CPU, and not as a metric that
+turns to null on the chip."""
+
+import glob
+import json
+import os
+import re
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+REGEX_ARGS = ("module", "single", "fused", "ops")
+# what the device trace calls the four step programs
+MODULES = {"jit__tick", "jit__step_fused", "jit__paged_prefill_install",
+           "jit_train_step"}
+
+
+def patterns():
+    out = []
+    for path in sorted(glob.glob(
+            os.path.join(ROOT, "benchmarks", "metrics", "*.json"))):
+        with open(path) as f:
+            args = json.load(f).get("args", {})
+        out += [pytest.param(args[k], id=f"{os.path.basename(path)[:-5]}:{k}")
+                for k in REGEX_ARGS if k in args]
+    return out
+
+
+def module_name(jitted, *args) -> str:
+    """The XLA module's name as the trace has it: `jit_<function>`."""
+    import jax
+
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype), args)
+    return re.search(r"module @(\w+)", jitted.lower(*shapes).as_text()).group(1)
+
+
+@pytest.fixture(scope="module")
+def program_names():
+    """Every name the program gives that a reader may look for: the step
+    programs' module names, and a flash custom call as the TPU compiler
+    writes it, `%<nested jit's name>.N = <shape> custom-call(`."""
+    import jax
+    import jax.numpy as jnp
+
+    from kubeflow_tpu.models.registry import get_model
+    from kubeflow_tpu.ops.attention import local_attention
+    from kubeflow_tpu.parallel.mesh import MeshSpec, build_mesh
+    from kubeflow_tpu.runtime.trainer import TrainConfig, Trainer
+    from kubeflow_tpu.serving.continuous import SlotDecoder
+
+    model = get_model("transformer-test", vocab_size=64, max_seq_len=24,
+                      kv_pages=25, kv_page_size=4)
+    variables = model.init(jax.random.PRNGKey(0), np.zeros((1, 1), np.int32),
+                           train=False)
+    dec = SlotDecoder(model, variables, slots=3, prompt_len=8,
+                      max_new_tokens=6)
+    try:
+        table = jnp.asarray(dec.alloc.table)
+        names = {
+            module_name(dec._step, dec._params, dec.state, table),
+            module_name(dec._step_fused, dec._params, dec.state, table),
+            module_name(dec._paged_prefill_install, dec._params, dec.state,
+                        jnp.zeros((1, 8), jnp.int32),
+                        jnp.zeros((1,), jnp.int32), table[:1],
+                        jnp.zeros((1,), jnp.int32), jnp.int32(0),
+                        jnp.int32(1)),
+        }
+    finally:
+        dec.close()
+    cfg = TrainConfig.from_dict(dict(
+        model="transformer-test", task="lm", global_batch=4, seq_len=32,
+        vocab_size=256, mesh=MeshSpec(data=1), total_steps=1))
+    trainer = Trainer(cfg, mesh=build_mesh(cfg.mesh,
+                                           devices=jax.devices()[:1]))
+    names.add(module_name(trainer._train_step, trainer.abstract_state,
+                          trainer._example_batch()))
+    nested = local_attention.__wrapped__.__name__
+    names.add(f"%{nested}.7 = bf16[16,8192,128]{{2,1,0:T(8,128)(2,1)}} "
+              "custom-call(%bitcast.1, %bitcast.2, %bitcast.3), "
+              'custom_call_target="tpu_custom_call"')
+    return names
+
+
+def test_the_step_programs_keep_their_module_names(program_names):
+    assert MODULES <= program_names
+
+
+@pytest.mark.parametrize("pattern", patterns())
+def test_every_name_a_metric_reads_is_one_the_program_gives(
+        pattern, program_names):
+    assert any(re.search(pattern, name) for name in program_names), (
+        f"{pattern!r} matches none of {sorted(program_names)}: a metric "
+        "of BENCHMARK.json would read null")
+
+
+def test_the_metric_files_do_name_something():
+    assert len(patterns()) >= 8
