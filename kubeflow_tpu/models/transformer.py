@@ -248,17 +248,22 @@ class Attention(nn.Module):
         physical pool page. Writes scatter the chunk's K/V to
         (table[pos//PS], pos%PS) BEFORE attending (the full-cache
         write-then-attend discipline, so speculative verify chunks
-        self-heal identically); reads gather the slot's pages back into
-        a logical [B, MP*PS] view and run the same masked attention as
-        the dense path — token-for-token equal by construction.
+        self-heal identically). Reads take one of two paths, chosen by
+        what the call can observe (ops/paged_attention.py:use_kernel): a
+        decode tick on a TPU streams the pages that hold what each
+        slot's query sees straight out of the pool; every other call
+        gathers the slot's pages back into a logical [B, MP*PS] view and
+        runs the same masked attention as the dense path — token-for-
+        token equal by construction, and the kernel's reference.
 
         Why it's safe that the gather sees unallocated (0 = trash-page)
         table entries: the allocator guarantees every position <= the
         slot's current decode index is backed by an owned or shared
         page, so trash content is only ever visible at masked
-        (pos > qpos) positions. Idle lockstep slots have their whole
-        row zeroed at free time, steering their stale writes into the
-        trash page instead of a page another slot now owns."""
+        (pos > qpos) positions (the kernel never fetches those pages).
+        Idle lockstep slots have their whole row zeroed at free time,
+        steering their stale writes into the trash page instead of a
+        page another slot now owns."""
         cfg = self.cfg
         b, lq = q.shape[0], q.shape[1]
         hkv, hd = cfg.n_kv_heads, cfg.head_dim
@@ -281,8 +286,26 @@ class Attention(nn.Module):
         offs = flat % PS
         ck.value = ck.value.at[pages, offs].set(k_w.reshape(b * lq, hkv, hd))
         cv.value = cv.value.at[pages, offs].set(v_w.reshape(b * lq, hkv, hd))
-        # gather the logical view (reference impl: a TPU kernel would
-        # stream pages instead of materializing the gather)
+        from kubeflow_tpu.ops.paged_attention import (
+            paged_decode_attention, use_kernel)
+
+        if use_kernel(lq, ck.value.shape, ck.value.dtype):
+            # one query a slot, on a TPU: what it sees is one range of
+            # positions (causality ends it; the window and the left
+            # padding begin it: the mask below, as two integers a slot),
+            # and the kernel streams the pages that hold it out of the pool
+            last = pos_q[:, 0]
+            start = jnp.zeros_like(last)
+            if cfg.attention_window:
+                start = jnp.maximum(start, last - cfg.attention_window + 1)
+            if pad_len is not None:
+                start = jnp.maximum(start, pad_len)
+            return paged_decode_attention(
+                q[:, 0], ck.value, cv.value, page_table, start, last
+            )[:, None]
+        # the reference, and the path of prefill and verify chunks and of
+        # every backend but the TPU: gather each slot's whole table row
+        # into a logical view, then mask
         k_all = ck.value[page_table].reshape(b, MP * PS, hkv, hd)
         v_all = cv.value[page_table].reshape(b, MP * PS, hkv, hd)
         g = cfg.n_heads // hkv

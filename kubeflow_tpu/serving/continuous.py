@@ -313,6 +313,13 @@ class SlotDecoder:
             "queue_wait_s_sum": 0.0, "first_token_s_sum": 0.0,
             "first_tokens": 0,
         }
+        if self.paged:
+            # how much of the page table the plain loop's decode ticks
+            # walk (one tick = one query a slot): the pages that hold
+            # what each active slot's query sees, summed over slots and
+            # ticks, beside ticks x every entry of the table, which is
+            # what gathering the table touches
+            self._counters.update(kv_pages_walked=0, kv_pages_tabled=0)
         # the loop's host phases: phase_s.* in stats(), and kftpu.sched.*
         # annotations in the profiler's trace
         self._phase = obs_trace.PhaseClock(
@@ -429,10 +436,14 @@ class SlotDecoder:
             # advance the model one position for every slot (idle slots
             # compute too — lockstep static shape — but their state is
             # frozen by the masks below; their cache writes land in
-            # their own dead rows (dense) or the trash page (paged))
+            # their own dead rows (dense) or the trash page (paged)).
+            # An idle slot's query is made to see nothing, by padding
+            # that begins past its position: the paged attention kernel
+            # then fetches no page for it.
             logits_next, mut = model.apply(
                 params | {"cache": cache}, tok[:, None], train=False,
-                decode_index=pos, mutable=["cache"], pad_len=pads,
+                decode_index=pos, mutable=["cache"],
+                pad_len=jnp.where(active, pads, pos + 1),
                 **({"page_table": page_table}
                    if page_table is not None else {}))
             pos = jnp.where(active, pos + 1, pos)
@@ -456,9 +467,10 @@ class SlotDecoder:
         #    section 5, `sched.host_ms_per_round.*`): without an admission
         #    the host's share of a round is about 1.3 ms (page bookkeeping
         #    and the table's upload 0.6-0.7, the dispatch 0.5-0.6, completion
-        #    0.1) against a 25-26 ms tick of an 8-layer Mistral-7B, so at
-        #    that size the round-trip is 5% of a tick and fusing buys
-        #    little; an admission adds 6-15 ms of host time. Correctness is
+        #    0.1) against a tick of 3.7-5.2 ms of an 8-layer Mistral-7B,
+        #    so the round-trip is a quarter to a third of a single tick
+        #    and a thirtieth of a fused round; an admission adds 6-15 ms
+        #    of host time. Correctness is
         #    unchanged — the tick body masks on remaining>0, so a slot
         #    finishing mid-window just idles until the window ends; the
         #    cost is admission/completion latency bounded at FUSE ticks,
@@ -754,6 +766,14 @@ class SlotDecoder:
                 c["first_token_s_sum"] += now - r.t_submit
                 c["first_tokens"] += 1
 
+    def _pages_seen(self, pad: int, pos: int) -> int:
+        """Pages that hold what a query at `pos` sees behind `pad`
+        positions of left padding: the range `_decode_paged` hands the
+        paged attention kernel (models/transformer.py)."""
+        window = self.model.cfg.attention_window
+        first = max(pad, pos - window + 1) if window else pad
+        return pos // self.page_size - first // self.page_size + 1
+
     def _expired_slots(self, owners: dict) -> list[int]:
         """Active slots whose request deadline has passed."""
         now = self.clock()
@@ -841,7 +861,7 @@ class SlotDecoder:
                         # decode writes march forward: hand out the pages
                         # the window will cross (reserved at admission)
                         # and run the COW barrier over the write range
-                        for s_ in owners:
+                        for s_, r in owners.items():
                             start = int(last_pos[s_])
                             self.alloc.append(s_, start + ticks)
                             copies = self.alloc.write_barrier(
@@ -849,6 +869,11 @@ class SlotDecoder:
                             if copies:
                                 self.state = self._apply_copies(
                                     self.state, *self._cow_arrays(copies))
+                            self._counters["kv_pages_walked"] += sum(
+                                self._pages_seen(r.pad, pos)
+                                for pos in range(start, start + ticks))
+                        self._counters["kv_pages_tabled"] += (
+                            ticks * self.alloc.table.size)
                         pt = jnp.asarray(self.alloc.table)
                         args = (self._params, self.state, pt)
                     else:

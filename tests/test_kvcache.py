@@ -327,6 +327,63 @@ class TestPagedDecode:
                         max_new_tokens=4)
 
 
+class TestPagesWalkedCounters:
+    """`kv_pages_walked` / `kv_pages_tabled` in stats(): the pages that
+    hold what each active slot's query sees, tick by tick, against ticks
+    x the whole table. Pages of 4, prompt_len 8, a window of 16, 2 slots
+    with rows of 7 pages (28 positions): 14 table entries a tick."""
+
+    # (real prompt tokens, new tokens, pages walked, rounds), by hand. A
+    # request alone in the decoder fuses 8 ticks while 8 or more remain.
+    # pad 3, positions 8..27; a query at p sees max(3, p - 15)..p:
+    #   8-11: pages 0-2 = 3 each; 12-15: 4; 16-18: 5; 19 (from 4): 4;
+    #   20-22: 5; 23 (from 8): 4; 24-26: 5; 27 (from 12): 4
+    #   = 12 + 16 + 15 + 4 + 15 + 4 + 15 + 4 = 85; rounds 8 + 8 + 4 x 1
+    # pad 0, positions 8..10: pages 0-2 = 3 each = 9; three single ticks
+    # pad 6, positions 8..16 from 6 (page 1): 8-11: 2; 12-15: 3; 16: 4
+    #   = 8 + 12 + 4 = 24; rounds 8 + 1
+    SCHEDULE = [(5, 20, 85, 6), (8, 3, 9, 3), (2, 9, 24, 2)]
+
+    def test_three_request_schedule_matches_the_hand_count(self, lm):
+        from kubeflow_tpu.serving.continuous import SlotDecoder
+
+        _, variables = lm
+        pm = paged_model(kv_pages=15, kv_page_size=4, max_seq_len=28,
+                         attention_window=16)
+        dec = SlotDecoder(pm, variables, slots=2, prompt_len=8,
+                          max_new_tokens=20)
+        try:
+            assert dec.alloc.table.shape == (2, 7)
+            walked = ticks = rounds = 0
+            for real, new, pages, n_rounds in self.SCHEDULE:
+                got = dec.submit(list(range(1, real + 1)), max_new=new)
+                assert len(got) == new
+                walked, ticks = walked + pages, ticks + new
+                rounds += n_rounds
+                st = dec.stats()
+                assert st["kv_pages_walked"] == walked
+                # a fused round counts its eight ticks, not one
+                assert st["kv_pages_tabled"] == ticks * 14
+                assert st["rounds"] == rounds
+            assert (walked, ticks, rounds) == (118, 32, 11)
+        finally:
+            dec.close()
+
+    def test_dense_decoder_has_no_page_counters(self, lm):
+        from kubeflow_tpu.serving.continuous import SlotDecoder
+
+        model, variables = lm
+        dec = SlotDecoder(model, variables, slots=2, prompt_len=8,
+                          max_new_tokens=4)
+        try:
+            dec.submit([1, 2, 3])
+            st = dec.stats()
+            assert "kv_pages_walked" not in st
+            assert "kv_pages_tabled" not in st
+        finally:
+            dec.close()
+
+
 class TestSpeculativeLockstep:
     """speculative_generate's propose/verify round generalized to
     [S, k] inside SlotDecoder._tick: output must be token-for-token
