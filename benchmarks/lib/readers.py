@@ -5,9 +5,17 @@ finds nothing to read returns None and the metric is left out."""
 
 from __future__ import annotations
 
-import importlib
-
 from benchmarks.lib import spec
+
+
+def reader_of(meta: dict):
+    """The function a metric's file names: `<module>:<function>` under
+    a `metrics/` directory."""
+    mod, _, fn = meta["reader"].partition(":")
+    module = spec.load_module("metrics", mod)
+    if module is None:
+        raise KeyError(f"no metrics/{mod}.py for the reader {meta['reader']!r}")
+    return getattr(module, fn)
 
 
 def read_all(bench: dict, workload: str, ctx: dict) -> dict:
@@ -17,9 +25,7 @@ def read_all(bench: dict, workload: str, ctx: dict) -> dict:
         if "workloads" in m and workload not in m["workloads"]:
             continue
         meta = files[m["name"]]
-        mod, _, fn = meta["reader"].partition(":")
-        reader = getattr(importlib.import_module(f"benchmarks.metrics.{mod}"), fn)
-        value = reader(ctx, **meta.get("args", {}))
+        value = reader_of(meta)(ctx, **meta.get("args", {}))
         if value is not None:
             out[m["name"]] = {"value": float(value), "unit": m["unit"]}
     return out

@@ -1,30 +1,29 @@
-"""The plain reference: the published decoder (pre-RMSNorm, rotary
-embeddings in the half-split convention, grouped-query causal attention
-with an optional sliding window, SwiGLU, untied head) in jax.numpy and
-float32 at `highest` matmul precision. No kernels, no cache, no batching.
-It imports nothing of the program and takes its weights from
-benchmarks/lib/weights.py, leaf by leaf, so that it fits beside nothing.
+"""What every architecture's plain reference shares: matrix products in
+float32 at `highest` precision (or, for the control, on operands rounded
+to a lower type), RMS norm, rotary embeddings, the chunking that keeps a
+long sequence inside the memory, the weight-only quantization rule, the
+optimizer as a configuration states it, and the leaf-by-leaf follower of
+a trainer's first steps. No kernels, no cache, no batching; nothing of
+the program is imported. The equations of a model are its
+architecture's: benchmarks/arch/<name>.py.
 
 `lowp` computes every matrix product on operands rounded to a lower
 type: the control that `correct` has to fail (see PERF.md)."""
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.lib import weights as W
-from benchmarks.lib.spec import Dims
 
 HIGHEST = jax.lax.Precision.HIGHEST
 Q_BLOCK = 512       # queries attended at once
 ROW_CHUNK = 2048    # positions of the MLP and of the loss at once
 
 
-def _mm(spec, a, b, lowp=None):
+def mm(spec, a, b, lowp=None):
     if lowp is not None:
         a = a.astype(lowp).astype(jnp.float32)
         b = b.astype(lowp).astype(jnp.float32)
@@ -46,7 +45,7 @@ def rope(x, positions, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _chunk_size(n: int) -> int:
+def chunk_size(n: int) -> int:
     """The largest of ROW_CHUNK, its halves down to Q_BLOCK, or n itself,
     that divides n."""
     size = ROW_CHUNK
@@ -57,87 +56,17 @@ def _chunk_size(n: int) -> int:
     return n
 
 
-def _chunks(fn, x):
+def chunks(fn, x):
     """fn over row chunks of x [n, ...]; nothing of a chunk is kept for
     the backward pass but its input."""
     n = x.shape[0]
-    size = _chunk_size(n)
+    size = chunk_size(n)
     xs = x.reshape((n // size, size) + x.shape[1:])
     ys = jax.lax.map(jax.checkpoint(fn), xs)
     return ys.reshape((n,) + ys.shape[2:])
 
 
-def attention(d: Dims, q, k, v, lowp=None):
-    """q [n, H, hd], k, v [n, Hkv, hd] at positions 0..n-1; causal, and
-    within the window where there is one. n is a multiple of Q_BLOCK, or less."""
-    n = q.shape[0]
-    g = d.heads // d.kv_heads
-    qg = q.reshape(n, d.kv_heads, g, d.head_dim)
-    kpos = jnp.arange(n)
-
-    def block(args):
-        qb, qpos = args
-        s = _mm("qhgd,khd->hgqk", qb, k, lowp) * (d.head_dim ** -0.5)
-        ok = kpos[None, :] <= qpos[:, None]
-        if d.window:
-            ok = ok & (kpos[None, :] > qpos[:, None] - d.window)
-        s = jnp.where(ok[None, None], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        return _mm("hgqk,khd->qhgd", p, v, lowp)
-
-    qb = min(Q_BLOCK, n)
-    if n % qb:
-        raise ValueError(f"sequence {n} is no multiple of {qb}")
-    out = jax.lax.map(
-        jax.checkpoint(block),
-        (qg.reshape(n // qb, qb, d.kv_heads, g, d.head_dim),
-         kpos.reshape(n // qb, qb)))
-    return out.reshape(n, d.heads, d.head_dim)
-
-
-def layer(d: Dims, x, w, lowp=None):
-    """One decoder layer over one sequence x [n, d_model], positions
-    0..n-1; n a multiple of Q_BLOCK, or less."""
-    n = x.shape[0]
-    pos = jnp.arange(n)
-    h = rms_norm(x, w["ln_attn"], d.norm_eps)
-    q = rope(_mm("nd,dhk->nhk", h, w["q"], lowp), pos, d.rope_theta)
-    k = rope(_mm("nd,dhk->nhk", h, w["k"], lowp), pos, d.rope_theta)
-    v = _mm("nd,dhk->nhk", h, w["v"], lowp)
-    a = attention(d, q, k, v, lowp)
-    x = x + _mm("nhk,hkd->nd", a, w["o"], lowp)
-
-    def mlp(hc):
-        gate = _mm("nd,df->nf", hc, w["gate"], lowp)
-        up = _mm("nd,df->nf", hc, w["up"], lowp)
-        return _mm("nf,fd->nd", jax.nn.silu(gate) * up, w["down"], lowp)
-
-    h = rms_norm(x, w["ln_mlp"], d.norm_eps)
-    return x + _chunks(mlp, h)
-
-
-def head_logits(d: Dims, x, ln_f, lm_head, lowp=None):
-    return _mm("nd,dv->nv", rms_norm(x, ln_f, d.norm_eps), lm_head, lowp)
-
-
-def mean_xent(d: Dims, x, ln_f, lm_head, targets, lowp=None):
-    """Mean cross-entropy over every position of x [B, T, d]."""
-    b, t, _ = x.shape
-
-    def chunk(args):
-        xc, yc = args
-        logits = head_logits(d, xc, ln_f, lm_head, lowp)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        return jnp.sum(lse - jnp.take_along_axis(
-            logits, yc[:, None], axis=-1)[:, 0])
-
-    size = _chunk_size(t)
-    xs = x.reshape(b * t // size, size, -1)
-    ys = targets.reshape(b * t // size, size)
-    return jnp.sum(jax.lax.map(jax.checkpoint(chunk), (xs, ys))) / (b * t)
-
-
-# -- weights as the configuration states them --------------------------------
+# -- weights as a quantized server holds them --------------------------------
 
 def quantize(w, bits: int, per_row: bool = False):
     """Symmetric round-to-nearest weight quantization, returned already
@@ -148,63 +77,6 @@ def quantize(w, bits: int, per_row: bool = False):
     amax = jnp.max(jnp.abs(w), axis=axes, keepdims=True)
     scale = jnp.where(amax > 0, amax / top, 1.0)
     return jnp.clip(jnp.round(w / scale), -top, top) * scale
-
-
-def served_weights(leaves: dict, bits: int | None) -> dict:
-    """A tree of leaves as a weight-only quantized server holds them:
-    matrices quantized, norm scales exact."""
-    if not bits:
-        return leaves
-    return {k: (v if v.ndim < 2 else quantize(v, bits, k == "embedding"))
-            for k, v in leaves.items()}
-
-
-# -- serving: one request's logits at its served positions --------------------
-
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
-def _served_gaps(d: Dims, n_out_max: int, bits, ctrl_bits,
-                 key, tokens, n_prompt, n_out):
-    """tokens [T]: the real prompt, then the served tokens, then padding.
-    Returns for each served token j < n_out the gap by which its reference
-    logit lies below the reference's best, and the same gap for the token
-    a forward pass at `ctrl_bits` weights would have put first."""
-
-    def forward(b):
-        top = served_weights(
-            {n: W.top_leaf(d, key, n)
-             for n in ("embedding", "ln_f", "lm_head")}, b)
-        x = top["embedding"][tokens]
-
-        def body(i, x):
-            return layer(d, x, served_weights(W.layer_leaves(d, key, i), b))
-
-        x = jax.lax.fori_loop(0, d.layers, body, x)
-        rows = n_prompt - 1 + jnp.arange(n_out_max)
-        return head_logits(d, x[rows], top["ln_f"], top["lm_head"])
-
-    logits = forward(bits)
-    served = tokens[n_prompt + jnp.arange(n_out_max)]
-    live = jnp.arange(n_out_max) < n_out
-    best = jnp.max(logits, axis=-1)
-    gap = best - jnp.take_along_axis(logits, served[:, None], -1)[:, 0]
-    out = {"gap": jnp.where(live, gap, 0.0)}
-    if ctrl_bits:
-        first = jnp.argmax(forward(ctrl_bits), axis=-1)
-        cgap = best - jnp.take_along_axis(logits, first[:, None], -1)[:, 0]
-        out["control_gap"] = jnp.where(live, cgap, 0.0)
-    return out
-
-
-def served_gaps(d: Dims, seed: int, bits, prompt, served, n_pad_to: int,
-                n_out_max: int, ctrl_bits=None) -> dict:
-    """Host entry: one finished request against the reference."""
-    toks = np.zeros(n_pad_to, np.int32)
-    toks[:len(prompt)] = prompt
-    toks[len(prompt):len(prompt) + len(served)] = served
-    out = _served_gaps(d, n_out_max, bits, ctrl_bits, W.seed_key(seed),
-                       jnp.asarray(toks), jnp.int32(len(prompt)),
-                       jnp.int32(len(served)))
-    return {k: np.asarray(v)[:len(served)] for k, v in out.items()}
 
 
 # -- training: the first steps, leaf by leaf ----------------------------------
@@ -231,17 +103,26 @@ def make_tx(train: dict):
 class TrainReference:
     """Follows the program's first steps on one device. Holds the float32
     parameters and each leaf's optimizer state; gradients exist one layer
-    at a time and are applied as soon as they are whole."""
+    at a time and are applied as soon as they are whole.
 
-    def __init__(self, d: Dims, train: dict, seed: int, lowp=None,
+    The outline is a stack of `d.layers` layers between an embedding
+    look-up and a normed head with a mean cross-entropy; what a layer
+    computes and which leaves it has are the architecture's. `model` is
+    its module: `layer(d, x, w, lowp)`, `mean_xent(d, x, ln_f, lm_head,
+    targets, lowp)`, `layer_leaves(d, key, i)`, `top_leaf(d, key, name)`
+    and `TOP_LEAVES` (embedding, final norm, head, in that order). An
+    architecture of another outline brings a follower of its own."""
+
+    def __init__(self, model, d, train: dict, seed: int, lowp=None,
                  rows=None):
-        self.d, self.lowp, self.rows = d, lowp, rows
+        self.m, self.d, self.lowp, self.rows = model, d, lowp, rows
+        self.emb, self.ln_f, self.head = model.TOP_LEAVES
         # the key is an argument of every compiled maker, never a constant
         # closed over: a constant would make each seed a new program
         self.tx = make_tx(train)
-        self._make_layer = jax.jit(lambda key, i: W.layer_leaves(d, key, i))
-        self._make_top = {n: jax.jit(lambda key, n=n: W.top_leaf(d, key, n))
-                          for n in ("embedding", "ln_f", "lm_head")}
+        self._make_layer = jax.jit(lambda key, i: model.layer_leaves(d, key, i))
+        self._make_top = {n: jax.jit(lambda key, n=n: model.top_leaf(d, key, n))
+                          for n in model.TOP_LEAVES}
         key = W.seed_key(seed)
         self.layers = [self._make_layer(key, jnp.int32(i))
                        for i in range(d.layers)]
@@ -257,15 +138,15 @@ class TrainReference:
         self._update = jax.jit(update, donate_argnums=(0, 2))
         lp = lowp
         self._fwd = jax.jit(lambda x, w: jax.vmap(
-            lambda r: layer(d, r, w, lp))(x))
+            lambda r: model.layer(d, r, w, lp))(x))
 
         def row_vjp(x, w, dy):
-            _, vjp = jax.vjp(lambda x, w: layer(d, x, w, lp), x, w)
+            _, vjp = jax.vjp(lambda x, w: model.layer(d, x, w, lp), x, w)
             return vjp(dy)
 
         self._row_vjp = jax.jit(row_vjp)
         self._top = jax.jit(jax.value_and_grad(
-            lambda x, ln_f, head, y: mean_xent(d, x, ln_f, head, y, lp),
+            lambda x, ln_f, head, y: model.mean_xent(d, x, ln_f, head, y, lp),
             argnums=(0, 1, 2)))
         self._emb_grad = jax.jit(
             lambda dx, tok, v: jnp.zeros((v, dx.shape[-1]), jnp.float32)
@@ -292,14 +173,14 @@ class TrainReference:
         if self.rows is not None:
             tokens, targets = tokens[self.rows], targets[self.rows]
         tok, tgt = jnp.asarray(tokens), jnp.asarray(targets)
-        xs = [self.top["embedding"][tok]]
+        xs = [self.top[self.emb][tok]]
         for w in self.layers:
             xs.append(self._fwd(xs[-1], w))
         loss, (dx, g_ln, g_head) = self._top(
-            xs.pop(), self.top["ln_f"], self.top["lm_head"], tgt)
+            xs.pop(), self.top[self.ln_f], self.top[self.head], tgt)
         norms: dict = {}
-        self._apply("ln_f", g_ln, self.top, "ln_f", norms)
-        self._apply("lm_head", g_head, self.top, "lm_head", norms)
+        self._apply(self.ln_f, g_ln, self.top, self.ln_f, norms)
+        self._apply(self.head, g_head, self.top, self.head, norms)
         del g_ln, g_head
         for i in reversed(range(self.d.layers)):
             x, w = xs.pop(), self.layers[i]
@@ -312,7 +193,7 @@ class TrainReference:
             for k in list(w):
                 self._apply(f"layer_{i}/{k}", gw.pop(k), w, k, norms)
         g_emb = self._emb_grad(dx, tok, self.d.vocab)
-        self._apply("embedding", g_emb, self.top, "embedding", norms)
+        self._apply(self.emb, g_emb, self.top, self.emb, norms)
         return {"loss": float(loss),
                 "grad_norm": {k: float(np.sqrt(v)) for k, v in norms.items()}}
 

@@ -1,8 +1,10 @@
-"""The two serving drivers: `serve_closed` (clients that each wait for
-their answer) and `serve_open` (arrivals on a schedule). Both drive the
-HTTP predict route of a ModelServer built with the calls that
-serving/server.py:main makes, in this process, so that the profiler sees
-the device."""
+"""What the two serving drivers share (benchmarks/drivers/serve_closed.py:
+clients that each wait for their answer; serve_open.py: arrivals on a
+schedule). Both drive the HTTP predict route of a ModelServer built with
+the calls that serving/server.py:main makes, in this process, so that the
+profiler sees the device. What knows the model's shape (the weights, the
+program's keywords, how a prediction reads as tokens, the reference and
+its comparison) is asked of the cell's architecture."""
 
 from __future__ import annotations
 
@@ -25,6 +27,18 @@ TRACE_SECONDS = 3.0    # the profiler runs for this much of the window
 WARM_LENGTHS = (7, 9)  # real lengths of the warm-up prompts, under any mix's
 
 
+def _decoders() -> list:
+    """The program's live objects that can do what the driver asks of a
+    decoder (`stats()`, `active_slots`; `state`, `_params` and
+    `variables` to free), whatever their class. The side door of ROADMAP
+    D7: `serve_lm_generator` keeps its decoder in a closure and gives no
+    accessor yet."""
+    return [o for o in gc.get_objects()
+            if str(getattr(type(o), "__module__", "")).startswith("kubeflow_tpu.")
+            and callable(getattr(type(o), "stats", None))
+            and hasattr(type(o), "active_slots")]
+
+
 class Served:
     """A live server with the benchmark's weights, and its decoder."""
 
@@ -32,13 +46,10 @@ class Served:
         import jax
 
         from kubeflow_tpu.runtime import checkpoint
-        from kubeflow_tpu.serving import continuous, server
-
-        from benchmarks.lib import weights
+        from kubeflow_tpu.serving import server
 
         self.cell, self.devices = cell, devices
-        earlier = {id(o) for o in gc.get_objects()
-                   if isinstance(o, continuous.SlotDecoder)}
+        earlier = {id(o) for o in _decoders()}
         self.serve_cfg = dict(cell.config["serve"], **overrides)
         d = cell.dims
 
@@ -46,8 +57,10 @@ class Served:
         # benchmark stands in for the checkpoint store, so that the
         # weights are its own, made on the device from the seed, and
         # nothing is written to disk. Quantization stays the server's.
+        # (The other side door of D7: `serve_lm_generator` takes no
+        # `variables=` yet.)
         def restore(directory, step=None):
-            params = weights.make_program_params(d, seed)
+            params = cell.arch.make_program_params(d, seed)
             return {"params": params}, 0
 
         real = checkpoint.restore_variables
@@ -57,7 +70,7 @@ class Served:
             self.server.register(server.serve_lm_generator(
                 MODEL, cell.config["program"]["model"],
                 checkpoint_dir="benchmark-seeded-weights",
-                **self.serve_cfg, **d.model_kwargs()))
+                **self.serve_cfg, **cell.arch.model_kwargs(cell)))
         finally:
             checkpoint.restore_variables = real
         self.svc = self.server.serve(host="127.0.0.1", port=0)
@@ -72,16 +85,15 @@ class Served:
             ok, got = self.ask(toks, min(20, self.serve_cfg["max_new_tokens"]))
             if not ok:
                 raise RuntimeError(f"warm-up request failed: {got}")
-        found = [o for o in gc.get_objects()
-                 if isinstance(o, continuous.SlotDecoder)
-                 and id(o) not in earlier]
+        found = [o for o in _decoders() if id(o) not in earlier]
         if len(found) != 1:
-            raise RuntimeError(f"expected one SlotDecoder, found {len(found)}")
+            raise RuntimeError(f"expected one new decoder, found {len(found)}")
         self.decoder = found[0]
         jax.block_until_ready(self.decoder.state)
 
     def ask(self, tokens, max_new: int, timeout: float = 120.0):
-        """(ok, new tokens or what went wrong). Never raises."""
+        """(ok, the prediction as the server returned it or what went
+        wrong). Never raises."""
         body = json.dumps({"instances": [
             {"tokens": list(tokens), "max_new_tokens": int(max_new)}]}).encode()
         req = urllib.request.Request(
@@ -136,7 +148,9 @@ def _record(rec: dict, served: Served, r: schedule.Request) -> None:
     ok, got = served.ask(r.prompt, r.max_new)
     rec["done"] = time.monotonic()
     rec["ok"] = ok
-    rec["tokens"] = got if ok else None
+    rec["prediction"] = got if ok else None
+    # the tokens that count, as the architecture reads the prediction
+    rec["tokens"] = served.cell.arch.answer_tokens(got) if ok else None
     rec["error"] = None if ok else got
 
 
@@ -239,52 +253,52 @@ def _open(served: Served, reqs, sizes, seconds, trace, compiles) -> tuple:
     return recs, win, pool, []
 
 
-def check_answers(cell: Cell, seed: int, measured: list, bits,
-                  ctrl_bits=None) -> list:
+def check_answers(cell: Cell, seed: int, measured: list) -> list:
     """The comparison that decides `correct`: a sample of the finished
-    requests, drawn from the seed, with the longest in it, each run once
-    through the plain reference."""
-    from benchmarks.lib import reference
-
-    d, serve_cfg = cell.dims, cell.config["serve"]
+    requests, drawn from the seed, with the longest in it, each given to
+    the architecture's comparison with its prompt and its prediction
+    whole. Every number that comes back judged needs a limit in the mix's
+    file: a name without one is an error, never a pass."""
     limits = cell.traffic["limits"][cell.config_name]
-    n_max = serve_cfg["max_new_tokens"]
+    vocab = cell.dims.vocab
     done = [m for m in measured if m["ok"]]
-    bad = sum(1 for m in done if len(m["tokens"]) != m["req"].max_new
-              or not all(isinstance(t, int) and 0 <= t < d.vocab
-                         for t in m["tokens"]))
+
+    def whole(m):
+        return m["tokens"] is not None and len(m["tokens"]) == m["req"].max_new
+
+    bad = sum(1 for m in done if not whole(m) or not all(
+        isinstance(t, int) and 0 <= t < vocab for t in m["tokens"]))
     checks = [("malformed_answers", float(bad), 0.0)]
-    done = [m for m in done if len(m["tokens"]) == m["req"].max_new]
+    done = [m for m in done if whole(m)]
     if not done:
-        return checks + [("served_logit_gap", float("nan"),
-                          limits["served_logit_gap"])]
+        return checks + [(name, float("nan"), lim)
+                         for name, lim in limits.items()]
     rng = np.random.default_rng(seed)
     longest = max(done, key=lambda m: len(m["req"].prompt) + m["req"].max_new)
     rest = [m for m in done if m is not longest]
     k = min(cell.traffic["check_requests"] - 1, len(rest))
     sample = [longest] + [rest[i] for i in rng.choice(len(rest), k, False)]
-    pad_to = -(-(serve_cfg["prompt_len"] + n_max) // reference.Q_BLOCK) \
-        * reference.Q_BLOCK
-    gap, cgap = 0.0, 0.0
-    for m in sample:
-        out = reference.served_gaps(
-            d, seed, bits, m["req"].prompt, m["tokens"], pad_to, n_max,
-            ctrl_bits)
-        gap = max(gap, float(out["gap"].max()))
-        if ctrl_bits:
-            cgap = max(cgap, float(out["control_gap"].max()))
-    checks.append(("served_logit_gap", gap, limits["served_logit_gap"]))
-    if ctrl_bits:
-        # reported beside the judged number, never judged itself
-        checks.append(("reference_control_gap", cgap, 1e30))
+    judged, beside = cell.arch.compare_served(cell, seed, [
+        {"prompt": m["req"].prompt, "prediction": m["prediction"]}
+        for m in sample])
+    for name, value in judged.items():
+        if name not in limits:
+            raise KeyError(
+                f"{cell.arch.__name__} compares {name!r}, and "
+                f"{cell.traffic_file} gives it no limit under "
+                f"limits[{cell.config_name!r}]")
+        checks.append((name, value, limits[name]))
+    # reported beside the judged numbers, never judged themselves
+    checks += [(name, value, 1e30) for name, value in beside.items()]
     return checks
 
 
 def run(cell: Cell, seed: int, seconds: float, trace_on: bool, t_start: float,
-        overrides: dict | None = None, require_tpu: bool = True,
+        closed: bool, overrides: dict | None = None, require_tpu: bool = True,
         break_served=None) -> dict:
-    """One run of a serving cell. `break_served` is for the tests: it is
-    given the live Served before any traffic, to plant a fault."""
+    """One run of a serving cell, `closed` loop or open. `break_served`
+    is for the tests: it is given the live Served before any traffic, to
+    plant a fault."""
     devices = harness.devices_for(cell.chips, require_tpu)
     harness.configure_cache()
     compiles = harness.CompileCounter()
@@ -293,7 +307,6 @@ def run(cell: Cell, seed: int, seconds: float, trace_on: bool, t_start: float,
     if break_served:
         break_served(served)
     trace = harness.TraceWindow(trace_on)
-    closed = traffic["driver"] == "serve_closed"
     if closed:
         sizes = [traffic["block"]] * traffic["blocks"]
     else:
@@ -322,7 +335,7 @@ def run(cell: Cell, seed: int, seconds: float, trace_on: bool, t_start: float,
                                "window: give its file more blocks")
     ok = [m for m in measured if m["ok"]]
     failed = len(measured) - len(ok)
-    out_tokens = sum(len(m["tokens"]) for m in ok)
+    out_tokens = sum(len(m["tokens"] or ()) for m in ok)
     metrics = {"setup_s": {"value": setup_s, "unit": "s"}}
     if closed:
         metrics["out_tok_per_s"] = {"value": out_tokens / window_s,
@@ -335,12 +348,8 @@ def run(cell: Cell, seed: int, seconds: float, trace_on: bool, t_start: float,
         metrics["req_latency_p90_s"] = {
             "value": harness.percentile(lat, 90), "unit": "s"}
     red = trace.reduce()
-    # the reference holds its weights as the configuration states them,
-    # whatever an override (the control) made the program do
-    ref_bits = {"int8": 8, "int4": 4}.get(cell.config["serve"].get("param_dtype"))
     t_ref = time.monotonic()
-    checks = check_answers(cell, seed, measured, ref_bits,
-                           ctrl_bits=cell.traffic.get("reference_control_bits"))
+    checks = check_answers(cell, seed, measured)
     print(f"reference: {time.monotonic() - t_ref:.1f} s", file=sys.stderr)
     checks.append(("failed_requests", float(failed), 0.0))
     ctx = {
@@ -350,7 +359,7 @@ def run(cell: Cell, seed: int, seconds: float, trace_on: bool, t_start: float,
         "stats1": win["stats1"], "samples": win["samples"],
         "slots": served.serve_cfg["decode_slots"],
         "requests": [{"prompt": len(m["req"].prompt),
-                      "out": len(m["tokens"]),
+                      "out": len(m["tokens"] or ()),
                       "late_s": m["sent"] - m.get("due", m["sent"])}
                      for m in ok],
         "padded_prompt": served.serve_cfg["prompt_len"],

@@ -28,29 +28,13 @@ def batches(seed: int, batch: int, seq_len: int, vocab: int):
         k += 1
 
 
-def leaf_names(params: dict) -> dict:
-    """The program's parameter tree flattened to the reference's names."""
-    out = {}
-    for top, sub in params.items():
-        if top.startswith("layer_"):
-            out[f"{top}/ln_attn"] = sub["ln_attn"]["scale"]
-            out[f"{top}/ln_mlp"] = sub["ln_mlp"]["scale"]
-            for n in ("q", "k", "v", "o"):
-                out[f"{top}/{n}"] = sub["attn"][n]["kernel"]
-            for n in ("gate", "up", "down"):
-                out[f"{top}/{n}"] = sub["mlp"][n]["kernel"]
-        elif top == "embedding":
-            out[top] = sub
-        else:
-            out[top] = sub.get("scale", sub.get("kernel"))
-    return out
-
-
-def first_grad_norms(optimizer: str, state) -> dict:
+def first_grad_norms(leaf_names, optimizer: str, state) -> dict:
     """Each leaf's gradient norm at step 1, worked out from the optimizer
     state after that step: adafactor's second-moment statistics start
     with decay 0, so they hold mean(g*g) over the factored axis (or g*g
-    itself for a small leaf); adamw's first moment holds 0.1 * g."""
+    itself for a small leaf); adamw's first moment holds 0.1 * g.
+    `leaf_names` is the architecture's: the program's tree flattened to
+    the reference's names."""
     import jax
     import jax.numpy as jnp
 
@@ -86,33 +70,6 @@ def first_grad_norms(optimizer: str, state) -> dict:
     return {k: float(x) for k, x in got.items()}
 
 
-def change_norms(cell: Cell, seed: int, state) -> dict:
-    """Norm of each leaf's change since the seed's first weights, a layer
-    at a time so that two copies of the model never exist."""
-    import jax
-    import jax.numpy as jnp
-
-    from benchmarks.lib import weights as W
-
-    # the key is an argument, never a constant closed over: a constant
-    # would make each seed a new program, compiled inside set-up
-    d, key = cell.dims, W.seed_key(seed)
-    params = leaf_names(state.params)
-    layer = jax.jit(lambda key, i, now: {
-        k: jnp.sqrt(jnp.sum((now[k] - v) ** 2))
-        for k, v in W.layer_leaves(d, key, i).items()})
-    out = {}
-    for i in range(d.layers):
-        now = {k.split("/")[1]: v for k, v in params.items()
-               if k.startswith(f"layer_{i}/")}
-        out.update({f"layer_{i}/{k}": float(v)
-                    for k, v in layer(key, jnp.int32(i), now).items()})
-    for n in ("embedding", "ln_f", "lm_head"):
-        out[n] = float(jax.jit(lambda key, now, n=n: jnp.sqrt(jnp.sum(
-            (now - W.top_leaf(d, key, n)) ** 2)))(key, params[n]))
-    return out
-
-
 def worst_leaf_gap(prog: dict, ref: dict, keep=None) -> float:
     """The widest gap between the program's norm of a leaf and the
     reference's, against the reference's norm of that leaf or of the
@@ -141,11 +98,10 @@ def compare(cell: Cell, prog: dict, ref: dict) -> list:
 
 
 def run_reference(cell: Cell, seed: int, lowp=None, rows=None) -> dict:
-    """The reference's readings of the first CHECK_STEPS steps."""
-    from benchmarks.lib import reference
-
+    """The reference's readings of the first CHECK_STEPS steps, from the
+    follower the cell's architecture builds."""
     tr = cell.config["trainer"]
-    ref = reference.TrainReference(cell.dims, tr, seed, lowp=lowp, rows=rows)
+    ref = cell.arch.train_reference(cell, seed, lowp=lowp, rows=rows)
     feed = batches(seed, tr["global_batch"], tr["seq_len"], cell.dims.vocab)
     out = {"loss": [], "grad_norm": None}
     try:
@@ -168,24 +124,20 @@ def build(cell: Cell, seed: int, devices, overrides: dict):
     from kubeflow_tpu.parallel.mesh import build_mesh
     from kubeflow_tpu.runtime.trainer import TrainConfig, Trainer, TrainState
 
-    from benchmarks.lib import weights
-
     tr = dict(cell.config["trainer"], **overrides)
     cfg = TrainConfig.from_dict(dict(
         tr, model=cell.config["program"]["model"], task="lm",
         vocab_size=cell.dims.vocab, seed=seed & 0x7FFFFFFF, log_every=10**9,
-        model_kwargs=dict(cell.dims.model_kwargs(),
-                          max_seq_len=tr["seq_len"],
-                          **cell.config["program"].get("model_kwargs", {}))))
+        model_kwargs=cell.arch.model_kwargs(cell, max_seq_len=tr["seq_len"])))
     trainer = Trainer(cfg, mesh=build_mesh(cfg.mesh, list(devices)))
     sh = trainer.state_shardings
     with trainer.mesh:
-        params = weights.make_program_params(cell.dims, seed, sh.params)
+        params = cell.arch.make_program_params(cell.dims, seed, sh.params)
         want = jax.tree.map(lambda a: a.shape, trainer.abstract_state.params)
         got = jax.tree.map(lambda a: a.shape, params)
         if want != got:
             raise RuntimeError("the program's parameter tree is not the one "
-                               "benchmarks/lib/weights.py makes")
+                               f"{cell.arch.__name__} makes")
         step, opt_state = jax.jit(
             lambda p: (jax.numpy.zeros((), jax.numpy.int32), trainer.tx.init(p)),
             out_shardings=(sh.step, sh.opt_state))(params)
@@ -221,8 +173,9 @@ def run(cell: Cell, seed: int, seconds: float, trace_on: bool, t_start: float,
     for k in range(1, CHECK_STEPS + 1):
         state, _ = trainer.fit(steps=k, state=state, callback=note_loss)
         if k == 1:
-            prog["grad_norm"] = first_grad_norms(cfg.optimizer, state)
-    prog["change_norm"] = change_norms(cell, seed, state)
+            prog["grad_norm"] = first_grad_norms(
+                cell.arch.leaf_names, cfg.optimizer, state)
+    prog["change_norm"] = cell.arch.change_norms(cell.dims, seed, state.params)
 
     # -- the window: one fit call; its first step is the lead-in ----------
     marks: list = []

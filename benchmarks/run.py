@@ -32,13 +32,6 @@ def parse_overrides(pairs) -> dict:
     return out
 
 
-def drivers() -> dict:
-    from benchmarks.lib import serve, train
-
-    return {"serve_closed": serve.run, "serve_open": serve.run,
-            "train_fit": train.run}
-
-
 def result_line(cell, bench: dict, res: dict, trace_on: bool) -> dict:
     """The contract's last line from a driver's result."""
     from benchmarks.lib import readers
@@ -81,7 +74,7 @@ def main(argv=None) -> int:
         import dataclasses
 
         cell = dataclasses.replace(cell, traffic=dict(cell.traffic, **mix))
-    run = drivers()[cell.traffic["driver"]]
+    run = spec.driver(cell)     # benchmarks/drivers/<the mix's driver>.py
     try:
         res = run(cell, args.seed, args.seconds, bool(args.trace), T_START,
                   overrides=overrides)

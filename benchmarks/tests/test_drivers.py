@@ -10,7 +10,8 @@ import sys
 import pytest
 
 from benchmarks import run as bench_run
-from benchmarks.lib import harness, serve, spec, train
+from benchmarks.drivers import serve_closed
+from benchmarks.lib import harness, readers, spec, train
 from benchmarks.tests import toy
 
 ROOT = spec.ROOT
@@ -103,8 +104,9 @@ def _alter_tokens(served):
 def test_altered_token_is_not_correct():
     import time
 
-    res = serve.run(toy.cell("toy-closed"), 21, 0.5, False, time.monotonic(),
-                    require_tpu=False, break_served=_alter_tokens)
+    res = serve_closed.run(toy.cell("toy-closed"), 21, 0.5, False,
+                           time.monotonic(), require_tpu=False,
+                           break_served=_alter_tokens)
     assert res["correct"] is False
     gap = dict((n, (v, lim)) for n, v, lim in res["checks"])["served_logit_gap"]
     assert gap[0] > gap[1]
@@ -115,8 +117,9 @@ def test_int4_program_is_not_correct():
     the place of the int8 the configuration states."""
     import time
 
-    res = serve.run(toy.cell("toy-closed"), 23, 0.5, False, time.monotonic(),
-                    overrides={"param_dtype": "int4"}, require_tpu=False)
+    res = serve_closed.run(toy.cell("toy-closed"), 23, 0.5, False,
+                           time.monotonic(), overrides={"param_dtype": "int4"},
+                           require_tpu=False)
     assert res["correct"] is False
     checks = {n: (v, lim) for n, v, lim in res["checks"]}
     assert checks["served_logit_gap"][0] > checks["served_logit_gap"][1]
@@ -181,22 +184,24 @@ def test_lower_precision_reference_is_not_correct():
 def test_every_listed_metric_has_a_reader_and_its_cells():
     """BENCHMARK.json against the files: each per-layer metric has a file
     whose reader imports, moves an end-to-end metric that its cells
-    report, and every cell keeps setup_s, one more end-to-end metric and
-    one per-layer metric."""
-    import importlib
-
+    report, and finds every count it needs in the architecture of each of
+    its cells; every cell keeps setup_s, one more end-to-end metric and
+    one per-layer metric, and its mix names a driver that is there."""
     bench = spec.load_json(os.path.join(ROOT, "BENCHMARK.json"))
     files = spec.metric_files()
-    cells = {w["name"] for w in bench["workloads"]}
+    cells = {w["name"]: spec.cell(w["name"], bench)   # configuration and mix
+             for w in bench["workloads"]}
     e2e = {m["name"]: set(m.get("workloads", cells)) for m in bench["end_to_end"]}
     for m in bench["per_layer"]:
-        meta = files[m["name"]]
-        mod, _, fn = meta["reader"].partition(":")
-        assert callable(getattr(
-            importlib.import_module(f"benchmarks.metrics.{mod}"), fn))
+        reader = readers.reader_of(files[m["name"]])
+        assert callable(reader)
         assert set(m["workloads"]) <= e2e[m["moves"]], m["name"]
-        assert set(m["workloads"]) <= cells
-    for w in cells:
+        assert set(m["workloads"]) <= set(cells)
+        for w in m["workloads"]:
+            for count in getattr(reader, "counts", ()):
+                assert callable(getattr(cells[w].arch, count, None)), (
+                    f"{m['name']} needs {count} of {cells[w].arch.__name__}")
+    for w, cell in cells.items():
         assert sum(w in ws for ws in e2e.values()) >= 2
         assert any(w in m["workloads"] for m in bench["per_layer"])
-        spec.cell(w, bench)     # its configuration and its mix are there
+        assert callable(spec.driver(cell))

@@ -1,4 +1,5 @@
-"""Toy cells for the tests: the real drivers on files under data/."""
+"""Toy cells for the tests: the real drivers and the `dense_gqa`
+architecture on files under data/."""
 
 import os
 
@@ -13,4 +14,4 @@ def bench() -> dict:
 
 
 def cell(name: str) -> spec.Cell:
-    return _CELL(name, bench(), os.path.join(DATA, "traffic"))
+    return _CELL(name, bench(), [DATA, spec.BENCH_DIR])
