@@ -124,6 +124,22 @@ class TransformerConfig:
     # moe_fill / moe_drop step diagnostics: fill << 1 wastes expert
     # GEMM width on padding, drop >> 0 silently zeroes token updates.
     moe_capacity_factor: float = 1.25
+    # Width of one expert's SwiGLU (0 = d_ff): fine-grained mixtures
+    # publish many narrow experts beside a dense width they never use.
+    moe_d_ff: int = 0
+    # RMSNorm over head_dim of q and of k (one scale each a layer),
+    # before the rotary embedding.
+    qk_norm: bool = False
+    # Generation by diffusion over blocks (serving/continuous.py, "the
+    # block step"): `gen_block` positions are denoised together, and a
+    # query sees every key up to the END of its own block, blocks counted
+    # from the request's first real token. 0 = one token a step, causal:
+    # the programs compiled today. `gen_steps` denoising passes fix
+    # gen_block / gen_steps positions each (0 = one position a pass);
+    # `gen_mask_id` is the token a position holds until it is fixed.
+    gen_block: int = 0
+    gen_steps: int = 0
+    gen_mask_id: int = 0
     # Pipeline parallelism: split the block stack into this many stages
     # over the `pipe` mesh axis (0/1 = no pipelining).
     pipeline_stages: int = 0
@@ -241,7 +257,8 @@ def _remat_policy(cfg: "TransformerConfig"):
 class Attention(nn.Module):
     cfg: TransformerConfig
 
-    def _decode_paged(self, q, k, v, decode_index, pad_len, page_table):
+    def _decode_paged(self, q, k, v, decode_index, pad_len, page_table,
+                      block_step=False):
         """Paged decode: the cache is a pool of [kv_pages, kv_page_size]
         position pages shared across slots; `page_table` [B, MP] maps
         each slot's logical page j (positions j*PS..(j+1)*PS-1) to a
@@ -255,6 +272,14 @@ class Attention(nn.Module):
         gathers the slot's pages back into a logical [B, MP*PS] view and
         runs the same masked attention as the dense path — token-for-
         token equal by construction, and the kernel's reference.
+
+        A block model (cfg.gen_block = B) masks by blocks: a query sees
+        the keys from its slot's first real position (`pad_len`) to the
+        end of its own block, blocks of B counted from there. Prefill
+        chunks take that mask on the gather path. `block_step` is the
+        caller's word that the chunk IS one block (B queries from the
+        block's first position): its rows then all see one range, which
+        is what the kernel takes.
 
         Why it's safe that the gather sees unallocated (0 = trash-page)
         table entries: the allocator guarantees every position <= the
@@ -289,20 +314,21 @@ class Attention(nn.Module):
         from kubeflow_tpu.ops.paged_attention import (
             paged_decode_attention, use_kernel)
 
-        if use_kernel(lq, ck.value.shape, ck.value.dtype):
-            # one query a slot, on a TPU: what it sees is one range of
-            # positions (causality ends it; the window and the left
-            # padding begin it: the mask below, as two integers a slot),
-            # and the kernel streams the pages that hold it out of the pool
-            last = pos_q[:, 0]
+        if use_kernel(lq, ck.value.shape, ck.value.dtype,
+                      one_range=block_step):
+            # on a TPU, one query a slot or the queries of one block:
+            # what they see is one range of positions (causality, or the
+            # block's end, ends it; the window and the left padding begin
+            # it: the mask below, as two integers a slot), and the kernel
+            # streams the pages that hold it out of the pool
+            last = pos_q[:, -1]
             start = jnp.zeros_like(last)
             if cfg.attention_window:
                 start = jnp.maximum(start, last - cfg.attention_window + 1)
             if pad_len is not None:
                 start = jnp.maximum(start, pad_len)
             return paged_decode_attention(
-                q[:, 0], ck.value, cv.value, page_table, start, last
-            )[:, None]
+                q, ck.value, cv.value, page_table, start, last)
         # the reference, and the path of prefill and verify chunks and of
         # every backend but the TPU: gather each slot's whole table row
         # into a logical view, then mask
@@ -315,7 +341,14 @@ class Attention(nn.Module):
             preferred_element_type=jnp.float32) * (hd ** -0.5)
         pos = jnp.arange(MP * PS)[None, None, None, None, :]
         qpos = pos_q[:, None, None, :, None]
-        mask = pos <= qpos
+        if cfg.gen_block:
+            # the last position of the query's block
+            first = (jnp.zeros((b,), jnp.int32) if pad_len is None
+                     else pad_len)[:, None, None, None, None]
+            B = cfg.gen_block
+            mask = pos <= first + ((qpos - first) // B + 1) * B - 1
+        else:
+            mask = pos <= qpos
         if cfg.attention_window:
             mask = mask & (pos > qpos - cfg.attention_window)
         if pad_len is not None:
@@ -478,7 +511,7 @@ class Attention(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, segment_ids=None, decode_index=None,
-                 pad_len=None, page_table=None):
+                 pad_len=None, page_table=None, block_step=False):
         cfg = self.cfg
         init = nn.initializers.normal(0.02)
         dense = lambda feats, names, name: nn.DenseGeneral(  # noqa: E731
@@ -493,6 +526,9 @@ class Attention(nn.Module):
         q = dense((cfg.n_heads, cfg.head_dim), (AXIS_FSDP, AXIS_MODEL, None), "q")(x)
         k = dense((cfg.n_kv_heads, cfg.head_dim), (AXIS_FSDP, AXIS_MODEL, None), "k")(x)
         v = dense((cfg.n_kv_heads, cfg.head_dim), (AXIS_FSDP, AXIS_MODEL, None), "v")(x)
+        if cfg.qk_norm:
+            q = RMSNorm(dtype=cfg.dtype, name="q_norm")(q)
+            k = RMSNorm(dtype=cfg.dtype, name="k_norm")(k)
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
         # remat anchors for the "slim" whitelist policy: saving post-rope
@@ -519,7 +555,7 @@ class Attention(nn.Module):
             # falls through to the SHARED output projection below, like
             # the rolling path — 'o' must stay single-sited
             out = self._decode_paged(q, k, v, decode_index, pad_len,
-                                     page_table)
+                                     page_table, block_step)
         elif decode_index is not None and cfg.rolling_kv_cache:
             if not cfg.attention_window:
                 raise ValueError(
@@ -783,7 +819,8 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, segment_ids=None, decode_index=None,
-                 pad_len=None, page_table=None):
+                 pad_len=None, page_table=None, block_step=False,
+                 live=None):
         cfg = self.cfg
         # "block_norm" anchors both norm outputs: they are the weight-grad
         # inputs of the q/k/v and gate/up matmuls, so saving these d-wide
@@ -792,8 +829,8 @@ class Block(nn.Module):
         ln1 = checkpoint_name(
             RMSNorm(dtype=cfg.dtype, name="ln_attn")(x), "block_norm")
         x = x + Attention(cfg, name="attn")(
-            ln1, positions, segment_ids, decode_index, pad_len, page_table
-        )
+            ln1, positions, segment_ids, decode_index, pad_len, page_table,
+            block_step)
         ln2 = checkpoint_name(
             RMSNorm(dtype=cfg.dtype, name="ln_mlp")(x), "block_norm")
         if self.use_moe:
@@ -801,7 +838,7 @@ class Block(nn.Module):
 
             mlp_out = MoEBlock(
                 cfg, capacity_factor=cfg.moe_capacity_factor,
-                name="moe")(ln2)
+                name="moe")(ln2, live)
         else:
             mlp_out = SwiGLU(cfg, name="mlp")(ln2)
         return x + mlp_out
@@ -838,7 +875,7 @@ class TransformerLM(nn.Module):
     @nn.compact
     def __call__(self, tokens, train: bool = True, segment_ids=None,
                  decode_index=None, pad_len=None, page_table=None,
-                 return_hidden=False):
+                 return_hidden=False, block_step=False):
         cfg = self.cfg
         del train  # no dropout in the speed-run configuration
         emb = self.param(
@@ -871,10 +908,16 @@ class TransformerLM(nn.Module):
             offs = jnp.arange(tokens.shape[1], dtype=jnp.int32)
             positions = (jnp.broadcast_to(idx + offs, tokens.shape)
                          if idx.ndim == 0 else idx[:, None] + offs[None, :])
+            # left padding, and an idle slot's whole chunk (the decoder
+            # gives it padding that begins past its position), are no
+            # tokens: a mixture layer routes none of them
+            live = (None if pad_len is None or not cfg.moe_every
+                    else positions >= pad_len[:, None])
             for i in range(cfg.n_layers):
                 use_moe = cfg.moe_every > 0 and (i + 1) % cfg.moe_every == 0
                 x = Block(cfg, use_moe=use_moe, name=f"layer_{i}")(
-                    x, positions, None, decode_index, pad_len, page_table)
+                    x, positions, None, decode_index, pad_len, page_table,
+                    block_step, live)
             x = RMSNorm(dtype=cfg.dtype, name="ln_f")(x)
             return LMHead(cfg, name="lm_head")(x)
         positions = jnp.broadcast_to(
@@ -940,8 +983,10 @@ class TransformerLM(nn.Module):
         mlp = 3 * cfg.d_model * cfg.d_ff          # SwiGLU: gate+up+down
         n_moe = (cfg.n_layers // cfg.moe_every) if cfg.moe_every else 0
         n_dense = cfg.n_layers - n_moe
-        # MoE layer: top_k expert MLPs execute per token, plus the router
-        moe = cfg.expert_top_k * mlp + cfg.d_model * cfg.n_experts
+        # MoE layer: top_k expert MLPs (of the experts' own width) execute
+        # per token, plus the router
+        expert = 3 * cfg.d_model * (cfg.moe_d_ff or cfg.d_ff)
+        moe = cfg.expert_top_k * expert + cfg.d_model * cfg.n_experts
         head = cfg.vocab_size * cfg.d_model
         flops = 6.0 * (cfg.n_layers * attn + n_dense * mlp + n_moe * moe
                        + head)

@@ -1,13 +1,24 @@
 """Mixture-of-experts with expert parallelism.
 
-Two dispatch implementations behind one module:
+Three dispatch implementations behind one module:
+
+- **dropless** (sort + grouped matmul, one device): the routed (token,
+  expert) pairs are sorted by expert into contiguous groups and each of
+  gate, up and down is ONE grouped matmul over the groups
+  (`jax.lax.ragged_dot`): no capacity, no padding, no one-hot tensors,
+  nothing dropped (`moe_drop` reads 0 by construction), and an expert no
+  pair visits is never read. Padding rows (`live` false) are routed
+  nowhere. What `moe_impl="auto"` takes without a mesh or on a mesh of
+  one device: the path of a served model, many narrow experts and few
+  rows a group.
 
 - **dense** (Switch/GShard one-hot einsums): dispatch/combine are einsums
   against one-hot [b,s,e,c] tensors. Correct on any mesh, runs the whole
   block on the MXU — and materializes capacity-padded tensors whose
   dispatch/combine einsums cost O(s*e*c*d) MACs regardless of how many
-  slots are filled. Kept as the oracle and as the fallback for meshes the
-  sparse path doesn't cover.
+  slots are filled, and a capacity that DROPS what overflows it. Kept as
+  the oracle (`moe_impl="dense"`) and as the fallback for meshes of
+  several devices that the sparse path doesn't cover.
 
 - **sparse** (sort + scatter + explicit all-to-all under shard_map): per
   token-shard, routed (token, slot) pairs are sorted by expert id,
@@ -27,7 +38,19 @@ every dense layer's compute ep-fold.
 
 Per-step diagnostics are sowed into the "diagnostics" collection:
   moe_fill — filled fraction of expert capacity slots (1 - padding);
-  moe_drop — fraction of routed (token, slot) pairs dropped to overflow.
+  moe_drop — fraction of routed (token, slot) pairs dropped to overflow;
+and, as int32 counts of this call (of the router's choice before any
+capacity; the dropless path leaves padding rows out):
+  moe_pairs — routed pairs of live tokens;
+  moe_expert_visits — experts that got at least one pair (the groups the
+    grouped matmul reads weights for);
+  moe_load_max — the fullest expert's pairs.
+
+In the device trace the dropless path's expert matmuls are the compiler's
+grouped-matmul custom calls, `%ragged-dot-none.N = ... custom-call(`
+(EXPERT_MATMUL_TRACE_NAME below; tests/test_trace_names.py). The router is
+a plain matrix product that XLA fuses: a fusion carries no name of the
+program's in the trace, and its time is read as part of the step's.
 
 Reference framework has no MoE (SURVEY.md §2.5 "Expert parallelism:
 Absent"); this is TPU-native net-new capability.
@@ -52,6 +75,12 @@ from kubeflow_tpu.parallel.mesh import (
 )
 
 
+# What the device trace calls the dropless path's three grouped matmuls
+# (XLA:TPU's own kernel for `jax.lax.ragged_dot`): the benchmark's
+# `moe.expert_roofline.*` finds them by it.
+EXPERT_MATMUL_TRACE_NAME = "ragged-dot"
+
+
 def _router(cfg, x, init):
     """Top-k routing (f32 softmax). Returns (probs [b,s,e],
     gate_vals [b,s,k] renormalized, gate_idx [b,s,k])."""
@@ -72,6 +101,36 @@ def _expert_mlp(cfg, xin, w_gate, w_up, w_down):
     h = nn.silu(jnp.einsum("etd,edf->etf", xin, w_gate.astype(cfg.dtype))) * \
         jnp.einsum("etd,edf->etf", xin, w_up.astype(cfg.dtype))
     return jnp.einsum("etf,efd->etd", h, w_down.astype(cfg.dtype))
+
+
+def dropless_mlp(cfg, x, gate_vals, gate_idx, w_gate, w_up, w_down,
+                 live=None):
+    """Sort by expert, one grouped matmul each for gate, up and down over
+    the contiguous groups, combine with the gates. x [t, d] flattened
+    tokens, gate_* [t, k], weights [e, ...], `live` [t] bool or None
+    (padding rows are routed nowhere and come back as zeros). Returns
+    (y [t, d], counts [e]): the pairs each expert got."""
+    t, d = x.shape
+    k = gate_idx.shape[-1]
+    e = w_gate.shape[0]
+    eidx = gate_idx.reshape(-1)                      # [t*k]
+    if live is not None:
+        # a dead pair sorts behind every group and belongs to none
+        eidx = jnp.where(jnp.repeat(live, k), eidx, e)
+    order = jnp.argsort(eidx)                        # stable
+    counts = jnp.zeros((e + 1,), jnp.int32).at[eidx].add(1)[:e]
+    xs = x[order // k].astype(cfg.dtype)
+    wg, wu, wd = (w.astype(cfg.dtype) for w in (w_gate, w_up, w_down))
+    h = nn.silu(jax.lax.ragged_dot(xs, wg, counts)) * \
+        jax.lax.ragged_dot(xs, wu, counts)
+    out = jax.lax.ragged_dot(h, wd, counts)          # [t*k, d], sorted
+    # rows behind the last group are no group's: whatever they hold
+    out = jnp.where((eidx[order] < e)[:, None], out, 0)
+    # back to (token, slot) order, then the gates' weighted sum
+    out = out[jnp.argsort(order)].reshape(t, k, d)
+    y = jnp.einsum("tkd,tk->td", out.astype(jnp.float32),
+                   gate_vals.astype(jnp.float32))
+    return y.astype(cfg.dtype), counts
 
 
 def sparse_dispatch_mlp(cfg, x_local, gate_vals, gate_idx, w_gate, w_up,
@@ -148,6 +207,12 @@ class MoEBlock(nn.Module):
     cfg: "TransformerConfig"  # noqa: F821 — structural typing, avoids cycle
     capacity_factor: float = 1.25
 
+    def _dropless_ok(self, mesh) -> bool:
+        """One device holds every expert and every token: nothing to
+        exchange, so nothing needs a capacity."""
+        return (getattr(self.cfg, "moe_impl", "auto") == "auto"
+                and (mesh is None or mesh.size == 1))
+
     def _sparse_ok(self, mesh) -> bool:
         impl = getattr(self.cfg, "moe_impl", "auto")
         if impl == "dense":
@@ -179,32 +244,54 @@ class MoEBlock(nn.Module):
         return ok
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(self, x: jax.Array, live=None) -> jax.Array:
+        """`live` [b, s] bool: rows that are tokens (None = all). Only
+        the dropless path reads it; the capacity paths serve training
+        meshes, whose rows are all tokens."""
         cfg = self.cfg
         b, s, d = x.shape
         e, k = cfg.n_experts, cfg.expert_top_k
+        d_ff = cfg.moe_d_ff or cfg.d_ff
         init = nn.initializers.normal(0.02)
 
         probs, gate_vals, gate_idx = _router(cfg, x, init)
 
         w_gate = self.param(
             "w_gate", nn.with_partitioning(init, (AXIS_EXPERT, AXIS_FSDP, AXIS_MODEL)),
-            (e, d, cfg.d_ff), jnp.float32)
+            (e, d, d_ff), jnp.float32)
         w_up = self.param(
             "w_up", nn.with_partitioning(init, (AXIS_EXPERT, AXIS_FSDP, AXIS_MODEL)),
-            (e, d, cfg.d_ff), jnp.float32)
+            (e, d, d_ff), jnp.float32)
         w_down = self.param(
             "w_down", nn.with_partitioning(init, (AXIS_EXPERT, AXIS_MODEL, AXIS_FSDP)),
-            (e, cfg.d_ff, d), jnp.float32)
+            (e, d_ff, d), jnp.float32)
 
         mesh = current_mesh()
-        use_sparse = self._sparse_ok(mesh)
-        if use_sparse:
+        dropless = self._dropless_ok(mesh)
+        use_sparse = not dropless and self._sparse_ok(mesh)
+        if dropless:
+            y, counts = dropless_mlp(
+                cfg, x.reshape(b * s, d), gate_vals.reshape(b * s, k),
+                gate_idx.reshape(b * s, k), w_gate, w_up, w_down,
+                None if live is None else live.reshape(b * s))
+            y = y.reshape(b, s, d)
+            kept = routed = slots = jnp.sum(counts)
+        elif use_sparse:
             y, kept, routed, slots = self._sparse(
                 x, gate_vals, gate_idx, w_gate, w_up, w_down, mesh)
         else:
             y, kept, routed, slots = self._dense(
                 x, gate_vals, gate_idx, w_gate, w_up, w_down)
+        if not dropless:
+            # the router's choice, before any capacity: the same three
+            # counts from every path, so that a program traced without
+            # its mesh (init) and one traced under it sow one structure
+            counts = jnp.zeros((e,), jnp.int32).at[
+                gate_idx.reshape(-1)].add(1)
+        self.sow("diagnostics", "moe_pairs", jnp.sum(counts))
+        self.sow("diagnostics", "moe_expert_visits",
+                 jnp.sum((counts > 0).astype(jnp.int32)))
+        self.sow("diagnostics", "moe_load_max", jnp.max(counts))
         # Ground truth for which dispatch path actually ran (ADVICE r4):
         # _sparse_ok silently falls back to dense on a meshless trace, so
         # a run labeled 'sparse' could measure dense with nothing in the

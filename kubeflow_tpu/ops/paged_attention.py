@@ -1,5 +1,5 @@
-"""Pallas TPU paged decode attention: one query a slot, read straight out
-of the page pool.
+"""Pallas TPU paged decode attention: one query a slot, or the queries of
+one block a slot, read straight out of the page pool.
 
 The paged KV cache (runtime/kvcache.py) is a pool `[kv_pages, page_size,
 kv_heads, head_dim]` and a table `[slots, max_pages]` of the pool pages
@@ -8,7 +8,11 @@ query, at position `last[b]`, that sees positions `start[b]..last[b]`
 (left padding and the sliding window both only raise `start`). The
 kernel walks the pages `start // PS .. last // PS` of the slot's table
 row and nothing else: an idle slot (`start > last`) fetches no page and
-gives zeros.
+gives zeros. A block model's step (models/transformer.py `gen_block`)
+has `lq` queries a slot that all see that one range, the block's end
+being `last`: they go through the same walk as `lq x heads` query rows
+against each streamed block of pages. A chunk whose rows see different
+ranges (prefill, speculative verify) is not this kernel's.
 
 How it reads the pool as it is. A page `[PS, kv_heads, head_dim]` is one
 contiguous block and is fetched by one DMA for K and one for V,
@@ -60,20 +64,24 @@ PAGES_PER_BLOCK = 16
 PAGED_HEAD_DIMS = (128, 256)
 
 
-def use_kernel(lq: int, pool_shape, pool_dtype) -> bool:
+def use_kernel(lq: int, pool_shape, pool_dtype,
+               one_range: bool = False) -> bool:
     """Whether a paged decode step runs the kernel or the gather path,
     from what the code can observe, logged with the reason (the rule
     `ops/attention.py:resolve_impl` uses for flash): the kernel for one
-    query a slot on a TPU backend, a pool `[pages, PS, kv_heads,
-    head_dim]` whose pages it tiles, and a pool that lives whole on one
-    device; the gather path for chunks (prefill, speculative verify),
-    off the TPU, and under a mesh of several devices, where the pool is
-    sharded over kv heads."""
+    query a slot, or for a chunk whose rows all see one range
+    (`one_range`: a block model's step), on a TPU backend, a pool
+    `[pages, PS, kv_heads, head_dim]` whose pages it tiles, and a pool
+    that lives whole on one device; the gather path for chunks whose
+    rows see different ranges (prefill, speculative verify), off the
+    TPU, and under a mesh of several devices, where the pool is sharded
+    over kv heads."""
     backend = jax.default_backend()
     mesh = current_mesh()
     kv_heads, head_dim = pool_shape[-2:]
     pack = max(1, 4 // jnp.dtype(pool_dtype).itemsize)
-    if lq != 1:
+    if lq != 1 and not one_range:
+        # (a chunk whose rows see different ranges)
         choice, why = "gather", f"a chunk of {lq} queries a slot"
     elif backend != "tpu":
         choice, why = "gather", f"default backend is {backend!r}, not tpu"
@@ -86,7 +94,10 @@ def use_kernel(lq: int, pool_shape, pool_dtype) -> bool:
     elif mesh is not None and mesh.size > 1:
         choice, why = "gather", f"mesh of {mesh.size} devices shards the pool"
     else:
-        choice, why = "kernel", f"tpu backend, head_dim {head_dim}"
+        choice, why = "kernel", (
+            f"tpu backend, head_dim {head_dim}, "
+            + ("one query a slot" if lq == 1
+               else f"a block of {lq} queries a slot that see one range"))
     log.info("paged decode: paged attention -> %s (%s)", choice, why)
     return choice == "kernel"
 
@@ -94,9 +105,9 @@ def use_kernel(lq: int, pool_shape, pool_dtype) -> bool:
 def _kernel(pt_ref, start_ref, last_ref,     # scalar prefetch
             q_ref, k_hbm, v_hbm, o_ref,
             kbuf, vbuf, sems, buf_ref, m_s, l_s, acc_s,
-            *, scale: float, group: int):
+            *, scale: float, group: int, q_heads: int, lq: int):
     _, ppb, ps, hkv, hd = kbuf.shape
-    heads = q_ref.shape[1]
+    heads = q_ref.shape[1]                   # query rows: lq x q_heads, padded
     rows = ppb * ps * hkv                    # (position, kv head) rows a block
     b, nb = pl.program_id(0), pl.num_programs(0)
 
@@ -157,7 +168,9 @@ def _kernel(pt_ref, start_ref, last_ref,     # scalar prefetch
 
     row = jax.lax.broadcasted_iota(jnp.int32, (heads, rows), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (heads, rows), 1)
-    own_head = jax.lax.div(row, group) == jax.lax.rem(col, hkv)
+    # a block's query rows lie query by query, each with every head
+    head = row if lq == 1 else jax.lax.rem(row, q_heads)
+    own_head = jax.lax.div(head, group) == jax.lax.rem(col, hkv)
 
     def attend(blk, x, edge: bool):
         q = q_ref[0]                                         # [heads, hd]
@@ -212,27 +225,40 @@ def _kernel(pt_ref, start_ref, last_ref,     # scalar prefetch
     o_ref[0] = (acc_s[...] / jnp.maximum(l_s[...], 1e-20)).astype(o_ref.dtype)
 
 
+# The custom call's names in the device trace (`%<name>.N = ...
+# custom-call(`): one query a slot, and a block of queries a slot. The
+# benchmark finds the kernels by them (tests/test_trace_names.py).
+KERNEL_NAME = "paged_decode_attention"
+BLOCK_KERNEL_NAME = "paged_block_attention"
+
+
 def paged_decode_attention(q, k_pages, v_pages, page_table, start, last):
-    """q [b, heads, hd]; k_pages, v_pages [kv_pages, PS, kv_heads, hd]
-    (they stay in HBM, in that layout); page_table [b, MP], start [b],
-    last [b] int32. Returns [b, heads, hd]: slot b's query over the
-    positions start[b]..last[b] of its pages, zeros where start > last.
-    Every table entry of the pages that hold those positions must be a
-    page of the pool; entries outside them are never read."""
-    return _call(q, k_pages, v_pages, page_table, start, last,
-                 interpret=interpret_mode())
+    """q [b, heads, hd], or [b, lq, heads, hd] for the lq queries of a
+    block; k_pages, v_pages [kv_pages, PS, kv_heads, hd] (they stay in
+    HBM, in that layout); page_table [b, MP], start [b], last [b] int32.
+    Returns q's shape: each of slot b's queries over the positions
+    start[b]..last[b] of its pages, zeros where start > last. Every
+    table entry of the pages that hold those positions must be a page of
+    the pool; entries outside them are never read."""
+    lq = q.shape[1] if q.ndim == 4 else 1
+    out = _call(q.reshape(q.shape[0], -1, q.shape[-1]), k_pages, v_pages,
+                page_table, start, last, lq=lq, interpret=interpret_mode())
+    return out.reshape(q.shape)
 
 
 # One jit for every layer's call: a model's layers share the shapes, so
 # the kernel is traced once a process and lowered once a program, not
 # once a layer (0.3 s a trace on the chip's host, my chip run, PR 27;
 # the tick and the fused round call it 16 times, and set-up is measured).
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _call(q, k_pages, v_pages, page_table, start, last, *, interpret: bool):
-    b, heads, hd = q.shape
+@functools.partial(jax.jit, static_argnames=("lq", "interpret"))
+def _call(q, k_pages, v_pages, page_table, start, last, *, lq: int,
+          interpret: bool):
+    b, heads, hd = q.shape                   # heads: lq x the query heads
     _, ps, hkv, _ = k_pages.shape
-    if heads % hkv:
-        raise ValueError(f"{heads} query heads do not group over {hkv} kv heads")
+    q_heads = heads // lq
+    if q_heads % hkv:
+        raise ValueError(
+            f"{q_heads} query heads do not group over {hkv} kv heads")
     # the query rows fill whole sublane tiles of the pool's dtype; rows
     # added here match no kv head and are cut off again below
     tile = 8 * 4 // q.dtype.itemsize
@@ -241,7 +267,8 @@ def _call(q, k_pages, v_pages, page_table, start, last, *, interpret: bool):
     q_spec = pl.BlockSpec((1, padded, hd), lambda i, *_: (i, 0, 0))
     buf = pltpu.VMEM((2, PAGES_PER_BLOCK, ps, hkv, hd), k_pages.dtype)
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=hd ** -0.5, group=heads // hkv),
+        functools.partial(_kernel, scale=hd ** -0.5, group=q_heads // hkv,
+                          q_heads=q_heads, lq=lq),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b,),
@@ -261,7 +288,7 @@ def _call(q, k_pages, v_pages, page_table, start, last, *, interpret: bool):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="paged_decode_attention",
+        name=KERNEL_NAME if lq == 1 else BLOCK_KERNEL_NAME,
     )(page_table.astype(jnp.int32), start.astype(jnp.int32),
       last.astype(jnp.int32), qp, k_pages, v_pages)
     return out[:, :heads]

@@ -148,6 +148,11 @@ def generate(model, variables, prompt: jax.Array, *,
     samples). Returns [B, Lp + N].
     """
     b, lp = prompt.shape
+    if getattr(model.cfg, "gen_block", 0):
+        raise ValueError(
+            "generate() decodes one token a step under a causal mask; a "
+            "block model (gen_block > 0) is served by "
+            "serving/continuous.py:SlotDecoder over the paged KV cache")
     check_decode_geometry(model, lp, max_new_tokens)
     params = {"params": variables["params"]}
     cache = init_cache(model, b)

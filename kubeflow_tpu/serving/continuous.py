@@ -35,6 +35,18 @@ Three per-replica speed levers compose on top of the slot machinery
   already uses, and output stays token-for-token equal to plain
   greedy decode.
 
+Beside the three levers, **the block step**: a model that generates by
+diffusion over blocks (cfg.gen_block = B > 0, models/transformer.py)
+takes another kind of step in the same loop. Each slot holds one block
+of B positions, MASK where nothing is fixed yet; a tick is one pass over
+every slot's block against the committed cache (write, then attend,
+under the block-causal mask), which fixes the most confident masked
+positions, and a pass over a block with no MASK left commits it: its
+tokens go to the output, the slot moves B positions on and opens the
+next block. Slots sit at different steps of their blocks in one lockstep
+program. What kind of step a model takes is read from its config; the
+scheduler, admission and the page allocator are the same.
+
 Single-host scheduler; the decode/prefill programs themselves run under
 whatever mesh the variables are sharded over.
 """
@@ -165,6 +177,17 @@ class _DecodeMeter:
 SCHED_PHASES = ("admit", "prefill", "pages", "tick", "readback",
                 "complete", "idle")
 
+# What a block model's pass counts on the device, in this order, over the
+# slots that hold a request (serving/continuous.py `_block_pass`):
+# passes x active slots; blocks committed; the pages from each slot's
+# first real position to its block's end (what the pass's attention
+# walks: `kv_pages_walked`); and from the mixture layers (ops/moe.py),
+# summed over layers: the routed pairs, the experts that got at least one
+# (each a group whose weights the grouped matmul reads) and the fullest
+# expert's pairs.
+BLOCK_COUNTERS = ("block_passes", "blocks_committed", "kv_pages_walked",
+                  "moe_pairs", "moe_expert_visits", "moe_load_max")
+
 # A request's stamps, and the waits summed from them, are observability
 # payload on the spans' clock; no decision reads them (deadlines run on
 # the injectable self.clock).
@@ -180,7 +203,7 @@ class _Request:
 
     __slots__ = ("prompt", "pad", "req", "ev", "sink", "deadline",
                  "t_submit", "t_admit", "t_first", "t_done", "slot",
-                 "prefill_tokens")
+                 "prefill_tokens", "fixed_at", "blocks", "passes")
 
     def __init__(self, prompt, pad: int, req: int, deadline):
         self.prompt, self.pad, self.req = prompt, pad, req
@@ -191,6 +214,10 @@ class _Request:
         self.t_admit = self.t_first = self.t_done = 0.0
         self.slot = -1
         self.prefill_tokens = 0
+        # a block model's: the denoising step at which each token was
+        # fixed, the blocks the answer lies in and the passes it took
+        self.fixed_at: list | None = None
+        self.blocks = self.passes = 0
 
     def finish(self, result) -> None:
         """The one exit: tokens or the error into the sink, the done
@@ -228,6 +255,11 @@ class SlotDecoder:
       uncached suffix.
     - speculative (draft_model given): greedy-only lockstep
       propose/verify rounds; composes with dense or paged target.
+    - block (the model's cfg.gen_block > 0; paged, greedy, no draft):
+      a tick is one denoising or committing pass over every slot's
+      block of gen_block positions, and `submit` returns
+      ``{"tokens": [...], "fixed_at": [...]}``, the step of its block
+      at which each token was fixed.
     """
 
     def __init__(self, model, variables, *, slots: int = 8,
@@ -259,8 +291,43 @@ class SlotDecoder:
         self.spec = draft_model is not None
         self.draft_k = draft_k if self.spec else 0
         self.paged = bool(getattr(model.cfg, "kv_pages", 0))
+        # a block model's step (cfg.gen_block > 0): B positions a slot,
+        # `per` of them fixed a pass
+        self.B = B = int(getattr(model.cfg, "gen_block", 0) or 0)
+        if B:
+            steps = getattr(model.cfg, "gen_steps", 0) or B
+            if B % steps:
+                raise ValueError(f"gen_block {B} is no multiple of "
+                                 f"gen_steps {steps}")
+            self._per = B // steps
+            if not self.paged:
+                raise ValueError(
+                    "a block model (gen_block > 0) is served through the "
+                    "paged KV cache only: its block-causal mask lives in "
+                    "the paged decode path (build it with kv_pages and "
+                    "kv_page_size)")
+            if self.spec:
+                raise ValueError(
+                    "a block model (gen_block > 0) takes no draft_model: "
+                    "speculative verify is a causal chunk, a block step "
+                    "is not")
+            if temperature != 0.0:
+                raise ValueError(
+                    "a block model (gen_block > 0) is decoded greedily "
+                    "(temperature must be 0): sampling the fixed tokens "
+                    "is not there yet")
+            if getattr(model.cfg, "attention_window", 0):
+                raise ValueError(
+                    "a block model (gen_block > 0) with attention_window: "
+                    "the queries of a block would see different ranges")
+        elif self.spec and getattr(draft_model.cfg, "gen_block", 0):
+            raise ValueError("a block model (gen_block > 0) cannot draft "
+                             "for a one-token model")
+        # positions a slot may touch past its last token: the verify
+        # chunk's overhang, or the rest of the answer's last block
+        self._overhang = B if B else self.draft_k
         check_decode_geometry(model, prompt_len,
-                              max_new_tokens + self.draft_k)
+                              max_new_tokens + self._overhang)
         if self.spec:
             if temperature != 0.0:
                 raise ValueError("speculative lockstep decode is "
@@ -278,8 +345,8 @@ class SlotDecoder:
             check_decode_geometry(draft_model, prompt_len,
                                   max_new_tokens + draft_k)
         # a slot's worst-case sequence: prompt + its budget + the
-        # speculative verify chunk's overhang past the last token
-        self._total_len = prompt_len + max_new_tokens + self.draft_k
+        # overhang past the last token
+        self._total_len = prompt_len + max_new_tokens + self._overhang
         if self.paged:
             cfg = model.cfg
             self.page_size = cfg.kv_page_size
@@ -320,6 +387,10 @@ class SlotDecoder:
             # ticks, beside ticks x every entry of the table, which is
             # what gathering the table touches
             self._counters.update(kv_pages_walked=0, kv_pages_tabled=0)
+        if B:
+            # the block step, counted on the device and read back with
+            # `remaining` (BLOCK_COUNTERS says what each counts)
+            self._counters.update(dict.fromkeys(BLOCK_COUNTERS, 0))
         # the loop's host phases: phase_s.* in stats(), and kftpu.sched.*
         # annotations in the profiler's trace
         self._phase = obs_trace.PhaseClock(
@@ -389,16 +460,37 @@ class SlotDecoder:
         #    module `jit__paged_prefill_install` by it
         #    (benchmarks/metrics/*.json; tests/test_trace_names.py) ------
         def _paged_prefill_install(params, state, toks, start, pt_row,
-                                   pad, slot, req_n):
+                                   pad, slot, req_n, block=None):
             cache, last, pos, remaining, out, pads, req, rng = state
             logits, mut = model.apply(
                 params | {"cache": cache}, toks, train=False,
                 decode_index=start, mutable=["cache"], pad_len=pad,
                 page_table=pt_row)
             cache = mut["cache"]
-            last = jax.lax.dynamic_update_slice(
-                last, logits[:, -1], (slot, 0))
-            pos = _set1(jnp, pos, slot, self.P)
+            if block is None:
+                last = jax.lax.dynamic_update_slice(
+                    last, logits[:, -1], (slot, 0))
+                first_pos = self.P
+            else:
+                # a block model: the prompt's whole blocks are committed
+                # by this pass (no position sees a later block); the
+                # tokens behind them (`tail` [B], the first `n_tail`
+                # real) open the slot's first block as fixed, and the
+                # block steps write those positions anew
+                tail, n_tail = block
+                mine = jnp.arange(self.S) == slot
+                blk = dict(last)
+                blk["tok"] = jnp.where(mine[:, None], tail[None, :],
+                                       blk["tok"])
+                blk["fixed"] = jnp.where(
+                    mine[:, None], (jnp.arange(B) < n_tail)[None, :],
+                    blk["fixed"])
+                blk["at"] = jnp.where(mine[:, None], 0, blk["at"])
+                blk["step"] = jnp.where(mine, 0, blk["step"])
+                blk["out_at"] = jnp.where(mine[:, None], 0, blk["out_at"])
+                last = blk
+                first_pos = self.P - n_tail
+            pos = _set1(jnp, pos, slot, first_pos)
             remaining = _set1(jnp, remaining, slot, req_n)
             out = jax.lax.dynamic_update_slice(
                 out, jnp.zeros((1, self.N), jnp.int32), (slot, 0))
@@ -451,6 +543,82 @@ class SlotDecoder:
             last = jnp.where(active[:, None], logits_next[:, 0], last)
             return (mut["cache"], last, pos, remaining, out, pads, req, rng)
 
+        # -- compiled: a block model's tick, one pass over every slot's
+        #    block. It takes the place of `_tick` under the same names
+        #    (`jit__tick`, `jit__step_fused` in the device trace) -------
+        def _block_pass(params, state, page_table):
+            cache, blk, pos, remaining, out, pads, req, rng = state
+            active = remaining > 0
+            masked = ~blk["fixed"]                              # [S, B]
+            # no MASK going in: this pass runs the clean block, and the
+            # keys and values it writes are the committed ones
+            commit = active & ~masked.any(axis=1)
+            toks = jnp.where(masked, jnp.int32(model.cfg.gen_mask_id),
+                             blk["tok"])
+            logits, mut = model.apply(
+                params | {"cache": cache}, toks, train=False,
+                decode_index=pos, mutable=["cache", "diagnostics"],
+                pad_len=jnp.where(active, pads, pos + B),
+                page_table=page_table, block_step=True)
+            logits = logits.astype(jnp.float32)                 # [S, B, V]
+            cand = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            # confidence: the softmax probability of the argmax token
+            conf = jnp.exp(jnp.max(logits, axis=-1)
+                           - jax.nn.logsumexp(logits, axis=-1))
+            # fix the `per` masked positions of highest confidence (the
+            # first of equals); fewer where fewer are masked
+            _, pick = jax.lax.top_k(jnp.where(masked, conf, -1.0),
+                                    self._per)
+            chosen = ((jnp.arange(B)[None, None, :] == pick[:, :, None])
+                      .any(axis=1) & masked & active[:, None])
+            step = blk["step"] + (active & ~commit)
+            tok = jnp.where(chosen, cand, blk["tok"])
+            fixed = blk["fixed"] | chosen
+            at = jnp.where(chosen, step[:, None], blk["at"])
+            # commit: the block's tokens to their output columns (the
+            # first block's prompt tokens have none, and the last block
+            # is cut to the tokens asked for), B positions on, and the
+            # next block opens all MASK
+            cols = pos[:, None] + jnp.arange(B)[None, :] - self.P
+            emit = commit[:, None] & (cols >= 0) & (cols < req[:, None])
+            rows = jnp.broadcast_to(jnp.arange(self.S)[:, None], cols.shape)
+            cols = jnp.where(emit, cols, self.N)    # out of range: dropped
+            out = out.at[rows, cols].set(tok, mode="drop")
+            out_at = blk["out_at"].at[rows, cols].set(at, mode="drop")
+            remaining = remaining - emit.sum(axis=1).astype(jnp.int32)
+            last_pos = pos + B - 1
+            pos = jnp.where(commit, pos + B, pos)
+            keep = ~commit[:, None]
+            counted = [
+                active.sum(), commit.sum(),
+                jnp.where(active, last_pos // self.page_size
+                          - pads // self.page_size + 1, 0).sum(),
+                *(_diag_sum(jax, mut.get("diagnostics", {}), n)
+                  for n in BLOCK_COUNTERS[3:])]
+            blk = {"tok": jnp.where(keep, tok, 0),
+                   "fixed": fixed & keep,
+                   "at": jnp.where(keep, at, 0),
+                   "step": jnp.where(commit, 0, step),
+                   "out_at": out_at,
+                   "ctr": blk["ctr"] + jnp.stack(
+                       [jnp.asarray(c, jnp.int32) for c in counted])}
+            return (mut["cache"], blk, pos, remaining, out, pads, req, rng)
+
+        def _counted_from_zero(state):
+            """A dispatched program counts from zero: the host adds each
+            round's counts to its own, which never wrap."""
+            blk = dict(state[1], ctr=jnp.zeros_like(state[1]["ctr"]))
+            return (state[0], blk) + tuple(state[2:])
+
+        if B:
+            tick_once = _block_pass
+
+            def _tick(params, state, page_table):    # noqa: F811
+                return _block_pass(params, _counted_from_zero(state),
+                                   page_table)
+        else:
+            tick_once = _tick
+
         if self.paged:
             self._step = jax.jit(_tick, donate_argnums=(1,))
         else:
@@ -481,8 +649,10 @@ class SlotDecoder:
         # (`jit__step_fused` in the device trace: read by the benchmark)
         def _step_fused(params, state, page_table=None):
             def body(st, _):
-                return _tick(params, st, page_table), None
+                return tick_once(params, st, page_table), None
 
+            if B:
+                state = _counted_from_zero(state)
             st, _ = jax.lax.scan(body, state, None, length=FUSE)
             return st
 
@@ -493,6 +663,9 @@ class SlotDecoder:
                 lambda params, state: _step_fused(params, state),
                 donate_argnums=(1,))
         self._fuse = FUSE
+        # the most tokens a slot can finish in a fused round: a block
+        # takes a denoising pass and a committing one at the least
+        self._fuse_tokens = B * -(-FUSE // 2) if B else FUSE
 
         # -- compiled: speculative admission (prefill target + draft,
         #    install into slot rows, return the first greedy token) ----
@@ -543,10 +716,26 @@ class SlotDecoder:
                 return init_paged_cache(model, self._mp)
             return init_cache(model, self.S)
 
+        def _fresh_block():
+            """What a block model's next pass starts from, in the place
+            of the one-token model's last logits: each slot's block (its
+            tokens, which are fixed, the step each was fixed at, the
+            denoising passes so far), the step of every output token,
+            and the counts of BLOCK_COUNTERS since the last dispatch.
+            Maskedness is `fixed`, never a comparison with the MASK id:
+            a prompt may hold that id."""
+            return {"tok": jnp.zeros((self.S, B), jnp.int32),
+                    "fixed": jnp.zeros((self.S, B), bool),
+                    "at": jnp.zeros((self.S, B), jnp.int32),
+                    "step": jnp.zeros((self.S,), jnp.int32),
+                    "out_at": jnp.zeros((self.S, self.N), jnp.int32),
+                    "ctr": jnp.zeros((len(BLOCK_COUNTERS),), jnp.int32)}
+
         def _fresh_state():
             return (
                 _fresh_cache(),
-                jnp.zeros((self.S, cfg_vocab), jnp.float32),
+                (_fresh_block() if B else
+                 jnp.zeros((self.S, cfg_vocab), jnp.float32)),
                 jnp.zeros((self.S,), jnp.int32),            # pos
                 jnp.zeros((self.S,), jnp.int32),            # remaining
                 jnp.zeros((self.S, self.N), jnp.int32),     # out
@@ -590,8 +779,10 @@ class SlotDecoder:
     # -- host API ----------------------------------------------------------
 
     def submit(self, tokens: list[int], max_new: int | None = None,
-               deadline: float | None = None) -> list[int]:
-        """Block until the continuation for this prompt is decoded.
+               deadline: float | None = None) -> "list[int] | dict":
+        """Block until the continuation for this prompt is decoded: the
+        list of its tokens, or for a block model ``{"tokens": [...],
+        "fixed_at": [...]}``.
         `max_new` caps THIS request's budget below the decoder-wide
         max_new_tokens (a paged decoder then reserves fewer pages).
         `deadline` is an ABSOLUTE time on self.clock: past it the
@@ -604,7 +795,7 @@ class SlotDecoder:
 
     def submit_padded(self, padded_row, pad: int,
                       max_new: int | None = None,
-                      deadline: float | None = None) -> list[int]:
+                      deadline: float | None = None) -> "list[int] | dict":
         """Pre-padded variant for callers that already align rows."""
         import numpy as np
 
@@ -632,6 +823,8 @@ class SlotDecoder:
         self._note_request(r)
         if r.sink and isinstance(r.sink[0], Exception):
             raise r.sink[0]
+        if self.B:
+            return {"tokens": r.sink, "fixed_at": r.fixed_at}
         return r.sink
 
     def _note_request(self, r: _Request) -> None:
@@ -648,7 +841,8 @@ class SlotDecoder:
             prompt_tokens=self.P - r.pad,
             prefill_tokens_computed=r.prefill_tokens,
             new_tokens=len(r.sink) if outcome == "ok" else 0, slot=r.slot,
-            outcome=outcome)
+            outcome=outcome,
+            **({"blocks": r.blocks, "passes": r.passes} if self.B else {}))
         if self.meter and first is not None:
             self.meter.request_waits(wait, first)
 
@@ -774,6 +968,33 @@ class SlotDecoder:
         first = max(pad, pos - window + 1) if window else pad
         return pos // self.page_size - first // self.page_size + 1
 
+    # -- a block model's geometry (all zero or empty for B = 0) -----------
+
+    def _tail(self, r: _Request) -> int:
+        """The prompt's last tokens that fill no whole block: they open
+        the request's first block as fixed."""
+        return (self.P - r.pad) % self.B if self.B else 0
+
+    def _block_end(self, r: _Request) -> int:
+        """One past the last position of the block the answer ends in:
+        every position the request's passes write."""
+        tail = self._tail(r)
+        return self.P - tail + -(-(tail + r.req) // self.B) * self.B
+
+    def _first_block(self, r: _Request) -> tuple:
+        """The last argument of the prefill of a block model's request:
+        its first block's tokens and how many of them the prompt fixed.
+        Nothing for a one-token model."""
+        if not self.B:
+            return ()
+        import numpy as np
+
+        tail = self._tail(r)
+        r.blocks = -(-(tail + r.req) // self.B)
+        toks = np.zeros(self.B, np.int32)
+        toks[:tail] = r.prompt[self.P - tail:]
+        return ((self._jnp.asarray(toks), self._jnp.int32(tail)),)
+
     def _expired_slots(self, owners: dict) -> list[int]:
         """Active slots whose request deadline has passed."""
         now = self.clock()
@@ -854,24 +1075,34 @@ class SlotDecoder:
                     waiting = (self._carry is not None
                                or not self._pending.empty())
                     fuse = ((not waiting or not self._free)
-                            and all(int(last_rem[s_]) >= self._fuse
+                            and all(int(last_rem[s_]) >= self._fuse_tokens
                                     for s_ in owners))
                     ticks = self._fuse if fuse else 1
                     if self.paged:
                         # decode writes march forward: hand out the pages
                         # the window will cross (reserved at admission)
-                        # and run the COW barrier over the write range
+                        # and run the COW barrier over the write range.
+                        # A block model's pass writes its block's B
+                        # positions, and a round of `ticks` passes can
+                        # commit every other pass: the pages are there
+                        # before a block's first pass.
                         for s_, r in owners.items():
                             start = int(last_pos[s_])
-                            self.alloc.append(s_, start + ticks)
-                            copies = self.alloc.write_barrier(
-                                s_, start, start + ticks)
+                            if self.B:
+                                r.passes += ticks
+                                end = min(start + self.B * (1 + ticks // 2),
+                                          self._block_end(r))
+                            else:
+                                end = start + ticks
+                            self.alloc.append(s_, end)
+                            copies = self.alloc.write_barrier(s_, start, end)
                             if copies:
                                 self.state = self._apply_copies(
                                     self.state, *self._cow_arrays(copies))
-                            self._counters["kv_pages_walked"] += sum(
-                                self._pages_seen(r.pad, pos)
-                                for pos in range(start, start + ticks))
+                            if not self.B:   # (a block pass counts its own)
+                                self._counters["kv_pages_walked"] += sum(
+                                    self._pages_seen(r.pad, pos)
+                                    for pos in range(start, start + ticks))
                         self._counters["kv_pages_tabled"] += (
                             ticks * self.alloc.table.size)
                         pt = jnp.asarray(self.alloc.table)
@@ -893,10 +1124,19 @@ class SlotDecoder:
                     # one readback of the tokens per round, and only
                     # where a slot finished
                     out = np.asarray(self.state[4]) if done else None
+                    if self.B:
+                        # the round's counts, from the same read-back
+                        for name, n in zip(BLOCK_COUNTERS, np.asarray(
+                                self.state[1]["ctr"]).tolist()):
+                            self._counters[name] += n
+                        out_at = (np.asarray(self.state[1]["out_at"])
+                                  if done else None)
                 with phase("complete"):
                     self._note_first_tokens(owners.values())
                     for s_ in done:
                         r = owners.pop(s_)
+                        if self.B:
+                            r.fixed_at = [int(t) for t in out_at[s_][:r.req]]
                         r.finish(int(t) for t in out[s_][:r.req])
                         self._free.append(s_)
                         self._counters["completed"] += 1
@@ -999,7 +1239,8 @@ class SlotDecoder:
                 if not self._validate(r):
                     continue
                 row = [int(t) for t in r.prompt]
-                total = self.P + r.req + self.draft_k
+                total = (self._block_end(r) if self.B
+                         else self.P + r.req + self.draft_k)
                 if not self.alloc.can_admit(row, r.pad, total):
                     # head-of-line page gate: FIFO order is preserved (no
                     # bypass) — the request waits for completions to free
@@ -1020,7 +1261,8 @@ class SlotDecoder:
                         jnp.asarray([plan.compute_start], jnp.int32),
                         jnp.asarray(self.alloc.table[slot:slot + 1]),
                         jnp.asarray([r.pad], jnp.int32),
-                        jnp.int32(slot), jnp.int32(r.req))
+                        jnp.int32(slot), jnp.int32(r.req),
+                        *self._first_block(r))
             except Exception as e:
                 # the slot's PAGES go back before the slot id does —
                 # recycling the slot while the allocator still holds
@@ -1033,7 +1275,7 @@ class SlotDecoder:
             with phase("admit"):
                 self._note_admitted(r, slot, len(suffix), owners)
                 last_rem[slot] = r.req
-                last_pos[slot] = self.P
+                last_pos[slot] = self.P - self._tail(r)
                 if self.meter:
                     self.meter.prefill_tokens(len(suffix))
                     self.meter.prefix_hits(plan.shared_pages)
@@ -1256,6 +1498,15 @@ class SlotDecoder:
                 fail_all(e)
                 self._active = 0
         self._drain_shutdown(owners)
+
+
+def _diag_sum(jax, diagnostics, name: str):
+    """The sum of every layer's sow of `name` in a "diagnostics"
+    collection (0 where no layer sowed it)."""
+    from jax.tree_util import tree_flatten_with_path
+
+    return sum((v for path, v in tree_flatten_with_path(diagnostics)[0]
+                if any(getattr(p, "key", None) == name for p in path)), 0)
 
 
 def _set1(jnp, vec, i, val):

@@ -434,8 +434,9 @@ def _unstack(out: Any, n: int) -> list:
         arrs = {k: np.asarray(v)[:n] for k, v in out.items()}
         return [{k: arrs[k][i].tolist() for k in arrs} for i in range(n)]
     if isinstance(out, list):
-        # ragged rows (per-request max_new_tokens budgets differ)
-        return [list(r) for r in out[:n]]
+        # ragged rows (per-request max_new_tokens budgets differ), or a
+        # block model's {"tokens", "fixed_at"} a row
+        return [r if isinstance(r, dict) else list(r) for r in out[:n]]
     return np.asarray(out)[:n].tolist()
 
 
@@ -758,7 +759,9 @@ def cast_params(variables, dtype):
 
     def leaf(x):
         if hasattr(x, "dtype") and jnp.issubdtype(x.dtype, jnp.floating):
-            return x.astype(dtype)
+            # a leaf that is there in `dtype` already stays the array it
+            # is: no second copy of weights that fill the device once
+            return x if x.dtype == jnp.dtype(dtype) else x.astype(dtype)
         return x
 
     return jax.tree.map(leaf, variables)
@@ -788,7 +791,12 @@ def serve_lm_generator(name: str, model_name: str, *, prompt_len: int = 128,
     `prompt_len` and decoded with the KV-cache loop
     (runtime/generate.py) for exactly `max_new_tokens` steps — one
     compiled program per batch bucket, never per request shape (static
-    shapes are an XLA requirement). Responses carry the new tokens only.
+    shapes are an XLA requirement). Responses carry the new tokens only;
+    a block model's (cfg.gen_block > 0, continuous batching over the
+    paged cache only) are `{"tokens": [...], "fixed_at": [...]}`, the
+    denoising step of its block at which each token was fixed.
+    `param_dtype="bfloat16"` leaves leaves that are bfloat16 already as
+    they are.
     """
     import jax
     import jax.numpy as jnp
@@ -810,7 +818,17 @@ def serve_lm_generator(name: str, model_name: str, *, prompt_len: int = 128,
     if kv_pages:
         model_kwargs = dict(model_kwargs,
                             kv_pages=kv_pages, kv_page_size=kv_page_size)
+    # a block model's last block may reach gen_block positions past the
+    # last token asked for
+    seq_budget += int(model_kwargs.get("gen_block", 0) or 0)
     model = get_model(model_name, max_seq_len=seq_budget, **model_kwargs)
+    if getattr(model.cfg, "gen_block", 0) and not (
+            continuous_batching and kv_pages):
+        raise ValueError(
+            "a block model (gen_block > 0) is served by the slot decoder "
+            "over the paged KV cache only (continuous_batching with "
+            "kv_pages and kv_page_size): no other path has its "
+            "block-causal mask or its block step")
     if draft_model:
         if temperature > 0:
             raise ValueError("speculative decoding is greedy-only "
@@ -1015,6 +1033,10 @@ def serve_lm_generator(name: str, model_name: str, *, prompt_len: int = 128,
                     outs = list(pool.map(
                         lambda c, *a: c.run(dec.submit_padded, *a),
                         ctxs, rows, pad_lens, maxnews, [dl] * len(rows)))
+            if isinstance(outs[0], dict):
+                # a block model's prediction: the tokens, and the step of
+                # its block at which each was fixed
+                return outs
             # per-request budgets produce ragged rows; pad the response
             # rows only when a caller actually mixed budgets
             if len({len(o) for o in outs}) > 1:

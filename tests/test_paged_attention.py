@@ -47,6 +47,10 @@ class Case:
     window: int = 0
     shared: int = 0        # leading logical pages slot 1 shares with slot 0
     cell_pool: bool = False   # on a TPU: the serving cells' 16,385 pages
+    # a block model's step: `block` queries a slot that all see
+    # starts..lasts, the block's own positions being lasts-block+1..lasts
+    # (0 = one query a slot, at lasts)
+    block: int = 0
 
 
 def cell_case() -> Case:
@@ -84,6 +88,14 @@ CASES = {
     "head-256": Case((5, 0), (130, 64), (5, 0), heads=4, kv_heads=2,
                      head_dim=256),
     "one-slot": Case((3,), (190,), (3,)),
+    # blocks of 4 queries: across a page's edge (14..17), behind padding
+    # that begins inside the block's page (pad 33, block 33..36), an idle
+    # slot, and at the block-diffusion cell's heads (32 over 4 kv heads)
+    "block-of-4-across-a-page-edge": Case((2, 5), (17, 100), (2, 5), block=4),
+    "block-of-4-first-after-the-padding": Case(
+        (33, 0, 91), (36, 63, 90), (33, 0, 91), block=4),
+    "block-of-4-cell-heads": Case((7, 130, 0), (142, 161, 3), (7, 130, 0),
+                                  heads=32, kv_heads=4, block=4),
     "thirty-two-slots-cell-shape": cell_case,    # sized by the backend
 }
 
@@ -115,14 +127,15 @@ def build(case: Case, seed: int):
         pool = np.full(shape, TRASH, np.float32)
         pool[live] = rng.normal(size=(len(live),) + shape[1:])
         pools.append(jnp.asarray(pool, jnp.bfloat16))
-    q, k, v = (jnp.asarray(rng.normal(size=(b, 1, h, case.head_dim)),
-                           jnp.bfloat16)
+    q, k, v = (jnp.asarray(rng.normal(size=(b, case.block or 1, h,
+                                            case.head_dim)), jnp.bfloat16)
                for h in (case.heads, case.kv_heads, case.kv_heads))
     cfg = TransformerConfig(
         vocab_size=8, d_model=case.heads * case.head_dim, n_layers=1,
         n_heads=case.heads, n_kv_heads=case.kv_heads,
         head_dim=case.head_dim, d_ff=8, max_seq_len=case.max_pages * ps,
-        attention_window=case.window, kv_pages=pages, kv_page_size=ps)
+        attention_window=case.window, kv_pages=pages, kv_page_size=ps,
+        gen_block=case.block)
     return cfg, pools, jnp.asarray(table), q, k, v
 
 
@@ -149,14 +162,17 @@ def test_kernel_matches_gather_path(name, monkeypatch):
         def __call__(self, *xs):
             return self._decode_paged(*xs)
 
+    # the chunk's first position: the query's own, or its block's first
+    first = lasts - max(case.block - 1, 0)
     want, mut = jax.jit(lambda *xs: DecodeStep(cfg).apply(
         {"cache": {"key_pages": kp, "value_pages": vp}}, *xs,
-        mutable=["cache"]))(q, k, v, lasts, pads, table)
+        bool(case.block), mutable=["cache"]))(q, k, v, first, pads, table)
     kp1, vp1 = mut["cache"]["key_pages"], mut["cache"]["value_pages"]
     got = jax.jit(paged_attention.paged_decode_attention)(
-        q[:, 0], kp1, vp1, table, starts, lasts)
+        q if case.block else q[:, 0], kp1, vp1, table, starts, lasts)
 
-    got, want = np.asarray(got, np.float32), np.asarray(want[:, 0], np.float32)
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want if case.block else want[:, 0], np.float32)
     assert np.isfinite(got).all()
     idle = np.asarray(case.starts) > np.asarray(case.lasts)
     assert not got[idle].any(), "an idle slot gives zeros"
@@ -216,6 +232,34 @@ def test_kernel_compiles_for_a_v5e_at_the_cells_shape(v5e_chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
+def test_block_kernel_compiles_for_a_v5e_at_the_cells_shape(
+        v5e_chip, monkeypatch):
+    """The block-diffusion cell's step: 64 slots, 4 queries a slot of 32
+    heads over 4 kv heads of 128 (128 query rows against each streamed
+    block of pages), rows of 129 pages, the 8,193-page pool."""
+    import jax
+    import jax.numpy as jnp
+
+    from kubeflow_tpu.ops import flash_attention
+    from kubeflow_tpu.ops.paged_attention import (BLOCK_KERNEL_NAME,
+                                                  paged_decode_attention)
+
+    monkeypatch.setattr(flash_attention, "INTERPRET", False)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    pool = arg((8193, 16, 4, 128), jnp.bfloat16)
+    compiled = jax.jit(paged_decode_attention).lower(
+        arg((64, 4, 32, 128), jnp.bfloat16), pool, pool,
+        arg((64, 129), jnp.int32), arg((64,), jnp.int32),
+        arg((64,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the name the benchmark finds the kernel by in the device trace
+    assert f"%{BLOCK_KERNEL_NAME}" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
 def test_path_rule_follows_backend_and_chunk_length(caplog):
     """The gather path off the TPU and for chunks, the kernel for one
     query a slot on a TPU; which, and why, is logged."""
@@ -229,9 +273,12 @@ def test_path_rule_follows_backend_and_chunk_length(caplog):
         chunk = use_kernel(4096, pool, jnp.bfloat16)
         narrow = use_kernel(1, (64, 16, 8, 64), jnp.bfloat16)
         odd = use_kernel(1, (64, 16, 1, 128), jnp.bfloat16)
+        block = use_kernel(4, (64, 16, 4, 128), jnp.bfloat16, one_range=True)
     assert one == on_tpu()
     assert not chunk and not narrow and not odd
+    assert block == on_tpu()    # a chunk whose rows see one range
     said = [r.getMessage() for r in caplog.records]
+    assert ("a block of 4 queries" if on_tpu() else "not tpu") in said.pop()
     assert len(said) == 4 and all("paged attention ->" in m for m in said)
     assert ("-> kernel" if on_tpu() else "not tpu") in said[0]
     assert "-> gather (a chunk of 4096 queries a slot)" in said[1]

@@ -83,11 +83,66 @@ def program_names():
     names.add(f"%{nested}.7 = bf16[16,8192,128]{{2,1,0:T(8,128)(2,1)}} "
               "custom-call(%bitcast.1, %bitcast.2, %bitcast.3), "
               'custom_call_target="tpu_custom_call"')
+    # a block model's step programs keep the module names, and its two
+    # kernels are custom calls as the TPU compiler writes them: the
+    # grouped expert matmul under XLA's own name for `ragged_dot`, the
+    # block attention under the kernel's (tests/test_paged_attention.py
+    # compiles it for a v5e and finds that name in the module)
+    from kubeflow_tpu.ops.moe import EXPERT_MATMUL_TRACE_NAME
+    from kubeflow_tpu.ops.paged_attention import (BLOCK_KERNEL_NAME,
+                                                  KERNEL_NAME)
+
+    blocks = get_model(
+        "transformer-test", vocab_size=64, max_seq_len=24, kv_pages=25,
+        kv_page_size=4, moe_every=1, n_experts=4, expert_top_k=2,
+        moe_d_ff=32, qk_norm=True, gen_block=4, gen_mask_id=63)
+    variables = blocks.init(jax.random.PRNGKey(0),
+                            np.zeros((1, 1), np.int32), train=False)
+    dec = SlotDecoder(blocks, variables, slots=3, prompt_len=8,
+                      max_new_tokens=8)
+    try:
+        table = jnp.asarray(dec.alloc.table)
+        names |= {
+            "block:" + module_name(dec._step, dec._params, dec.state, table),
+            "block:" + module_name(dec._step_fused, dec._params, dec.state,
+                                   table),
+            "block:" + module_name(
+                dec._paged_prefill_install, dec._params, dec.state,
+                jnp.zeros((1, 8), jnp.int32), jnp.zeros((1,), jnp.int32),
+                table[:1], jnp.zeros((1,), jnp.int32), jnp.int32(0),
+                jnp.int32(1), (jnp.zeros((4,), jnp.int32), jnp.int32(1))),
+        }
+    finally:
+        dec.close()
+    names.add(f"%{EXPERT_MATMUL_TRACE_NAME}-none.1 = bf16[2048,768]"
+              "{1,0:T(8,128)(2,1)S(1)} custom-call(%get-tuple-element, "
+              '%x.1, %wu.1), custom_call_target="tpu_custom_call"')
+    for kernel in (KERNEL_NAME, BLOCK_KERNEL_NAME):
+        names.add(f"%{kernel}.3 = bf16[64,128,128]{{2,1,0:T(8,128)(2,1)}} "
+                  "custom-call(%table, %start, %last, %q, %k, %v), "
+                  'custom_call_target="tpu_custom_call"')
     return names
 
 
 def test_the_step_programs_keep_their_module_names(program_names):
     assert MODULES <= program_names
+    # a block model's step takes the one-token step's place under its name
+    assert {"block:jit__tick", "block:jit__step_fused",
+            "block:jit__paged_prefill_install"} <= program_names
+
+
+def test_the_kernels_names_are_what_their_definitions_say():
+    """The names said at the kernels' definitions (ops/moe.py,
+    ops/paged_attention.py) are the ones the metric files look for."""
+    from kubeflow_tpu.ops.moe import EXPERT_MATMUL_TRACE_NAME
+    from kubeflow_tpu.ops.paged_attention import (BLOCK_KERNEL_NAME,
+                                                  KERNEL_NAME)
+
+    looked_for = {p.values[0] for p in patterns()}
+    assert EXPERT_MATMUL_TRACE_NAME in looked_for
+    assert BLOCK_KERNEL_NAME in looked_for
+    assert (KERNEL_NAME, BLOCK_KERNEL_NAME) == (
+        "paged_decode_attention", "paged_block_attention")
 
 
 @pytest.mark.parametrize("pattern", patterns())
