@@ -286,6 +286,11 @@ class Attention(nn.Module):
         slot's current decode index is backed by an owned or shared
         page, so trash content is only ever visible at masked
         (pos > qpos) positions (the kernel never fetches those pages).
+        The same holds before the slot's first real position: the
+        logical pages that hold left padding only are trash entries
+        too (runtime/kvcache.py), masked by `pos >= pad_len`, and what
+        a prefill chunk writes at such positions lands in the trash
+        page.
         Idle lockstep slots have their whole row zeroed at free time,
         steering their stale writes into the trash page instead of a
         page another slot now owns."""

@@ -29,28 +29,45 @@ rows are zeroed so their stale lockstep writes land there instead of a
 page another slot now owns, and gathers through unallocated table
 entries read it only at masked positions.
 
+A page with no real position is no page: a prompt is left-padded to the
+decoder's fixed ``prompt_len``, and the logical pages wholly inside the
+padding keep TRASH_PAGE in the slot's row. They are not allocated, not
+counted, not hashed, not registered and not freed; every reader masks
+positions before the pad length, and what prefill writes there lands in
+the trash page.
+
 Prefix reuse hashes CHAINS, not pages in isolation: a page's K/V at
 layer > 0 depend on every earlier position (attention), so page j is
 shareable only under an identical full prefix — ``h_j =
-H(h_{j-1} || tokens_j)`` with the pad length folded into the root.
-Only COMPLETE prompt pages are ever registered (a partially-filled
-page will be written by decode and can never be shared safely).
+H(h_{j-1} || tokens_j)`` from the page that holds the first real token,
+with the pad length folded into the root. Only COMPLETE prompt pages
+are ever registered (a partially-filled page will be written by decode
+and can never be shared safely).
+
+What prefill computes is a suffix of the prompt whose length is a rung
+of ``prefill_ladder``: the shortest that covers the real tokens the
+index does not have. The compiled prefill is traced at those lengths
+and at no other, with or without hits; a hit that would leave a length
+between two rungs is cut back to the rung, and the pages behind the cut
+are computed again into private pages.
 
 Copy-on-write: any write into a page that is shared (referenced by
 another slot or by the prefix index) first clones it to a fresh page —
 ``write_barrier`` returns the (src, dst) copies for the caller to apply
-on-device BEFORE dispatching the program that writes. The reachable
-case in the serving path: a prompt fully covered by cached pages still
-needs its final position recomputed for the first-token logits, and
-that recompute writes into the last shared page.
+on-device BEFORE dispatching the program that writes. Reachable at
+admission where the computed suffix starts inside a claimed page (a
+``prompt_len`` that is no whole number of pages), and in decode where a
+block model's first block rewrites the prompt's tail.
 """
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import heapq
 from collections import OrderedDict
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
 import numpy as np
 
@@ -60,6 +77,36 @@ TRASH_PAGE = 0
 def pages_for(length: int, page_size: int) -> int:
     """Number of pages covering `length` positions."""
     return -(-length // page_size)
+
+
+@functools.lru_cache(maxsize=None)
+def prefill_ladder(prompt_len: int, page_size: int) -> tuple[int, ...]:
+    """The suffix lengths a paged prefill computes, ascending:
+    ``prompt_len`` x {1/4, 1/2, 3/4, 1} in whole pages (for 4,096 by 16:
+    1,024, 2,048, 3,072, 4,096). An admission takes the shortest rung
+    that covers what it must compute, so the prefill is compiled once a
+    rung, when the decoder is built, and a prompt above the first rung
+    pays for at most twice its own length (three halves above half of
+    ``prompt_len``). One rule for every decoder: nothing sets it. Four
+    rungs and not more because a rung is paid for at every start: on a
+    v5e's host one more trace, lowering and load of an 8-layer
+    Mistral-7B's prefill is 0.6 to 0.9 s of set-up (PERF.md section 6,
+    PR 30)."""
+    return tuple(sorted({
+        min(prompt_len,
+            pages_for(-(-prompt_len * n // 4), page_size) * page_size)
+        for n in (1, 2, 3, 4)}))
+
+
+class _Layout(NamedTuple):
+    """How an admission lies in its slot's row (``_layout``)."""
+
+    first: int            # logical page of the first real position
+    hashes: list          # chain hash of complete page first + i
+    hits: list            # the index's pages for the leading hashes
+    compute_start: int    # first prompt position prefill computes
+    claimed: int          # leading hits taken as shared pages
+    need: int             # pages to claim now and through decode
 
 
 @dataclass
@@ -105,8 +152,11 @@ class PageAllocator:
         self._free: list[int] = list(range(1, num_pages))  # heap, asc ids
         heapq.heapify(self._free)
         self._ref = np.zeros(num_pages, np.int64)
-        # per-slot: logical page index -> True if claimed shared
-        self._slot_len: list[int] = [0] * slots     # allocated logical pages
+        # per-slot extent: logical pages [_slot_first, _slot_len) are
+        # allocated (the pages before hold padding only and stay trash),
+        # _slot_total is what the slot may grow to
+        self._slot_first: list[int] = [0] * slots
+        self._slot_len: list[int] = [0] * slots
         self._slot_total: list[int] = [0] * slots   # reserved total pages
         self._reserved = 0                          # unallocated-yet pages
         # prefix index: chain hash -> page id (LRU via move_to_end)
@@ -139,15 +189,17 @@ class PageAllocator:
 
     # -- hashing ----------------------------------------------------------
 
-    def _chain_hashes(self, row, pad: int) -> list[bytes]:
-        """Chained hashes of the COMPLETE pages of `row` (one hash per
-        full page; the pad length salts the root because left-pad
-        masking changes every position's attention output)."""
+    def _chain_hashes(self, row, pad: int, first: int) -> list[bytes]:
+        """Chained hashes of the COMPLETE pages of `row` from logical
+        page `first` on (one hash per full page; the pad length salts
+        the root because left-pad masking changes every position's
+        attention output, and because the first page's leading
+        positions may be padding)."""
         ps = self.page_size
         toks = np.asarray(row, np.int32)
         h = hashlib.blake2b(f"pad={pad}".encode(), digest_size=16).digest()
         out = []
-        for j in range(len(toks) // ps):
+        for j in range(first, len(toks) // ps):
             h = hashlib.blake2b(
                 h + toks[j * ps:(j + 1) * ps].tobytes(),
                 digest_size=16).digest()
@@ -178,22 +230,35 @@ class PageAllocator:
 
     # -- admission --------------------------------------------------------
 
-    def _plan_hits(self, row, pad: int, total_len: int) -> tuple:
+    def _layout(self, row, pad: int, total_len: int) -> _Layout:
+        """Where the admission's pages begin, what the index has of
+        them, and the suffix prefill computes: the shortest rung of the
+        ladder that covers the real positions no hit covers (one at the
+        least: the first decode token needs the last position's
+        logits). Hits behind the rung's start are not claimed."""
+        ps = self.page_size
         prompt_len = len(row)
-        hashes = self._chain_hashes(row, pad) if self.prefix_enabled else []
+        real_from = min(pad, prompt_len - 1)
+        first = real_from // ps
+        hashes = (self._chain_hashes(row, pad, first)
+                  if self.prefix_enabled else [])
         hits = []
         for h in hashes:
             page = self._prefix.get(h)
             if page is None:
                 break
             hits.append(page)
-        need = pages_for(total_len, self.page_size) - len(hits)
-        if len(hits) * self.page_size >= prompt_len:
-            # fully-cached prompt: the final position is still
-            # recomputed for the first-token logits, and that write
-            # copy-on-writes the last shared page — one extra page
-            need += 1
-        return need, hits
+        covered = max(real_from, (first + len(hits)) * ps)
+        todo = max(1, prompt_len - covered)
+        compute_start = prompt_len - next(
+            n for n in prefill_ladder(prompt_len, ps) if n >= todo)
+        claimed = min(len(hits),
+                      max(0, pages_for(compute_start, ps) - first))
+        # the suffix starts inside the last claimed page: prefill's write
+        # copy-on-writes it, one page more
+        cow = 1 if claimed and compute_start % ps else 0
+        need = pages_for(total_len, ps) - first - claimed + cow
+        return _Layout(first, hashes, hits, compute_start, claimed, need)
 
     def plan(self, row, pad: int, total_len: int) -> tuple[int, int]:
         """(pages_to_claim, cached_positions) for an admission. Gate
@@ -202,24 +267,26 @@ class PageAllocator:
         pages THIS admission would hit — claiming them pins them, so
         the naive comparison over-admits and exhausts the pool
         mid-decode."""
-        need, hits = self._plan_hits(row, pad, total_len)
-        return need, len(hits) * self.page_size
+        lay = self._layout(row, pad, total_len)
+        return lay.need, lay.claimed * self.page_size
 
     def can_admit(self, row, pad: int, total_len: int) -> bool:
         """True when the admission can claim every page it needs NOW
         and lazily through decode: free pages plus prefix pages that
         are genuinely evictable (unreferenced AND not this admission's
-        own hits), minus what live slots have reserved."""
-        need, hits = self._plan_hits(row, pad, total_len)
-        hitset = set(hits)
+        own claimed hits), minus what live slots have reserved."""
+        lay = self._layout(row, pad, total_len)
+        hitset = set(lay.hits[:lay.claimed])
         evictable = sum(1 for p in self._prefix.values()
                         if self._ref[p] == 1 and p not in hitset)
-        return need <= len(self._free) + evictable - self._reserved
+        return lay.need <= len(self._free) + evictable - self._reserved
 
     def admit(self, slot: int, row, pad: int, total_len: int) -> AdmitPlan:
-        """Claim pages for a request: shared prompt pages from the
-        prefix index (refcounted, read-only), fresh pages for the rest
-        of the prompt; decode pages are RESERVED but appended lazily
+        """Claim pages for a request: none for the logical pages that
+        hold padding only (TRASH_PAGE stays in the row), shared prompt
+        pages from the prefix index (refcounted, read-only) up to where
+        the computed suffix starts, fresh pages for the rest of the
+        prompt; decode pages are RESERVED but appended lazily
         (``append``). Returns the plan — including any copy-on-write
         clones the caller must apply on-device before prefill runs —
         and registers the slot's newly computed complete prompt pages
@@ -236,47 +303,39 @@ class PageAllocator:
         if self._slot_total[slot]:
             raise RuntimeError(f"slot {slot} already admitted")
         ps = self.page_size
-        hashes = self._chain_hashes(row, pad) if self.prefix_enabled else []
+        lay = self._layout(row, pad, total_len)
+        first, k = lay.first, lay.claimed
         self.prefix_lookups += 1
-        hit_pages: list[int] = []
-        for h in hashes:
-            page = self._prefix.get(h)
-            if page is None:
-                break
-            hit_pages.append(page)
-            self._prefix.move_to_end(h)   # LRU touch
-        for j, page in enumerate(hit_pages):
-            self.table[slot, j] = page
+        for i, page in enumerate(lay.hits[:k]):
+            self.table[slot, first + i] = page
             self._ref[page] += 1
-        k = len(hit_pages)
-        cached = k * ps
+            self._prefix.move_to_end(lay.hashes[i])   # LRU touch
         self.prefix_hit_pages += k
-        self.prefix_hit_tokens += cached
-        # always recompute >= 1 prompt position: the first decode token
-        # needs the last position's logits
-        compute_start = min(cached, prompt_len - 1)
-        # private pages for the computed prompt tail
+        self.prefix_hit_tokens += k * ps
+        # the slot's extent is set before any page is drawn, so that
+        # free() gives back whatever was claimed if the pool runs dry
         n_prompt = pages_for(prompt_len, ps)
-        for j in range(k, n_prompt):
-            self.table[slot, j] = self._alloc_page()
+        self._slot_first[slot] = first
         self._slot_len[slot] = n_prompt
         self._slot_total[slot] = n_total
         self._reserved += n_total - n_prompt
+        # private pages for the computed prompt suffix
+        for j in range(first + k, n_prompt):
+            self.table[slot, j] = self._alloc_page()
         self.admits += 1
         plan = AdmitPlan(slot=slot, total_len=total_len,
-                         prompt_len=prompt_len, cached_positions=cached,
-                         compute_start=compute_start, shared_pages=k)
-        # prefill WRITES [compute_start, prompt_len): COW anything
-        # shared in that range (reachable when the whole prompt was
-        # cached and compute_start falls inside the last shared page)
-        plan.copies = self.write_barrier(slot, compute_start, prompt_len)
+                         prompt_len=prompt_len, cached_positions=k * ps,
+                         compute_start=lay.compute_start, shared_pages=k)
+        # prefill WRITES [compute_start, prompt_len): COW the claimed
+        # page the suffix starts inside, if it does
+        plan.copies = self.write_barrier(slot, lay.compute_start, prompt_len)
         # register newly computed COMPLETE prompt pages for reuse
         if self.prefix_enabled:
-            for j in range(k, prompt_len // ps):
+            for j in range(first + k, prompt_len // ps):
                 page = int(self.table[slot, j])
-                key = hashes[j]
+                key = lay.hashes[j - first]
                 if key in self._prefix or page in self._page_key:
-                    continue  # duplicate content (e.g. a COW clone)
+                    continue  # duplicate content (computed again, a clone)
                 self._prefix[key] = page
                 self._page_key[page] = key
                 self._ref[page] += 1
@@ -328,15 +387,16 @@ class PageAllocator:
         survive in the prefix index for future hits), zero the table
         row so the idle slot's lockstep writes land in the trash page,
         drop the unallocated reservation."""
-        for j in range(self._slot_len[slot]):
+        for j in range(self._slot_first[slot], self._slot_len[slot]):
             page = int(self.table[slot, j])
-            if page == TRASH_PAGE:
+            if page == TRASH_PAGE:   # (an admission the pool cut short)
                 continue
             self._ref[page] -= 1
             if self._ref[page] == 0:
                 heapq.heappush(self._free, page)
         self._reserved -= self._slot_total[slot] - self._slot_len[slot]
         self.table[slot, :] = TRASH_PAGE
+        self._slot_first[slot] = 0
         self._slot_len[slot] = 0
         self._slot_total[slot] = 0
 
@@ -347,6 +407,7 @@ class PageAllocator:
         self._free = list(range(1, self.num_pages))
         heapq.heapify(self._free)
         self._ref[:] = 0
+        self._slot_first = [0] * self.slots
         self._slot_len = [0] * self.slots
         self._slot_total = [0] * self.slots
         self._reserved = 0
@@ -358,11 +419,13 @@ class PageAllocator:
     def check(self) -> None:
         refs = np.zeros(self.num_pages, np.int64)
         for s in range(self.slots):
-            row = self.table[s, :self._slot_len[s]]
+            first, end = self._slot_first[s], self._slot_len[s]
+            row = self.table[s, first:end]
             for page in row:
                 assert page != TRASH_PAGE, (s, row)
                 refs[page] += 1
-            assert (self.table[s, self._slot_len[s]:] == TRASH_PAGE).all()
+            assert (self.table[s, :first] == TRASH_PAGE).all()
+            assert (self.table[s, end:] == TRASH_PAGE).all()
         for page in self._prefix.values():
             refs[page] += 1
         assert (refs == self._ref).all(), "refcount drift"

@@ -251,8 +251,10 @@ class SlotDecoder:
       idle-burst prefill — the original shape.
     - paged: the model was built with cfg.kv_pages/kv_page_size; a
       PageAllocator gates admission on page availability, prompts
-      reuse shared prefix pages, per-request prefill computes only the
-      uncached suffix.
+      reuse shared prefix pages, and per-request prefill computes the
+      shortest rung of a fixed ladder of lengths that covers the real
+      tokens no hit covers: never the padding before them, and never
+      at a length that was not compiled when the decoder was built.
     - speculative (draft_model given): greedy-only lockstep
       propose/verify rounds; composes with dense or paged target.
     - block (the model's cfg.gen_block > 0; paged, greedy, no draft):
@@ -274,7 +276,7 @@ class SlotDecoder:
         from kubeflow_tpu.runtime.generate import (
             check_decode_geometry, init_cache, prefill_scan)
         from kubeflow_tpu.runtime.kvcache import (
-            PageAllocator, init_paged_cache, pages_for)
+            PageAllocator, init_paged_cache, pages_for, prefill_ladder)
 
         self.model = model
         self.variables = variables
@@ -360,6 +362,8 @@ class SlotDecoder:
             self.alloc = PageAllocator(
                 cfg.kv_pages, self.page_size, slots, self._mp,
                 prefix_cache=prefix_cache)
+            # the suffix lengths prefill runs at (runtime/kvcache.py)
+            self._ladder = prefill_ladder(prompt_len, self.page_size)
         else:
             self.alloc = None
         self.meter = _DecodeMeter(metrics_name) if metrics_name else None
@@ -368,7 +372,10 @@ class SlotDecoder:
         # host-truth counters (stats(); the meter mirrors into sinks)
         self._counters = {
             "admitted": 0, "completed": 0, "peak_active": 0,
-            "prefill_tokens_computed": 0, "prompt_tokens_submitted": 0,
+            # per admission: the positions prefill computed, the prompt's
+            # real tokens (prompt_len less the padding), and prompt_len
+            "prefill_tokens_computed": 0, "prompt_tokens_real": 0,
+            "prompt_tokens_submitted": 0,
             "spec_rounds": 0, "spec_tokens_emitted": 0,
             "spec_tokens_accepted": 0, "spec_drafted": 0,
             "deadline_canceled": 0,
@@ -453,11 +460,13 @@ class SlotDecoder:
 
         self._clear_slots = jax.jit(_clear_slots, donate_argnums=(0,))
 
-        # -- compiled: paged prefill of ONE request's uncached prompt
-        #    suffix + install (the suffix length is one of a bounded
-        #    set of page-aligned sizes, so compiles stay bounded). The
-        #    function's name is a contract: the benchmark finds the XLA
-        #    module `jit__paged_prefill_install` by it
+        # -- compiled: paged prefill of ONE request's prompt suffix +
+        #    install. The suffix is the shortest rung of self._ladder
+        #    that covers the real tokens no prefix hit covers (the
+        #    allocator's plan), so the function is traced at the ladder's
+        #    lengths and at no other. The function's name is a contract
+        #    at every rung: the benchmark finds the XLA module
+        #    `jit__paged_prefill_install` by it
         #    (benchmarks/metrics/*.json; tests/test_trace_names.py) ------
         def _paged_prefill_install(params, state, toks, start, pt_row,
                                    pad, slot, req_n, block=None):
@@ -500,6 +509,8 @@ class SlotDecoder:
 
         self._paged_prefill_install = jax.jit(
             _paged_prefill_install, donate_argnums=(1,))
+        # the suffix lengths dispatched since the build
+        self._prefill_lengths: set = set()
 
         # -- compiled: apply COW page clones before a program writes ----
         def _apply_copies(state, src, dst):
@@ -752,12 +763,13 @@ class SlotDecoder:
             self._fresh_d_cache = lambda: init_cache(draft_model, self.S)
         else:
             self.state = _fresh_state()
+            if self.paged:
+                self._compile_prefills()
         # bytes the decode cache holds on-device (shape truth: the
         # density claims in tools/serve_bench.py --decode assert on it)
-        probe = jax.eval_shape(_fresh_cache)
         self._cache_bytes = sum(
-            leaf.size * leaf.dtype.itemsize
-            for leaf in jax.tree.leaves(probe))
+            leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(
+                self.t_cache if self.spec else self.state[0]))
         # prefill batch sizes we're willing to compile (smallest >= the
         # waiting count is used; idle bursts prefill together)
         self._PREFILL_SIZES = tuple(sorted(
@@ -775,6 +787,39 @@ class SlotDecoder:
             target=self._loop_spec if self.spec else self._loop,
             daemon=True, name="slot-decoder")
         self._thread.start()
+
+    def _compile_prefills(self) -> None:
+        """self._prefill_at: suffix length -> the prefill compiled (or
+        loaded from the compile cache) for it from abstract shapes, every
+        rung of the ladder, before the first request: a request never
+        meets a compilation, whatever its length. This thread traces and
+        lowers one rung after another (threads would only pass the
+        interpreter lock around: side by side the rungs took longer on a
+        v5e's host than one after another), and each lowered program
+        compiles or loads in the pool meanwhile. Nothing runs on the
+        device here."""
+        import concurrent.futures as cf
+
+        jax, jnp = self._jax, self._jnp
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+        block = ((i32(self.B), i32()),) if self.B else ()
+
+        def lowered(length):
+            return self._paged_prefill_install.lower(
+                self._params, self.state, i32(1, length), i32(1),
+                i32(1, self._mp), i32(1), i32(), i32(), *block)
+
+        t0 = _stamp()
+        with cf.ThreadPoolExecutor(len(self._ladder)) as pool, \
+                (self.mesh or contextlib.nullcontext()):
+            jobs = [pool.submit(lowered(n).compile) for n in self._ladder]
+        self._prefill_at = dict(zip(self._ladder,
+                                    (job.result() for job in jobs)))
+        log.info("paged prefill compiled at %s positions in %.2f s",
+                 list(self._ladder), _stamp() - t0)
 
     # -- host API ----------------------------------------------------------
 
@@ -873,6 +918,9 @@ class SlotDecoder:
                 prefix_hit_pages=self.alloc.prefix_hit_pages,
                 prefix_hit_tokens=self.alloc.prefix_hit_tokens,
                 cow_clones=self.alloc.cow_clones,
+                # distinct suffix lengths prefill ran at since the build:
+                # the ladder's size at the most
+                prefill_shapes=len(self._prefill_lengths),
             )
         return out
 
@@ -947,7 +995,9 @@ class SlotDecoder:
         c["admitted"] += 1
         c["queue_wait_s_sum"] += r.t_admit - r.t_submit
         c["prefill_tokens_computed"] += prefill_tokens
+        c["prompt_tokens_real"] += self.P - r.pad
         c["prompt_tokens_submitted"] += self.P
+        self._prefill_lengths.add(prefill_tokens)
 
     def _note_first_tokens(self, requests) -> None:
         """The first read-back after an admission has just ended: the
@@ -1224,8 +1274,6 @@ class SlotDecoder:
     # -- admission: paged (per-request suffix prefill, page-gated) ---------
 
     def _admit_paged(self, owners, fail_all, last_rem, last_pos) -> None:
-        import numpy as np
-
         jnp = self._jnp
         phase = self._phase
         ctx = self.mesh if self.mesh is not None else None
@@ -1238,7 +1286,8 @@ class SlotDecoder:
                     return
                 if not self._validate(r):
                     continue
-                row = [int(t) for t in r.prompt]
+                # (the allocator reads the row's real pages only)
+                row = r.prompt
                 total = (self._block_end(r) if self.B
                          else self.P + r.req + self.draft_k)
                 if not self.alloc.can_admit(row, r.pad, total):
@@ -1251,12 +1300,14 @@ class SlotDecoder:
             try:
                 with phase("admit"):
                     plan = self.alloc.admit(slot, row, r.pad, total)
-                    suffix = np.asarray(row[plan.compute_start:], np.int32)
+                    # a rung of the ladder: compiled when the decoder
+                    # was built
+                    suffix = row[plan.compute_start:]
                 with phase("prefill"), (ctx or contextlib.nullcontext()):
                     if plan.copies:
                         self.state = self._apply_copies(
                             self.state, *self._cow_arrays(plan.copies))
-                    self.state = self._paged_prefill_install(
+                    self.state = self._prefill_at[len(suffix)](
                         self._params, self.state, suffix[None, :],
                         jnp.asarray([plan.compute_start], jnp.int32),
                         jnp.asarray(self.alloc.table[slot:slot + 1]),
@@ -1333,7 +1384,7 @@ class SlotDecoder:
                         return
                     if not self._validate(r):
                         continue
-                    row = [int(t) for t in r.prompt]
+                    row = r.prompt
                     total = self.P + r.req + k
                     if self.paged:
                         if not self.alloc.can_admit(row, r.pad, total):
@@ -1350,8 +1401,7 @@ class SlotDecoder:
                                 self.t_cache = copy_pages(
                                     self.t_cache,
                                     *self._cow_arrays(plan.copies))
-                            suffix = np.asarray(
-                                row[plan.compute_start:], np.int32)
+                            suffix = row[plan.compute_start:]
                             self.t_cache, self.d_cache, first = \
                                 self._spec_admit_paged(
                                     self._params, self._d_params,
@@ -1361,7 +1411,7 @@ class SlotDecoder:
                                                 jnp.int32),
                                     jnp.asarray(
                                         self.alloc.table[slot:slot + 1]),
-                                    jnp.asarray([row], jnp.int32),
+                                    jnp.asarray(row[None, :]),
                                     jnp.asarray([r.pad], jnp.int32),
                                     jnp.int32(slot))
                             n_pref = len(suffix)
@@ -1371,7 +1421,7 @@ class SlotDecoder:
                                 self._spec_admit_dense(
                                     self._params, self._d_params,
                                     self.t_cache, self.d_cache,
-                                    jnp.asarray([row], jnp.int32),
+                                    jnp.asarray(row[None, :]),
                                     jnp.asarray([r.pad], jnp.int32),
                                     jnp.int32(slot))
                             n_pref = self.P

@@ -791,7 +791,11 @@ def serve_lm_generator(name: str, model_name: str, *, prompt_len: int = 128,
     `prompt_len` and decoded with the KV-cache loop
     (runtime/generate.py) for exactly `max_new_tokens` steps — one
     compiled program per batch bucket, never per request shape (static
-    shapes are an XLA requirement). Responses carry the new tokens only;
+    shapes are an XLA requirement). Over the paged cache the padding
+    is geometry only: its pages are never allocated, and prefill
+    computes the shortest of a fixed ladder of suffix lengths that
+    covers the real tokens (runtime/kvcache.py `prefill_ladder`), each
+    compiled when the decoder is built, at the first request. Responses carry the new tokens only;
     a block model's (cfg.gen_block > 0, continuous batching over the
     paged cache only) are `{"tokens": [...], "fixed_at": [...]}`, the
     denoising step of its block at which each token was fixed.

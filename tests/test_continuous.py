@@ -437,3 +437,170 @@ class TestPerRequestBudgets:
                     [{"tokens": [1, 2, 3], "max_new_tokens": 9}])
         finally:
             served.close()
+
+
+# -- the paged prefill computes the prompt and not its padding -------------
+#
+# Prompts of 32 in pages of 4: the ladder is 8, 16, 24, 32. Each model
+# is served twice, by the decoder as it is and by one whose ladder has the
+# one rung 32 (the whole padded row, every request: what the decoder did
+# before it had a ladder), and a one-token model by generate() besides.
+
+LADDER_P, LADDER_PAGE, LADDER_NEW = 32, 4, 6
+LADDER_KINDS = {
+    "dense": {},
+    "block": dict(moe_every=1, n_experts=4, expert_top_k=2, moe_d_ff=32,
+                  qk_norm=True, gen_block=4, gen_mask_id=63),
+}
+# a prompt at each rung, one under and one over
+LADDER_LENGTHS = sorted({n for r in (8, 16, 24, 32) for n in
+                         (r - 1, r, r + 1) if 1 <= n <= LADDER_P} | {1})
+
+
+def ladder_prompt(real):
+    return [(7 * real + 3 * i) % 61 + 1 for i in range(real)]
+
+
+@pytest.fixture(scope="module")
+def compile_events():
+    """XLA compilations, cache hits among them, as the benchmark counts
+    them for `server.compiles.*`."""
+    from benchmarks.lib.harness import CompileCounter
+
+    return CompileCounter()
+
+
+@pytest.fixture(scope="module", params=sorted(LADDER_KINDS))
+def laddered(request):
+    """(kind, model, variables, the decoder, answers of the decoder whose
+    only rung is the whole row)."""
+    import jax
+
+    from kubeflow_tpu.models.registry import get_model
+    from kubeflow_tpu.runtime import kvcache
+    from kubeflow_tpu.serving.continuous import SlotDecoder
+
+    kind = request.param
+    model = get_model("transformer-test", vocab_size=64, max_seq_len=48,
+                      kv_pages=65, kv_page_size=LADDER_PAGE,
+                      **LADDER_KINDS[kind])
+    variables = model.init(jax.random.PRNGKey(0), np.zeros((1, 1), np.int32),
+                           train=False)
+    kw = dict(slots=3, prompt_len=LADDER_P, max_new_tokens=LADDER_NEW,
+              prefix_cache=False)
+    ladder = kvcache.prefill_ladder
+    kvcache.prefill_ladder = lambda prompt_len, page_size: (prompt_len,)
+    try:
+        whole = SlotDecoder(model, variables, **kw)
+        try:
+            assert whole._ladder == (LADDER_P,)
+            want = {n: whole.submit(ladder_prompt(n))
+                    for n in LADDER_LENGTHS}
+            assert whole.stats()["prefill_tokens_computed"] == (
+                LADDER_P * len(LADDER_LENGTHS))
+        finally:
+            whole.close()
+    finally:
+        kvcache.prefill_ladder = ladder
+    dec = SlotDecoder(model, variables, **kw)
+    yield kind, model, variables, dec, want
+    dec.close()
+
+
+class TestPrefillLadder:
+    def test_every_rung_is_compiled_at_the_build(self, laddered):
+        _, _, _, dec, _ = laddered
+        assert dec._ladder == (8, 16, 24, 32)
+        assert sorted(dec._prefill_at) == list(dec._ladder)
+        assert dec.stats()["prefill_shapes"] == 0
+
+    @pytest.mark.parametrize("real", LADDER_LENGTHS)
+    def test_tokens_equal_the_whole_row_prefill(self, laddered, real):
+        kind, model, variables, dec, want = laddered
+        before = dec.stats()
+        got = dec.submit(ladder_prompt(real))
+        assert got == want[real]
+        if kind == "dense":
+            assert got == reference_generate(
+                model, variables, ladder_prompt(real),
+                prompt_len=LADDER_P, max_new=LADDER_NEW)
+        after = dec.stats()
+        computed = (after["prefill_tokens_computed"]
+                    - before["prefill_tokens_computed"])
+        assert computed == next(n for n in dec._ladder if n >= real)
+        assert (after["prompt_tokens_real"]
+                - before["prompt_tokens_real"]) == real
+        assert (after["prompt_tokens_submitted"]
+                - before["prompt_tokens_submitted"]) == LADDER_P
+        # the pages of the padding were never drawn
+        assert after["kv_pages_used"] == 0
+        dec.alloc.check()
+
+    def test_no_length_compiles_after_the_build(self, laddered,
+                                                compile_events):
+        """Once the single and the fused step have run, prompts of every
+        length, one at a time and in a burst, compile nothing: every
+        suffix is a rung, and every rung was compiled at the build."""
+        _, _, _, dec, _ = laddered
+        dec.submit(ladder_prompt(5))
+        dec.submit(ladder_prompt(5), max_new=2)
+        n0 = compile_events.n
+        for real in range(0, LADDER_P + 1):
+            assert dec.submit(ladder_prompt(real), max_new=2)
+        threads = [threading.Thread(
+            target=dec.submit, args=(ladder_prompt(real),))
+            for real in (2, 9, 17, 30)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert compile_events.n == n0
+        st = dec.stats()
+        assert st["prefill_shapes"] == len(dec._ladder)
+        assert st["completed"] == st["admitted"]
+
+    def test_what_the_trash_page_holds_changes_no_token(self, laddered):
+        """Pad pages are the trash page, which idle slots and the
+        padding's own positions write: large finite values there, keys
+        and values, leave every answer as it was."""
+        import jax
+
+        _, _, _, dec, want = laddered
+        assert dec.active_slots == 0
+        dec.state = (jax.tree.map(lambda pool: pool.at[0].set(1e4),
+                                  dec.state[0]),) + tuple(dec.state[1:])
+        for real in (1, 7, 16, 23):
+            assert dec.submit(ladder_prompt(real)) == want[real]
+
+
+def test_a_prefix_hit_is_cut_back_to_a_rung(lm):
+    """With the prefix cache on, a second prompt that shares 20 of its 29
+    tokens with the first computes 16 positions, a rung, where 9 are new,
+    and serves the tokens it would alone."""
+    import jax
+
+    from kubeflow_tpu.models.registry import get_model
+    from kubeflow_tpu.serving.continuous import SlotDecoder
+
+    model = get_model("transformer-test", vocab_size=64, max_seq_len=48,
+                      kv_pages=65, kv_page_size=LADDER_PAGE)
+    variables = model.init(jax.random.PRNGKey(0), np.zeros((1, 1), np.int32),
+                           train=False)
+    first = ladder_prompt(29)
+    second = first[:20] + [(t + 7) % 61 + 1 for t in first[20:]]
+    want = reference_generate(model, variables, second,
+                              prompt_len=LADDER_P, max_new=LADDER_NEW)
+    dec = SlotDecoder(model, variables, slots=2, prompt_len=LADDER_P,
+                      max_new_tokens=LADDER_NEW)
+    try:
+        dec.submit(first)
+        assert dec.submit(second) == want
+        st = dec.stats()
+        # pad 3: pages 0-4 hit (positions 0-19 hold 17 shared tokens), and
+        # the rung of 16 starts at 16: four of them are claimed
+        assert st["prefix_hit_pages"] == 4 and st["cow_clones"] == 0
+        assert st["prefill_tokens_computed"] == 32 + 16
+        assert st["prefill_shapes"] == 2
+        dec.alloc.check()
+    finally:
+        dec.close()

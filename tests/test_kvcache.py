@@ -49,23 +49,63 @@ def reference_generate(model, variables, tokens, prompt_len=8, max_new=4):
     return [int(t) for t in np.asarray(out)[0, prompt_len:]]
 
 
+def padded(real, prompt_len=32, first=1):
+    """A left-padded row of `real` distinct tokens from `first` on, and
+    its pad length."""
+    pad = prompt_len - real
+    return [0] * pad + list(range(first, first + real)), pad
+
+
+class TestPrefillLadder:
+    @pytest.mark.parametrize("prompt_len,page,want", [
+        (4096, 16, (1024, 2048, 3072, 4096)),
+        (1024, 16, (256, 512, 768, 1024)),
+        (32, 4, (8, 16, 24, 32)),
+        (8, 4, (4, 8)),
+        (10, 4, (4, 8, 10)),    # whole pages, cut at the prompt's end
+        (8, 3, (3, 6, 8)),
+        (1, 16, (1,)),
+    ])
+    def test_rungs(self, prompt_len, page, want):
+        from kubeflow_tpu.runtime.kvcache import prefill_ladder
+
+        assert prefill_ladder(prompt_len, page) == want
+
+    @pytest.mark.parametrize("prompt_len", [1, 7, 64, 100, 1000, 8192])
+    @pytest.mark.parametrize("page", [1, 4, 16, 128])
+    def test_shape_of_any_ladder(self, prompt_len, page):
+        from kubeflow_tpu.runtime.kvcache import prefill_ladder
+
+        rungs = prefill_ladder(prompt_len, page)
+        assert 1 <= len(rungs) <= 8
+        assert list(rungs) == sorted(set(rungs)) and rungs[-1] == prompt_len
+        assert all(n % page == 0 for n in rungs[:-1])
+        # a prompt pays for at most twice its own length, above a rung
+        for real in range(rungs[0], prompt_len + 1):
+            assert next(n for n in rungs if n >= real) < 2 * real + page
+
+
 class TestPageAllocator:
-    def test_admit_shares_prefix_and_cows_the_full_hit(self):
-        a = PageAllocator(num_pages=24, page_size=8, slots=4,
+    def test_admit_shares_the_prefix_up_to_a_rung(self):
+        """Pages of 8, prompts of 32: the ladder is 8, 16, 24, 32, so a
+        hit is claimed up to where the shortest covering rung starts."""
+        a = PageAllocator(num_pages=40, page_size=8, slots=6,
                           max_pages_per_slot=6)
         row = list(range(1, 33))                    # 4 full pages
         p0 = a.admit(0, row, 0, 40)
         assert p0.shared_pages == 0 and p0.compute_start == 0
         a.check()
-        # identical prompt: every full page hits; the final position is
-        # recomputed for logits, so the last shared page COW-clones
+        # identical prompt: every full page hits, and the last rung (one
+        # page here) is computed again for the first token's logits,
+        # into a private page: nothing to copy
         need, cached = a.plan(row, 0, 40)
-        assert cached == 32
+        assert (need, cached) == (2, 24)
         p1 = a.admit(1, row, 0, 40)
-        assert p1.shared_pages == 4 and p1.compute_start == 31
-        assert len(p1.copies) == 1 and a.cow_clones == 1
+        assert p1.shared_pages == 3 and p1.compute_start == 24
+        assert not p1.copies and a.cow_clones == 0
+        assert a.table[1, 3] not in a.table[0]
         a.check()
-        # page-aligned divergence: 3 shared pages, no COW
+        # page-aligned divergence: 3 shared pages
         p2 = a.admit(2, row[:24] + [9] * 8, 0, 40)
         assert p2.shared_pages == 3 and p2.compute_start == 24
         assert not p2.copies
@@ -74,15 +114,52 @@ class TestPageAllocator:
         p3 = a.admit(3, row[:28] + [9] * 4, 0, 40)
         assert p3.shared_pages == 3 and p3.compute_start == 24
         a.check()
+        # two pages hit, 16 to compute: a rung
+        p4 = a.admit(4, row[:16] + [9] * 16, 0, 40)
+        assert p4.shared_pages == 2 and p4.compute_start == 16
+        a.check()
+        # one page and a half hit: 24 to compute
+        p5 = a.admit(5, row[:12] + [9] * 20, 0, 40)
+        assert p5.shared_pages == 1 and p5.compute_start == 8
+        assert a.prefix_hit_pages == 12 and a.prefix_hit_tokens == 96
+        a.check()
 
-    def test_plan_accounts_for_the_cow_extra_page(self):
-        a = PageAllocator(num_pages=8, page_size=4, slots=2,
+    def test_a_rung_that_starts_inside_a_claimed_page_cows_it(self):
+        """A prompt length that is no whole number of pages (10 by 4:
+        rungs 4, 8, 10): the full hit computes the last 4 positions from
+        6 on, inside the second shared page, which is cloned first."""
+        a = PageAllocator(num_pages=24, page_size=4, slots=3,
                           max_pages_per_slot=3)
-        row = list(range(1, 9))                     # 2 full pages
-        a.admit(0, row, 0, 8)
-        need, cached = a.plan(row, 0, 8)
-        assert cached == 8
-        assert need == 1                            # 0 fresh + 1 COW clone
+        row = list(range(1, 11))
+        assert a.admit(0, row, 0, 12).compute_start == 0
+        assert a.plan(row, 0, 12) == (2, 8)     # the third page + the clone
+        p1 = a.admit(1, row, 0, 12)
+        assert p1.shared_pages == 2 and p1.compute_start == 6
+        assert len(p1.copies) == 1 and a.cow_clones == 1
+        src, dst = p1.copies[0]
+        assert src == a.table[0, 1] and dst == a.table[1, 1] and src != dst
+        assert a.table[1, 0] == a.table[0, 0]
+        a.check()
+        a.free(0)
+        a.free(1)
+        a.check()
+
+    @pytest.mark.parametrize("page,length,total,want", [
+        (4, 8, 8, (1, 4)),      # a rung of one page computed again
+        (4, 10, 12, (2, 8)),    # the unaligned tail's page + the COW clone
+        (4, 8, 16, (3, 4)),     # ... and the decode pages behind it
+    ])
+    def test_plan_accounts_for_every_page_of_a_full_hit(
+            self, page, length, total, want):
+        a = PageAllocator(num_pages=12, page_size=page, slots=2,
+                          max_pages_per_slot=4)
+        row = list(range(1, length + 1))
+        a.admit(0, row, 0, total)
+        assert a.plan(row, 0, total) == want
+        free = a.free_pages
+        a.admit(1, row, 0, total)
+        a.append(1, total)
+        assert free - a.free_pages == want[0]
         a.check()
 
     def test_free_returns_pages_and_zeroes_the_table_row(self):
@@ -117,11 +194,11 @@ class TestPageAllocator:
             if op < 0.40 and len(live) < a.slots:
                 slot = next(s for s in range(a.slots) if s not in live)
                 plen = rng.randrange(1, 25)
-                row = [rng.randrange(0, 4) for _ in range(plen)]
+                row = [rng.randrange(0, 2) for _ in range(plen)]
                 total = plen + rng.randrange(0, 16)
                 if pages_for(total, a.page_size) > a.max_pages_per_slot:
                     continue
-                pad = rng.randrange(0, 2)
+                pad = rng.choice([0, 0, 1, rng.randrange(0, plen + 1)])
                 if a.can_admit(row, pad, total):
                     a.admit(slot, row, pad, total)
                     live[slot] = (total, plen)
@@ -140,6 +217,8 @@ class TestPageAllocator:
                 del live[slot]
             a.check()
         assert admits > 100   # the run actually exercised admission
+        # ... and prefix hits, and the clones of write_barrier
+        assert a.prefix_hit_pages > 100 and a.cow_clones > 50
         for slot in sorted(live):
             a.free(slot)
             a.check()
@@ -161,14 +240,16 @@ class TestPageAllocator:
         a.check()
         assert a.free_pages == 2               # 4 pages live in the index
         row = list(range(1, 9))
-        # total_len 24 needs 6 pages - 2 hits + 1 COW = 5, obtainable =
-        # free(2) + NON-HIT evictables(2) = 4: the naive
-        # `need <= free + all evictables(4+2)` gate would admit and
-        # starve; the correct gate refuses. 20 (need 4) fits exactly.
-        assert a.can_admit(row, 0, 20) is True
-        assert a.can_admit(row, 0, 24) is False
-        a.admit(0, row, 0, 20)
-        a.append(0, 20)                          # never raises
+        # rungs 4 and 8: of the two hits the first is claimed, the second
+        # computed again. total_len 28 needs 7 pages - 1 claimed = 6,
+        # obtainable = free(2) + evictables that are NOT claimed (3) = 5:
+        # the naive `need <= free + all evictables (2 + 4)` gate would
+        # admit and starve; the correct gate refuses. 24 (need 5) fits
+        # exactly.
+        assert a.can_admit(row, 0, 24) is True
+        assert a.can_admit(row, 0, 28) is False
+        a.admit(0, row, 0, 24)
+        a.append(0, 24)                          # never raises
         a.check()
 
     def test_reset_forgets_everything(self):
@@ -178,6 +259,106 @@ class TestPageAllocator:
         a.reset()
         a.check()
         assert a.free_pages == 15 and a.used_pages == 0
+
+
+class TestPadPagesAreNoPages:
+    """Prompts of 32 in pages of 4 (rungs 8, 16, 24, 32), left-padded:
+    a page that holds no real position is no page."""
+
+    P, PS, TOTAL = 32, 4, 40
+
+    def alloc(self, **kw):
+        return PageAllocator(num_pages=64, page_size=self.PS, slots=4,
+                             max_pages_per_slot=10, **kw)
+
+    @pytest.mark.parametrize("real", [1, 3, 4, 5, 13, 31, 32])
+    def test_pad_pages_are_trash_and_cost_nothing(self, real):
+        a = self.alloc()
+        row, pad = padded(real)
+        pad_pages = pad // self.PS
+        need, cached = a.plan(row, pad, self.TOTAL)
+        assert need == 10 - pad_pages and cached == 0
+        assert a.can_admit(row, pad, self.TOTAL)
+        plan = a.admit(0, row, pad, self.TOTAL)
+        assert (a.table[0, :pad_pages] == TRASH_PAGE).all()
+        assert (a.table[0, pad_pages:8] != TRASH_PAGE).all()
+        assert a.used_pages == 8 - pad_pages
+        # the index holds the complete pages from the first real one on
+        assert len(a._prefix) == 8 - pad_pages
+        assert TRASH_PAGE not in a._prefix.values()
+        assert plan.shared_pages == 0 and not plan.copies
+        a.check()
+        a.append(0, self.TOTAL)
+        assert a.used_pages == need
+        a.write_barrier(0, 0, self.TOTAL)       # trash is never cloned
+        assert (a.table[0, :pad_pages] == TRASH_PAGE).all()
+        a.check()
+        a.free(0)
+        a.check()
+        assert (a.table[0] == TRASH_PAGE).all()
+        assert a.used_pages == len(a._prefix) == 8 - pad_pages
+
+    def test_a_pool_of_real_pages_admits_what_padding_would_refuse(self):
+        """4 usable pages hold a prompt of 5 real tokens and 4 new ones
+        (3 pages), whatever the 27 positions of padding before them."""
+        a = PageAllocator(num_pages=5, page_size=4, slots=1,
+                          max_pages_per_slot=9, prefix_cache=False)
+        row, pad = padded(5)
+        assert a.plan(row, pad, 36) == (3, 0)
+        assert a.can_admit(row, pad, 36)
+        a.admit(0, row, pad, 36)
+        a.append(0, 36)
+        a.check()
+
+    def test_an_empty_prompt_still_computes_a_rung(self):
+        a = self.alloc()
+        plan = a.admit(0, [0] * self.P, self.P, self.TOTAL)
+        assert plan.compute_start == self.P - 8
+        assert (a.table[0, :7] == TRASH_PAGE).all() and a.table[0, 7]
+        a.check()
+
+    @pytest.mark.parametrize("hit", [False, True], ids=["miss", "hit"])
+    @pytest.mark.parametrize("real", range(1, 33))
+    def test_the_computed_suffix_is_a_rung(self, real, hit):
+        """Every real length, alone and behind a prompt of the same pad
+        that shares its leading tokens (all but the last three)."""
+        from kubeflow_tpu.runtime.kvcache import prefill_ladder
+
+        a = self.alloc()
+        row, pad = padded(real)
+        shared = 0
+        if hit:
+            other = list(row)
+            other[-3:] = [60, 61, 62][-min(3, real):]
+            a.admit(1, other, pad, self.TOTAL)
+            shared = max(0, (self.P - 3) // self.PS - pad // self.PS)
+        plan = a.admit(0, row, pad, self.TOTAL)
+        length = self.P - plan.compute_start
+        rungs = prefill_ladder(self.P, self.PS)
+        assert length in rungs
+        # the shortest rung that covers what no claimed page holds
+        todo = self.P - max(pad, (pad // self.PS + shared) * self.PS)
+        assert length == next(n for n in rungs if n >= max(1, todo))
+        assert plan.shared_pages == max(
+            0, min(shared, plan.compute_start // self.PS - pad // self.PS))
+        assert not plan.copies
+        a.check()
+
+    @pytest.mark.parametrize("real", [1, 5, 12, 20])
+    def test_one_real_length_and_other_tokens_share_nothing(self, real):
+        """The allocator's trap (PERF.md section 4 before PR 30): two
+        left-padded prompts of one real length shared their all-zero pad
+        pages, a prefix hit that left a suffix of a new length."""
+        a = self.alloc()
+        row_a, pad = padded(real, first=1)
+        row_b, _ = padded(real, first=40)
+        pa = a.admit(0, row_a, pad, self.TOTAL)
+        pb = a.admit(1, row_b, pad, self.TOTAL)
+        assert pb.shared_pages == 0 and a.prefix_hit_pages == 0
+        assert pb.compute_start == pa.compute_start
+        assert not set(a.table[0][a.table[0] > 0]) & set(
+            a.table[1][a.table[1] > 0])
+        a.check()
 
 
 class TestPagedDecode:
@@ -233,15 +414,19 @@ class TestPagedDecode:
         finally:
             dec.close()
 
-    def test_prefix_reuse_cow_does_not_corrupt_the_sharer(self, lm):
+    @pytest.mark.parametrize("page,hits,clones", [(4, 2, 0), (3, 4, 2)])
+    def test_prefix_reuse_cow_does_not_corrupt_the_sharer(
+            self, lm, page, hits, clones):
         """Three live slots share prompt pages; the full-hit admissions
-        COW-clone the page they must rewrite. Every decode must still
-        equal the no-sharing reference — a clone that mutated the
+        compute their last rung again, into a private page (pages of 4:
+        rungs 4, 8) or from inside the last shared page, which they
+        COW-clone first (pages of 3: rungs 3, 6, 8). Every decode must
+        still equal the no-sharing reference — a clone that mutated the
         shared original would corrupt its sharers' tokens."""
         from kubeflow_tpu.serving.continuous import SlotDecoder
 
         model, variables = lm
-        pm = paged_model(kv_pages=25, kv_page_size=4)
+        pm = paged_model(kv_pages=25, kv_page_size=page)
         dec = SlotDecoder(pm, variables, slots=4, prompt_len=8,
                           max_new_tokens=6)
         try:
@@ -263,14 +448,20 @@ class TestPagedDecode:
                 t.join(timeout=120)
             assert results == [want] * 3
             st = dec.stats()
-            assert st["prefix_hit_pages"] >= 2   # sharing really happened
-            assert st["cow_clones"] >= 1         # and the COW path ran
+            assert st["prefix_hit_pages"] == hits  # sharing really happened
+            assert st["cow_clones"] == clones      # and the COW path ran
+            assert st["prefill_tokens_computed"] == 8 + 2 * page
+            assert st["prompt_tokens_real"] == 24
+            dec.alloc.check()
         finally:
             dec.close()
 
-    def test_admission_gates_on_pages_not_slots(self, lm):
-        """A pool sized for ~2 live sequences with 6 slots: requests
-        queue on page availability and all complete as pages free."""
+    @pytest.mark.parametrize("real,at_once", [(6, 2), (2, 3)])
+    def test_admission_gates_on_pages_not_slots(self, lm, real, at_once):
+        """A pool sized for 2 or 3 live sequences with 6 slots: requests
+        queue on page availability and all complete as pages free. A
+        sequence holds the pages of its real tokens and its answer: 3
+        for 6 + 4 tokens, 2 for 2 + 4 behind a page of padding."""
         from kubeflow_tpu.serving.continuous import SlotDecoder
 
         model, variables = lm
@@ -278,7 +469,7 @@ class TestPagedDecode:
         dec = SlotDecoder(pm, variables, slots=6, prompt_len=8,
                           max_new_tokens=4, prefix_cache=False)
         try:
-            prompts = [[i + 1, i + 2] for i in range(6)]
+            prompts = [list(range(i + 1, i + 1 + real)) for i in range(6)]
             want = [reference_generate(model, variables, p)
                     for p in prompts]
             results: list = [None] * 6
@@ -290,8 +481,8 @@ class TestPagedDecode:
             for t in threads:
                 t.join(timeout=120)
             assert results == want
-            # 7 usable pages / 3 pages per sequence -> never 3 at once
-            assert dec.stats()["peak_active"] <= 2
+            # 7 usable pages / 3 (or 2) pages per sequence
+            assert dec.stats()["peak_active"] <= at_once
         finally:
             dec.close()
 
@@ -439,11 +630,13 @@ class TestSpeculativeLockstep:
         finally:
             dec.close()
 
-    def test_spec_composes_with_paged_and_prefix_reuse(self, lm):
+    @pytest.mark.parametrize("page,hits,clones", [(4, 1, 0), (3, 2, 1)])
+    def test_spec_composes_with_paged_and_prefix_reuse(
+            self, lm, page, hits, clones):
         from kubeflow_tpu.serving.continuous import SlotDecoder
 
         model, variables = lm
-        pm = paged_model(kv_pages=33, kv_page_size=4)
+        pm = paged_model(kv_pages=33, kv_page_size=page)
         dec = SlotDecoder(pm, variables, slots=3, prompt_len=8,
                           max_new_tokens=4, draft_model=model,
                           draft_variables=variables, draft_k=3)
@@ -453,7 +646,9 @@ class TestSpeculativeLockstep:
             assert dec.submit(p) == want
             assert dec.submit(p) == want      # prefix-cache hit path
             st = dec.stats()
-            assert st["prefix_hit_pages"] >= 2 and st["cow_clones"] >= 1
+            assert (st["prefix_hit_pages"], st["cow_clones"]) == (hits, clones)
+            # the hit's suffix is a rung of the ladder too
+            assert st["prefill_tokens_computed"] == 8 + page
             assert st["spec_tokens_emitted"] / st["spec_rounds"] > 1.0
         finally:
             dec.close()

@@ -131,6 +131,35 @@ def test_the_step_programs_keep_their_module_names(program_names):
             "block:jit__paged_prefill_install"} <= program_names
 
 
+@pytest.mark.parametrize("more", [
+    {}, dict(moe_every=1, n_experts=4, expert_top_k=2, moe_d_ff=32,
+             qk_norm=True, gen_block=4, gen_mask_id=63)],
+    ids=["one-token", "block"])
+def test_the_prefill_keeps_its_module_name_at_every_rung(more):
+    """The prefill runs at the lengths of a ladder (runtime/kvcache.py),
+    each compiled when the decoder is built: what the device trace calls
+    each of them is what the decoder dispatches, read from the compiled
+    programs themselves."""
+    import jax
+
+    from kubeflow_tpu.models.registry import get_model
+    from kubeflow_tpu.serving.continuous import SlotDecoder
+
+    model = get_model("transformer-test", vocab_size=64, max_seq_len=48,
+                      kv_pages=25, kv_page_size=4, **more)
+    variables = model.init(jax.random.PRNGKey(0), np.zeros((1, 1), np.int32),
+                           train=False)
+    dec = SlotDecoder(model, variables, slots=2, prompt_len=32,
+                      max_new_tokens=8)
+    try:
+        assert sorted(dec._prefill_at) == [8, 16, 24, 32]
+        for length, program in dec._prefill_at.items():
+            name = re.search(r"HloModule (\w+)", program.as_text()).group(1)
+            assert name == "jit__paged_prefill_install", (length, name)
+    finally:
+        dec.close()
+
+
 def test_the_kernels_names_are_what_their_definitions_say():
     """The names said at the kernels' definitions (ops/moe.py,
     ops/paged_attention.py) are the ones the metric files look for."""
