@@ -9,6 +9,7 @@ Must run before jax initializes a backend, hence env mutation at import
 time (pytest imports conftest before test modules).
 """
 
+import functools
 import os
 
 # Unconditional: tests are hermetic CPU by design, whatever the machine
@@ -101,6 +102,54 @@ def make_segments(b, l, n_docs, seed=7):
     import jax.numpy as jnp
 
     return jnp.asarray(seg)
+
+
+# -- a SlotDecoder of each kind of step over each cache ---------------------
+#
+# The scheduler loop is one (serving/continuous.py), whatever step it
+# drives (serving/steps.py): the tests of the loop's contract run over
+# these modes, on toy models of one geometry (prompts of 8, pages of 4).
+
+DECODER_MODES = ("token-dense", "token-paged", "block", "spec-dense",
+                 "spec-paged")
+BLOCK_MODEL = dict(moe_every=1, n_experts=4, expert_top_k=2, moe_d_ff=32,
+                   qk_norm=True, gen_block=4, gen_mask_id=63)
+
+
+@functools.lru_cache(maxsize=None)
+def _toy_lm(**more):
+    import jax
+    import numpy as np
+
+    from kubeflow_tpu.models.registry import get_model
+
+    model = get_model("transformer-test", vocab_size=64, max_seq_len=24,
+                      **more)
+    return model, model.init(jax.random.PRNGKey(0),
+                             np.zeros((1, 1), np.int32), train=False)
+
+
+def slot_decoder(mode: str, **kw):
+    """A SlotDecoder of `mode` (one of DECODER_MODES); `kw` goes to it.
+    A speculative one drafts with the target's own weights over a dense
+    cache, two tokens a round."""
+    from kubeflow_tpu.serving.continuous import SlotDecoder
+
+    kind, _, cache = mode.partition("-")
+    more = dict(BLOCK_MODEL) if kind == "block" else {}
+    if cache != "dense":
+        more.update(kv_pages=33, kv_page_size=4)
+    model, variables = _toy_lm(**more)
+    if kind == "spec":
+        kw = dict(draft_model=_toy_lm()[0], draft_variables=variables,
+                  draft_k=2, **kw)
+    return SlotDecoder(model, variables, prompt_len=8, **kw)
+
+
+def answer_tokens(answer) -> list:
+    """The tokens of what `submit` returned: a block model's answer is
+    ``{"tokens": [...], "fixed_at": [...]}``."""
+    return answer["tokens"] if isinstance(answer, dict) else answer
 
 
 # -- virtual-time determinism guard (ISSUE 16) -------------------------------

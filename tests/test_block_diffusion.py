@@ -9,6 +9,7 @@ The program runs in float32 here, so that it and the reference agree to
 rounding of the last bits and a fault of any size shows; on the chip it
 runs in bfloat16 against the limits of the mix's file (PERF.md)."""
 
+import functools
 import hashlib
 import re
 import threading
@@ -16,6 +17,7 @@ import time
 
 import numpy as np
 import pytest
+from conftest import BLOCK_MODEL
 
 CONFIG = dict(
     hidden_size=64, num_hidden_layers=2, num_attention_heads=4,
@@ -307,36 +309,84 @@ def test_qk_norm(qk_norm):
     assert float(jnp.abs(got - want).max()) <= 2e-4
 
 
-# -- what a one-token model's programs still are -----------------------------------
+# -- what the programs still are ------------------------------------------------------
 
 # sha256 of `jax.make_jaxpr` of a paged transformer-test decoder's `_tick`
 # at commit 32e321a (the parent of the PR that brought the block step),
 # addresses struck out: gen_block = 0 compiles the program it compiled.
 TICK_JAXPR_AT_PARENT = (
     "c8a1309ec2f2ec039005c61b4835b49f8eb097a300eaef2849e76cc4db11236c")
+# The same of the other step programs, at commit aa28e3e (the parent of
+# the PR that moved them out of SlotDecoder.__init__ into
+# serving/steps.py): equal jaxprs are equal XLA modules under the same
+# names, equal compile-cache keys, and so equal set-up and device time.
+JAXPRS_AT_PARENT = {
+    "paged tick": TICK_JAXPR_AT_PARENT,
+    "paged fused":
+        "2ff8ce2e9ee4c968ddbc4ebf37e7230d7b7d0fcf34f2d10e88cb391cc7567f66",
+    "paged prefill-install":
+        "288e9ad20e847f9109d175efbb5917d34ae5bb4ecf6109bf55f747a25184fe4a",
+    "block tick":
+        "c056eff571af368a8d301e1cb9be4dc9ad8c6d1bf58221a2e30b063c29648573",
+    "block fused":
+        "f651468333f822b63c264141d9fa01670ef329b93ff9e236923185b2bce13efd",
+    "block prefill-install":
+        "4a41b9be2e5d46f3f64c8fd1b497fb4b37fabff9a941315732cd72853c01c299",
+    "dense tick":
+        "d6b2e876980d9585d33e6208711e4bd472b9c60ced0e578de6a2dc70464c0bad",
+}
 
 
-def test_gen_block_zero_leaves_the_dense_tick_what_it_was():
+@functools.lru_cache(maxsize=None)
+def program_jaxprs(cache: str) -> dict:
+    """The hashes of one decoder's step programs: `cache` is "paged",
+    "block" (a block model over the paged cache) or "dense"."""
     import jax
     import jax.numpy as jnp
 
     from kubeflow_tpu.models.registry import get_model
     from kubeflow_tpu.serving.continuous import SlotDecoder
 
+    def sha(fn, *args):
+        text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(fn)(*args)))
+        return hashlib.sha256(text.encode()).hexdigest()
+
+    more = {} if cache == "dense" else dict(kv_pages=25, kv_page_size=4)
+    if cache == "block":
+        more.update(BLOCK_MODEL)
     model = get_model("transformer-test", vocab_size=64, max_seq_len=24,
-                      kv_pages=25, kv_page_size=4)
+                      **more)
     variables = model.init(jax.random.PRNGKey(0), np.zeros((1, 1), np.int32),
                            train=False)
     dec = SlotDecoder(model, variables, slots=3, prompt_len=8,
-                      max_new_tokens=6)
+                      max_new_tokens=8 if cache == "block" else 6)
     try:
-        assert dec.B == 0 and "block_passes" not in dec.stats()
-        text = str(jax.make_jaxpr(dec._step)(
-            dec._params, dec.state, jnp.asarray(dec.alloc.table)))
+        assert dec.B == (4 if cache == "block" else 0)
+        assert ("block_passes" in dec.stats()) == (cache == "block")
+        if cache == "dense":
+            return {"dense tick": sha(dec._step, dec._params, dec.state)}
+        table = jnp.asarray(dec.alloc.table)
+        opening = ((jnp.zeros((4,), jnp.int32), jnp.int32(1)),) \
+            if cache == "block" else ()
+        return {
+            f"{cache} tick": sha(dec._step, dec._params, dec.state, table),
+            f"{cache} fused": sha(dec._step_fused, dec._params, dec.state,
+                                  table),
+            f"{cache} prefill-install": sha(
+                dec.step._paged_prefill_install, dec._params, dec.state,
+                jnp.zeros((1, 8), jnp.int32), jnp.zeros((1,), jnp.int32),
+                table[:1], jnp.zeros((1,), jnp.int32), jnp.int32(0),
+                jnp.int32(1), *opening)}
     finally:
         dec.close()
-    text = re.sub(r"0x[0-9a-f]+", "0x", text)
-    assert hashlib.sha256(text.encode()).hexdigest() == TICK_JAXPR_AT_PARENT
+
+
+@pytest.mark.parametrize("program", list(JAXPRS_AT_PARENT))
+def test_the_step_programs_are_what_they_were(program):
+    """Its first case was `test_gen_block_zero_leaves_the_dense_tick_what_
+    it_was`: the paged one-token tick, with the hash it had."""
+    assert program_jaxprs(program.split()[0])[program] \
+        == JAXPRS_AT_PARENT[program]
 
 
 # -- the refusals -------------------------------------------------------------------

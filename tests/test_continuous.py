@@ -6,6 +6,7 @@ import threading
 
 import numpy as np
 import pytest
+from conftest import DECODER_MODES, answer_tokens, slot_decoder
 
 
 @pytest.fixture(scope="module")
@@ -95,12 +96,9 @@ class TestSlotDecoder:
         finally:
             dec.close()
 
-    def test_close_fails_pending_cleanly(self, lm):
-        from kubeflow_tpu.serving.continuous import SlotDecoder
-
-        model, variables = lm
-        dec = SlotDecoder(model, variables, slots=1, prompt_len=8,
-                          max_new_tokens=2)
+    @pytest.mark.parametrize("mode", DECODER_MODES)
+    def test_close_fails_pending_cleanly(self, mode):
+        dec = slot_decoder(mode, slots=1, max_new_tokens=2)
         dec.close()
         with pytest.raises(RuntimeError, match="shut down"):
             dec.submit([1, 2, 3])
@@ -185,7 +183,7 @@ class TestSchedulingFairness:
                           max_new_tokens=3)
         try:
             calls: list = []
-            real_prefill = dec._prefill
+            real_prefill = dec.step._prefill
 
             def spy(params, prompts, pads):
                 calls.append(int(prompts.shape[0]))
@@ -193,7 +191,7 @@ class TestSchedulingFairness:
 
             # hold the loop while the burst queues up: pause via a fake
             # empty free list, then restore
-            dec._prefill = spy
+            dec.step._prefill = spy
             held, dec._free = dec._free, []
             prompts = [[i + 1, i + 2] for i in range(4)]
             want = [reference_generate(model, variables, p, max_new=3)
@@ -225,7 +223,7 @@ class TestSchedulingFairness:
                           max_new_tokens=4)
         try:
             trace: list = []
-            real_prefill, real_step = dec._prefill, dec._step
+            real_prefill, real_step = dec.step._prefill, dec._step
 
             def spy_prefill(*a, **k):
                 trace.append("P")
@@ -235,7 +233,7 @@ class TestSchedulingFairness:
                 trace.append("S")
                 return real_step(*a, **k)
 
-            dec._prefill, dec._step = spy_prefill, spy_step
+            dec.step._prefill, dec._step = spy_prefill, spy_step
             prompts = [[i + 1, i + 2] for i in range(4)]
             want = [reference_generate(model, variables, p) for p in prompts]
             results: dict = {}
@@ -300,15 +298,13 @@ def test_serve_bench_tool_runs_both_modes():
 class TestFailureContainment:
     """The high-effort decode review's findings, pinned."""
 
-    def test_malformed_row_in_burst_fails_only_its_caller(self, lm):
+    @pytest.mark.parametrize("mode", DECODER_MODES)
+    def test_malformed_row_in_burst_fails_only_its_caller(self, lm, mode):
         """A wrong-length submit_padded row must fail THAT caller; valid
         co-batched requests get THEIR OWN continuations (row/prefill
         alignment survives the drop)."""
-        from kubeflow_tpu.serving.continuous import SlotDecoder
-
         model, variables = lm
-        dec = SlotDecoder(model, variables, slots=4, prompt_len=8,
-                          max_new_tokens=3)
+        dec = slot_decoder(mode, slots=4, max_new_tokens=3)
         try:
             held, dec._free = dec._free, []  # queue the burst together
             results: dict = {}
@@ -335,41 +331,68 @@ class TestFailureContainment:
                 t.join(timeout=120)
             assert results["bad"] == "valueerror"
             for i in range(3):
-                assert results[i] == reference_generate(
-                    model, variables, [i + 1, i + 2], max_new=3), i
+                # what the request gets alone, and (one token a step,
+                # speculative or not) what plain greedy decode gives
+                assert results[i] == dec.submit([i + 1, i + 2]), i
+                if mode != "block":
+                    assert results[i] == reference_generate(
+                        model, variables, [i + 1, i + 2], max_new=3), i
         finally:
             dec.close()
 
-    def test_step_failure_recovers_instead_of_zombie(self, lm):
-        """A runtime failure in the donated step poisons in-flight
-        requests ONCE and the decoder rebuilds: later submits succeed
-        (no permanent zombie serving errors forever)."""
-        from kubeflow_tpu.serving.continuous import SlotDecoder
+    @pytest.mark.parametrize("where", ["round", "prefill"])
+    @pytest.mark.parametrize("mode", DECODER_MODES)
+    def test_step_failure_recovers_instead_of_zombie(self, mode, where,
+                                                     monkeypatch):
+        """A runtime failure in a donated program (the round's, or an
+        admission's prefill) poisons in-flight requests ONCE and the
+        decoder rebuilds its step's state and the allocator: later
+        submits succeed (no permanent zombie serving errors forever),
+        and no page is left claimed. Its speculative dense-prefill case
+        was `test_spec_round_failure_recovers_instead_of_zombie`."""
+        import jax
 
-        model, variables = lm
-        dec = SlotDecoder(model, variables, slots=2, prompt_len=8,
-                          max_new_tokens=3)
+        from kubeflow_tpu.serving import steps
+
+        dec = slot_decoder(mode, slots=2, max_new_tokens=3)
         try:
-            real_step = dec._step
+            want = dec.submit([1, 2, 3])         # while it is healthy
             blew = []
 
-            def exploding_step(params, state):
-                if not blew:
+            def exploding(real):
+                def call(*args, **kw):
+                    if blew:
+                        return real(*args, **kw)
                     blew.append(1)
                     # simulate the donation: the failed call consumed
                     # the input buffers before dying
-                    import jax
-
-                    jax.tree.map(lambda a: a.delete(), state)
+                    jax.tree.map(lambda a: a.delete(), dec.state)
                     raise RuntimeError("RESOURCE_EXHAUSTED (simulated)")
-                return real_step(params, state)
+                return call
 
-            dec._step = exploding_step
+            if where == "prefill" and dec.paged:
+                dec._prefill_at = {n: exploding(program) for n, program
+                                   in dec._prefill_at.items()}
+            elif where == "prefill":
+                name = ("_spec_admit_dense" if mode == "spec-dense"
+                        else "_prefill")
+                setattr(dec.step, name, exploding(getattr(dec.step, name)))
+            elif mode.startswith("spec"):
+                monkeypatch.setattr(steps, "lockstep_verify",
+                                    exploding(steps.lockstep_verify))
+            else:
+                dec._step = exploding(dec._step)
             with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
                 dec.submit([1, 2, 3])
+            assert blew
+            if dec.paged:
+                dec.alloc.check()
+                st = dec.stats()
+                # (the healthy request's prompt page may sit in the prefix
+                # index: a reset forgot that too)
+                assert st["kv_pages_free"] == st["kv_pages_total"]
             # rebuilt: the very next request decodes correctly
-            assert dec.submit([1, 2, 3]) == reference_generate(
-                model, variables, [1, 2, 3], max_new=3)
+            assert dec.submit([1, 2, 3]) == want
         finally:
             dec.close()
 
@@ -387,6 +410,59 @@ class TestFailureContainment:
         with pytest.raises(ValueError, match="max_seq_len"):
             generate(model, variables, jnp.ones((1, 12), jnp.int32),
                      max_new_tokens=8)
+
+
+# What stats() returned in each mode at commit aa28e3e, before the step
+# kinds were classes: the benchmark's sampler and its metrics read these
+# keys, and a key that comes or goes is a metric that turns to null.
+STATS_KEYS = {
+    "admitted", "cache_bytes", "completed", "deadline_canceled",
+    "first_token_s_sum", "first_tokens", "mode", "peak_active",
+    "phase_s.admit", "phase_s.complete", "phase_s.idle", "phase_s.pages",
+    "phase_s.prefill", "phase_s.readback", "phase_s.tick",
+    "prefill_tokens_computed", "prompt_tokens_real",
+    "prompt_tokens_submitted", "queue_wait_s_sum", "rounds", "spec_drafted",
+    "spec_rounds", "spec_tokens_accepted", "spec_tokens_emitted",
+    "speculative"}
+STATS_KEYS_PAGED = {
+    "cow_clones", "kv_page_size", "kv_pages_free", "kv_pages_tabled",
+    "kv_pages_total", "kv_pages_used", "kv_pages_walked", "prefill_shapes",
+    "prefix_hit_pages", "prefix_hit_tokens"}
+STATS_KEYS_BLOCK = {"block_passes", "blocks_committed", "moe_expert_visits",
+                    "moe_load_max", "moe_pairs"}
+
+
+@pytest.mark.parametrize("mode", DECODER_MODES)
+def test_stats_keys_and_the_one_decoder_the_harness_finds(mode):
+    """stats() has the keys it had in every mode, before and after a
+    request; and the benchmark's harness, which looks for the one new
+    live object with `stats()` and `active_slots`, finds the decoder and
+    not its step."""
+    from benchmarks.lib.serve import _decoders
+
+    earlier = {id(o) for o in _decoders()}
+    dec = slot_decoder(mode, slots=2, max_new_tokens=4)
+    try:
+        want = set(STATS_KEYS)
+        if dec.paged:
+            want |= STATS_KEYS_PAGED
+        if mode == "block":
+            want |= STATS_KEYS_BLOCK
+        first = dec.stats()
+        assert set(first) == want
+        assert len(answer_tokens(dec.submit([1, 2, 3]))) == 4
+        st = dec.stats()
+        assert set(st) == want
+        assert st["mode"] == ("paged" if dec.paged else "dense")
+        assert st["speculative"] == mode.startswith("spec")
+        assert (st["spec_rounds"] > 0) == mode.startswith("spec")
+        assert [o for o in _decoders() if id(o) not in earlier] == [dec]
+        # the harness blocks on `state` and frees its leaves
+        import jax
+
+        assert jax.tree.leaves(dec.state) and dec.state is dec.step.state
+    finally:
+        dec.close()
 
 
 class TestPerRequestBudgets:

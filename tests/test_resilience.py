@@ -9,6 +9,7 @@ import json
 import threading
 
 import pytest
+from conftest import answer_tokens, slot_decoder
 
 from kubeflow_tpu.obs import trace as obs_trace
 from kubeflow_tpu.runtime.metrics import MetricsRegistry
@@ -544,20 +545,6 @@ class TestServerOverload:
 # -- the replica-side slot cancel (zero-leak contract) -----------------------
 
 
-@pytest.fixture(scope="module")
-def paged_lm():
-    import jax
-    import numpy as np
-
-    from kubeflow_tpu.models.registry import get_model
-
-    model = get_model("transformer-test", vocab_size=64, max_seq_len=24,
-                      kv_pages=33, kv_page_size=4)
-    variables = model.init(jax.random.PRNGKey(0),
-                           np.zeros((1, 1), np.int32), train=False)
-    return model, variables
-
-
 class _AfterAdmitClock:
     """0.0 until the decoder has admitted a request, then just past the
     500.0 deadline: the round-boundary sweep right after admission sees
@@ -576,35 +563,37 @@ class _AfterAdmitClock:
         return 0.0
 
 
-class TestSlotDecoderDeadline:
-    def test_queue_side_gate_cancels_before_prefill(self, paged_lm):
-        from kubeflow_tpu.serving.continuous import SlotDecoder
+def _pool_is_whole(dec) -> None:
+    """Every page is back (a dense cache has no pool to leak from)."""
+    if dec.paged:
+        st = dec.stats()
+        assert st["kv_pages_free"] == st["kv_pages_total"]
+        dec.alloc.check()
 
-        model, variables = paged_lm
+
+# the loop's sweep and gate are one; what a cancel zeroes is the step's
+@pytest.mark.parametrize(
+    "mode", ["token-paged", "block", "spec-paged", "spec-dense"])
+class TestSlotDecoderDeadline:
+    def test_queue_side_gate_cancels_before_prefill(self, mode):
         clock = ManualClock(50.0)
-        dec = SlotDecoder(model, variables, slots=2, prompt_len=8,
-                          max_new_tokens=4, clock=clock)
+        dec = slot_decoder(mode, slots=2, max_new_tokens=4, clock=clock)
         try:
             with pytest.raises(DeadlineExceeded):
                 dec.submit([1, 2, 3], deadline=49.0)   # already past
             st = dec.stats()
             assert st["deadline_canceled"] == 1
             assert st["admitted"] == 0                 # never cost a slot
-            assert st["kv_pages_free"] == st["kv_pages_total"]
-            dec.alloc.check()
+            _pool_is_whole(dec)
         finally:
             dec.close()
 
-    def test_mid_decode_cancel_frees_slot_and_pages(self, paged_lm):
-        from kubeflow_tpu.serving.continuous import SlotDecoder
-
-        model, variables = paged_lm
+    def test_mid_decode_cancel_frees_slot_and_pages(self, mode):
         clock = _AfterAdmitClock()
         # prefix_cache off: the LRU prefix index retaining prompt pages
         # across frees is reuse, not the leak this test guards against
-        dec = SlotDecoder(model, variables, slots=2, prompt_len=8,
-                          max_new_tokens=12, clock=clock,
-                          prefix_cache=False)
+        dec = slot_decoder(mode, slots=2, max_new_tokens=12, clock=clock,
+                           prefix_cache=False)
         clock.dec = dec
         try:
             with pytest.raises(DeadlineExceeded):
@@ -614,23 +603,18 @@ class TestSlotDecoderDeadline:
             assert st["deadline_canceled"] == 1
             assert st["completed"] == 0
             # the cancel returned every page: zero-leak contract
-            assert st["kv_pages_free"] == st["kv_pages_total"]
-            dec.alloc.check()
+            _pool_is_whole(dec)
             assert dec.active_slots == 0
             # the decoder is still healthy after the cancel
-            assert len(dec.submit([4, 5], max_new=2)) == 2
+            assert len(answer_tokens(dec.submit([4, 5], max_new=2))) == 2
         finally:
             dec.close()
 
-    def test_no_deadline_requests_are_untouched(self, paged_lm):
-        from kubeflow_tpu.serving.continuous import SlotDecoder
-
-        model, variables = paged_lm
+    def test_no_deadline_requests_are_untouched(self, mode):
         clock = ManualClock(1e9)                       # far future always
-        dec = SlotDecoder(model, variables, slots=2, prompt_len=8,
-                          max_new_tokens=4, clock=clock)
+        dec = slot_decoder(mode, slots=2, max_new_tokens=4, clock=clock)
         try:
-            assert len(dec.submit([1, 2, 3])) == 4     # deadline=None
+            assert len(answer_tokens(dec.submit([1, 2, 3]))) == 4
             assert dec.stats()["deadline_canceled"] == 0
         finally:
             dec.close()

@@ -64,7 +64,7 @@ def program_names():
         names = {
             module_name(dec._step, dec._params, dec.state, table),
             module_name(dec._step_fused, dec._params, dec.state, table),
-            module_name(dec._paged_prefill_install, dec._params, dec.state,
+            module_name(dec.step._paged_prefill_install, dec._params, dec.state,
                         jnp.zeros((1, 8), jnp.int32),
                         jnp.zeros((1,), jnp.int32), table[:1],
                         jnp.zeros((1,), jnp.int32), jnp.int32(0),
@@ -107,7 +107,7 @@ def program_names():
             "block:" + module_name(dec._step_fused, dec._params, dec.state,
                                    table),
             "block:" + module_name(
-                dec._paged_prefill_install, dec._params, dec.state,
+                dec.step._paged_prefill_install, dec._params, dec.state,
                 jnp.zeros((1, 8), jnp.int32), jnp.zeros((1,), jnp.int32),
                 table[:1], jnp.zeros((1,), jnp.int32), jnp.int32(0),
                 jnp.int32(1), (jnp.zeros((4,), jnp.int32), jnp.int32(1))),

@@ -1050,7 +1050,7 @@ def run_kv_cancel_drill(seed: int) -> dict:
     through admit / append / mid-flight frees (the deadline-cancel and
     hedge-loser paths) and assert the refcount invariant plus a fully
     recovered freelist. No jax involved — this is the allocator the
-    slot decoder's ``_cancel_slot`` calls ``free()`` on."""
+    slot decoder's ``_cancel_expired`` calls ``free()`` on."""
     from kubeflow_tpu.runtime.kvcache import PageAllocator
 
     rng = random.Random(seed)
