@@ -4,13 +4,17 @@ Three dispatch implementations behind one module:
 
 - **dropless** (sort + grouped matmul, one device): the routed (token,
   expert) pairs are sorted by expert into contiguous groups and each of
-  gate, up and down is ONE grouped matmul over the groups
-  (`jax.lax.ragged_dot`): no capacity, no padding, no one-hot tensors,
-  nothing dropped (`moe_drop` reads 0 by construction), and an expert no
-  pair visits is never read. Padding rows (`live` false) are routed
-  nowhere. What `moe_impl="auto"` takes without a mesh or on a mesh of
-  one device: the path of a served model, many narrow experts and few
-  rows a group.
+  gate, up and down is ONE grouped matmul over the groups: no capacity,
+  no padding, no one-hot tensors, nothing dropped (`moe_drop` reads 0 by
+  construction), and an expert no pair visits is never read. Padding
+  rows (`live` false) are routed nowhere. What `moe_impl="auto"` takes
+  without a mesh or on a mesh of one device: the path of a served model,
+  many narrow experts and few rows a group. The grouped matmul is
+  `jax.lax.ragged_dot`, the reference and the CPU's path, or, where
+  `ops/grouped_matmul.py:use_kernel` says so from backend, mesh, dtype
+  and shape (a TPU, bfloat16, few rows a group), the Pallas kernel of
+  that module, which streams each visited expert's matrix through VMEM
+  once; the sort, the gates and the rounding are the same.
 
 - **dense** (Switch/GShard one-hot einsums): dispatch/combine are einsums
   against one-hot [b,s,e,c] tensors. Correct on any mesh, runs the whole
@@ -44,13 +48,18 @@ capacity; the dropless path leaves padding rows out):
   moe_pairs — routed pairs of live tokens;
   moe_expert_visits — experts that got at least one pair (the groups the
     grouped matmul reads weights for);
-  moe_load_max — the fullest expert's pairs.
+  moe_load_max — the fullest expert's pairs;
+  moe_kernel_pairs — the pairs whose grouped matmuls the Pallas kernel
+    took (all of `moe_pairs` or 0: the choice is made when the program
+    is traced).
 
-In the device trace the dropless path's expert matmuls are the compiler's
-grouped-matmul custom calls, `%ragged-dot-none.N = ... custom-call(`
-(EXPERT_MATMUL_TRACE_NAME below; tests/test_trace_names.py). The router is
-a plain matrix product that XLA fuses: a fusion carries no name of the
-program's in the trace, and its time is read as part of the step's.
+In the device trace the dropless path's expert matmuls are custom calls
+whose names start alike: the compiler's own for `ragged_dot`,
+`%ragged-dot-none.N = ... custom-call(`, and the Pallas kernel's,
+`%ragged-dot-streamed.N = ... custom-call(` (EXPERT_MATMUL_TRACE_NAME
+below, `grouped_matmul.KERNEL_NAME`; tests/test_trace_names.py). The
+router is a plain matrix product that XLA fuses: a fusion carries no name
+of the program's in the trace, and its time is read as part of the step's.
 
 Reference framework has no MoE (SURVEY.md §2.5 "Expert parallelism:
 Absent"); this is TPU-native net-new capability.
@@ -63,6 +72,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from kubeflow_tpu.ops import grouped_matmul
 from kubeflow_tpu.parallel.mesh import (
     AXIS_DCN,
     AXIS_DATA,
@@ -75,9 +85,10 @@ from kubeflow_tpu.parallel.mesh import (
 )
 
 
-# What the device trace calls the dropless path's three grouped matmuls
-# (XLA:TPU's own kernel for `jax.lax.ragged_dot`): the benchmark's
-# `moe.expert_roofline.*` finds them by it.
+# What the device trace's names of the dropless path's three grouped
+# matmuls start with (XLA:TPU's own kernel for `jax.lax.ragged_dot` and
+# `grouped_matmul.KERNEL_NAME`): the benchmark's `moe.expert_roofline.*`
+# finds them by it.
 EXPERT_MATMUL_TRACE_NAME = "ragged-dot"
 
 
@@ -104,12 +115,14 @@ def _expert_mlp(cfg, xin, w_gate, w_up, w_down):
 
 
 def dropless_mlp(cfg, x, gate_vals, gate_idx, w_gate, w_up, w_down,
-                 live=None):
+                 live=None, streamed=False):
     """Sort by expert, one grouped matmul each for gate, up and down over
     the contiguous groups, combine with the gates. x [t, d] flattened
     tokens, gate_* [t, k], weights [e, ...], `live` [t] bool or None
-    (padding rows are routed nowhere and come back as zeros). Returns
-    (y [t, d], counts [e]): the pairs each expert got."""
+    (padding rows are routed nowhere and come back as zeros); `streamed`:
+    the grouped matmuls are the Pallas kernel's and not `ragged_dot`'s
+    (the caller asks `grouped_matmul.use_kernel`). Returns (y [t, d],
+    counts [e]): the pairs each expert got."""
     t, d = x.shape
     k = gate_idx.shape[-1]
     e = w_gate.shape[0]
@@ -121,9 +134,10 @@ def dropless_mlp(cfg, x, gate_vals, gate_idx, w_gate, w_up, w_down,
     counts = jnp.zeros((e + 1,), jnp.int32).at[eidx].add(1)[:e]
     xs = x[order // k].astype(cfg.dtype)
     wg, wu, wd = (w.astype(cfg.dtype) for w in (w_gate, w_up, w_down))
-    h = nn.silu(jax.lax.ragged_dot(xs, wg, counts)) * \
-        jax.lax.ragged_dot(xs, wu, counts)
-    out = jax.lax.ragged_dot(h, wd, counts)          # [t*k, d], sorted
+    matmul = (grouped_matmul.grouped_matmul if streamed
+              else jax.lax.ragged_dot)
+    h = nn.silu(matmul(xs, wg, counts)) * matmul(xs, wu, counts)
+    out = matmul(h, wd, counts)                      # [t*k, d], sorted
     # rows behind the last group are no group's: whatever they hold
     out = jnp.where((eidx[order] < e)[:, None], out, 0)
     # back to (token, slot) order, then the gates' weighted sum
@@ -269,11 +283,15 @@ class MoEBlock(nn.Module):
         mesh = current_mesh()
         dropless = self._dropless_ok(mesh)
         use_sparse = not dropless and self._sparse_ok(mesh)
+        # one rule for gate, up and down: it asks of k and n what holds
+        # for them swapped
+        streamed = dropless and grouped_matmul.use_kernel(
+            b * s * k, d, d_ff, e, cfg.dtype)
         if dropless:
             y, counts = dropless_mlp(
                 cfg, x.reshape(b * s, d), gate_vals.reshape(b * s, k),
                 gate_idx.reshape(b * s, k), w_gate, w_up, w_down,
-                None if live is None else live.reshape(b * s))
+                None if live is None else live.reshape(b * s), streamed)
             y = y.reshape(b, s, d)
             kept = routed = slots = jnp.sum(counts)
         elif use_sparse:
@@ -292,6 +310,8 @@ class MoEBlock(nn.Module):
         self.sow("diagnostics", "moe_expert_visits",
                  jnp.sum((counts > 0).astype(jnp.int32)))
         self.sow("diagnostics", "moe_load_max", jnp.max(counts))
+        self.sow("diagnostics", "moe_kernel_pairs",
+                 jnp.sum(counts) if streamed else jnp.int32(0))
         # Ground truth for which dispatch path actually ran (ADVICE r4):
         # _sparse_ok silently falls back to dense on a meshless trace, so
         # a run labeled 'sparse' could measure dense with nothing in the

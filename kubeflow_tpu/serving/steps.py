@@ -41,10 +41,12 @@ from kubeflow_tpu.runtime.speculative import (
 # first real position to its block's end (what the pass's attention
 # walks: `kv_pages_walked`); and from the mixture layers (ops/moe.py),
 # summed over layers: the routed pairs, the experts that got at least one
-# (each a group whose weights the grouped matmul reads) and the fullest
-# expert's pairs.
+# (each a group whose weights the grouped matmul reads), the fullest
+# expert's pairs, and the pairs whose grouped matmuls were the Pallas
+# kernel's (ops/grouped_matmul.py: all of them or none).
 BLOCK_COUNTERS = ("block_passes", "blocks_committed", "kv_pages_walked",
-                  "moe_pairs", "moe_expert_visits", "moe_load_max")
+                  "moe_pairs", "moe_expert_visits", "moe_load_max",
+                  "moe_kernel_pairs")
 
 
 class TokenStep:

@@ -429,7 +429,7 @@ STATS_KEYS_PAGED = {
     "kv_pages_total", "kv_pages_used", "kv_pages_walked", "prefill_shapes",
     "prefix_hit_pages", "prefix_hit_tokens"}
 STATS_KEYS_BLOCK = {"block_passes", "blocks_committed", "moe_expert_visits",
-                    "moe_load_max", "moe_pairs"}
+                    "moe_kernel_pairs", "moe_load_max", "moe_pairs"}
 
 
 @pytest.mark.parametrize("mode", DECODER_MODES)

@@ -260,6 +260,50 @@ def test_block_kernel_compiles_for_a_v5e_at_the_cells_shape(
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
+@pytest.mark.parametrize("rows, k, n, groups", [
+    (2048, 2048, 768, 128), (2048, 768, 2048, 128),
+    (8192, 2048, 768, 128), (8192, 768, 2048, 128),
+    # the widest experts the rule can give it: the zoo's gpt-moe-8e at a
+    # served batch's few rows (8 MB an expert, two in VMEM)
+    (256, 1024, 4096, 8), (256, 4096, 1024, 8)],
+    ids=["pass-gate-up", "pass-down", "top-rung-gate-up", "top-rung-down",
+         "gpt-moe-8e-gate-up", "gpt-moe-8e-down"])
+def test_grouped_matmul_compiles_for_a_v5e_at_the_cells_shape(
+        v5e_chip, monkeypatch, rows, k, n, groups):
+    """The experts' streamed grouped matmul (ops/grouped_matmul.py; its
+    interpreter's tests are tests/test_grouped_matmul.py, its compile is
+    here because one file loads the TPU's compiler): 128 experts of
+    2,048 x 768, the rows of a pass and of the prefill's longest rung.
+    Mosaic takes two whole experts in VMEM, and the custom call keeps the
+    name, hyphens and all, by which the benchmark's
+    `moe.expert_roofline.blockdiff` finds a pass's grouped matmuls: an
+    array result `bf16[rows, n]`, no tuple."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from kubeflow_tpu.ops import flash_attention
+    from kubeflow_tpu.ops.grouped_matmul import KERNEL_NAME, grouped_matmul
+    from kubeflow_tpu.ops.moe import EXPERT_MATMUL_TRACE_NAME
+
+    monkeypatch.setattr(flash_attention, "INTERPRET", False)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    text = jax.jit(grouped_matmul).lower(
+        arg((rows, k), jnp.bfloat16), arg((groups, k, n), jnp.bfloat16),
+        arg((groups,), jnp.int32)).compile().as_text()
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1 and calls[0].startswith(f"%{KERNEL_NAME}")
+    # the reader's own expression (benchmarks/metrics/blockdiff.py)
+    assert re.search(rf"^%?{EXPERT_MATMUL_TRACE_NAME}[\w.\-]* = \w+\[{rows},",
+                     calls[0]), calls[0]
+    assert f" = bf16[{rows},{n}]" in calls[0]
+
+
 def test_path_rule_follows_backend_and_chunk_length(caplog):
     """The gather path off the TPU and for chunks, the kernel for one
     query a slot on a TPU; which, and why, is logged."""

@@ -88,6 +88,7 @@ def program_names():
     # grouped expert matmul under XLA's own name for `ragged_dot`, the
     # block attention under the kernel's (tests/test_paged_attention.py
     # compiles it for a v5e and finds that name in the module)
+    from kubeflow_tpu.ops.grouped_matmul import KERNEL_NAME as STREAMED_NAME
     from kubeflow_tpu.ops.moe import EXPERT_MATMUL_TRACE_NAME
     from kubeflow_tpu.ops.paged_attention import (BLOCK_KERNEL_NAME,
                                                   KERNEL_NAME)
@@ -117,6 +118,12 @@ def program_names():
     names.add(f"%{EXPERT_MATMUL_TRACE_NAME}-none.1 = bf16[2048,768]"
               "{1,0:T(8,128)(2,1)S(1)} custom-call(%get-tuple-element, "
               '%x.1, %wu.1), custom_call_target="tpu_custom_call"')
+    # and under the streamed kernel's, which starts alike
+    # (tests/test_paged_attention.py compiles it for a v5e and finds it)
+    names.add(f"%{STREAMED_NAME}.2 = bf16[2048,768]{{1,0:T(8,128)(2,1)}} "
+              "custom-call(%get-tuple-element.46, %subtract_clamp_fusion, "
+              "%pad_add_fusion, %dynamic_slice.0, %x.1, %wu.1), "
+              'custom_call_target="tpu_custom_call"')
     for kernel in (KERNEL_NAME, BLOCK_KERNEL_NAME):
         names.add(f"%{kernel}.3 = bf16[64,128,128]{{2,1,0:T(8,128)(2,1)}} "
                   "custom-call(%table, %start, %last, %q, %k, %v), "
@@ -163,6 +170,7 @@ def test_the_prefill_keeps_its_module_name_at_every_rung(more):
 def test_the_kernels_names_are_what_their_definitions_say():
     """The names said at the kernels' definitions (ops/moe.py,
     ops/paged_attention.py) are the ones the metric files look for."""
+    from kubeflow_tpu.ops.grouped_matmul import KERNEL_NAME as STREAMED_NAME
     from kubeflow_tpu.ops.moe import EXPERT_MATMUL_TRACE_NAME
     from kubeflow_tpu.ops.paged_attention import (BLOCK_KERNEL_NAME,
                                                   KERNEL_NAME)
@@ -172,6 +180,27 @@ def test_the_kernels_names_are_what_their_definitions_say():
     assert BLOCK_KERNEL_NAME in looked_for
     assert (KERNEL_NAME, BLOCK_KERNEL_NAME) == (
         "paged_decode_attention", "paged_block_attention")
+    assert STREAMED_NAME == "ragged-dot-streamed"
+
+
+def test_both_grouped_matmuls_are_found_by_the_experts_roofline(
+        program_names):
+    """`moe.expert_roofline.*` builds its expression from the file's
+    `ops` and the pass's row count (benchmarks/metrics/blockdiff.py): it
+    must find XLA's kernel and the streamed one alike, whichever the rule
+    (ops/grouped_matmul.py:use_kernel) gave a pass, or it would divide
+    all of the needed bytes by a part of the time."""
+    from kubeflow_tpu.ops.grouped_matmul import KERNEL_NAME as STREAMED_NAME
+    from kubeflow_tpu.ops.moe import EXPERT_MATMUL_TRACE_NAME
+
+    with open(os.path.join(ROOT, "benchmarks", "metrics",
+                           "moe.expert_roofline.blockdiff.json")) as f:
+        ops = json.load(f)["args"]["ops"]
+    assert ops == EXPERT_MATMUL_TRACE_NAME
+    assert STREAMED_NAME.startswith(ops)
+    rx = re.compile(rf"^%?{ops}[\w.\-]* = \w+\[2048,")
+    found = sorted(n.split(" = ")[0] for n in program_names if rx.search(n))
+    assert found == [f"%{ops}-none.1", f"%{STREAMED_NAME}.2"]
 
 
 @pytest.mark.parametrize("pattern", patterns())
