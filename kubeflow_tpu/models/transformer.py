@@ -22,6 +22,7 @@ modern decoder (Llama-class), in bf16 with f32 logits.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -55,6 +56,16 @@ def shard(x: jax.Array, spec: P) -> jax.Array:
 
 def _part(init, names):
     return nn.with_partitioning(init, names)
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """What one layer of the stack is, where the layers differ
+    (`TransformerConfig.layer_pattern`)."""
+
+    window: int = 0      # sliding-window attention; 0 = full causal
+    rope: bool = True    # rotary embedding on q and k; False = none at all
+    moe: bool = False    # a mixture layer (ops/moe.py) or a dense SwiGLU
 
 
 @dataclasses.dataclass(frozen=True)
@@ -144,6 +155,51 @@ class TransformerConfig:
     # over the `pipe` mesh axis (0/1 = no pipelining).
     pipeline_stages: int = 0
     pp_microbatches: int = 4
+    # Every RMSNorm's epsilon.
+    norm_eps: float = 1e-6
+    # A stack whose layers differ: one LayerSpec a layer (`_build` takes
+    # dicts of its fields too). () = the uniform stack `attention_window`
+    # and `moe_every` spell, which is what `layers()` then returns.
+    layer_pattern: tuple = ()
+    # An output gate on attention: o * sigmoid(x W_gate), head by head,
+    # before the output projection.
+    attn_gate: bool = False
+    # RMSNorm on each branch before it joins the residual stream
+    # (`ln_attn_out`, `ln_mlp_out`) beside the two on the way in.
+    sandwich_norm: bool = False
+    # The embedding's rows times this (muP: sqrt(d_model)).
+    embed_scale: float = 1.0
+    # A mixture layer that holds a share of the experts (ops/moe.py):
+    # the router is `n_experts_total` wide (0 = n_experts: every expert
+    # is held) and the `n_experts` held are ids `expert_first` and on.
+    n_experts_total: int = 0
+    expert_first: int = 0
+    # "softmax": softmax, the k largest, renormalised. "sigmoid": sigmoid
+    # scores, the k largest of score + `expert_bias` (a buffer: selection
+    # only), the chosen scores renormalised and times `moe_route_scale`.
+    moe_score: str = "softmax"
+    moe_route_scale: float = 1.0
+    # Shared experts: one SwiGLU of this many experts' width on every
+    # token, added to the routed sum.
+    moe_shared_experts: int = 0
+    # Pages kept by layer kind (runtime/kvcache.py): > 0 gives the layers
+    # with a window a pool of their own of this many pages and the second
+    # of two page tables, whose pages behind the window the allocator
+    # takes back while the request runs. 0: one pool size, one table.
+    kv_window_pages: int = 0
+
+    def layers(self) -> tuple:
+        """One LayerSpec a layer: the pattern, or the uniform stack."""
+        if self.layer_pattern:
+            if len(self.layer_pattern) != self.n_layers:
+                raise ValueError(
+                    f"layer_pattern has {len(self.layer_pattern)} entries "
+                    f"for n_layers={self.n_layers}")
+            return self.layer_pattern
+        return tuple(
+            LayerSpec(window=self.attention_window,
+                      moe=self.moe_every > 0 and (i + 1) % self.moe_every == 0)
+            for i in range(self.n_layers))
 
 
 def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
@@ -256,13 +312,24 @@ def _remat_policy(cfg: "TransformerConfig"):
 
 class Attention(nn.Module):
     cfg: TransformerConfig
+    layer: Optional[LayerSpec] = None    # None: the uniform stack's
+
+    @property
+    def window(self) -> int:
+        return (self.cfg.attention_window if self.layer is None
+                else self.layer.window)
 
     def _decode_paged(self, q, k, v, decode_index, pad_len, page_table,
-                      block_step=False):
+                      block_step=False, fresh=False):
         """Paged decode: the cache is a pool of [kv_pages, kv_page_size]
         position pages shared across slots; `page_table` [B, MP] maps
         each slot's logical page j (positions j*PS..(j+1)*PS-1) to a
-        physical pool page. Writes scatter the chunk's K/V to
+        physical pool page. Where pages are kept by layer kind
+        (cfg.kv_window_pages), a layer with a window has a pool of that
+        many pages and is handed the window kind's table, in whose rows
+        the pages behind the slot's window are TRASH_PAGE again: no
+        query of the slot sees a position there any more, the kernel
+        never walks them and the gather masks them. Writes scatter the chunk's K/V to
         (table[pos//PS], pos%PS) BEFORE attending (the full-cache
         write-then-attend discipline, so speculative verify chunks
         self-heal identically). Reads take one of two paths, chosen by
@@ -295,9 +362,12 @@ class Attention(nn.Module):
         steering their stale writes into the trash page instead of a
         page another slot now owns."""
         cfg = self.cfg
+        window = self.window
         b, lq = q.shape[0], q.shape[1]
         hkv, hd = cfg.n_kv_heads, cfg.head_dim
         NP, PS = cfg.kv_pages, cfg.kv_page_size
+        if window and cfg.kv_window_pages:
+            NP = cfg.kv_window_pages
         MP = page_table.shape[1]
         ck = self.variable("cache", "key_pages",
                            lambda: jnp.zeros((NP, PS, hkv, hd), cfg.dtype))
@@ -309,6 +379,30 @@ class Attention(nn.Module):
         pos_q = idx[:, None] + jnp.arange(lq, dtype=jnp.int32)[None, :]
         k_w = k.astype(cfg.dtype)
         v_w = v.astype(cfg.dtype)
+        if fresh:
+            # The chunk is a whole prompt and nothing real lies before
+            # it (the caller's word: a decoder without a prefix cache,
+            # one token a step): its attention is over its own q, k, v,
+            # the left padding a segment of its own, and no score exists
+            # beyond the kernel's tile. Only later ticks read the pages,
+            # and those of a window layer see its last `window`
+            # positions: the rest is not written.
+            from kubeflow_tpu.ops.attention import attention
+
+            keep = min(lq, window + PS) if window and cfg.kv_window_pages \
+                else lq
+            flat = pos_q[:, lq - keep:].reshape(-1)
+            rows = jnp.repeat(jnp.arange(b, dtype=jnp.int32), keep)
+            pages, offs = page_table[rows, flat // PS], flat % PS
+            for pool, new in ((ck, k_w), (cv, v_w)):
+                pool.value = pool.value.at[pages, offs].set(
+                    new[:, lq - keep:].reshape(b * keep, hkv, hd))
+            real = None if pad_len is None else (
+                pos_q >= pad_len[:, None]).astype(jnp.int32)
+            return attention(
+                q, k_w, v_w, causal=True, impl=cfg.attention_impl,
+                segment_ids=real, block_q=cfg.flash_block_q,
+                block_k=cfg.flash_block_k, window=window)
         # ---- write the chunk, THEN attend ----
         flat = pos_q.reshape(-1)                       # [b*lq] positions
         rows = jnp.repeat(jnp.arange(b, dtype=jnp.int32), lq)
@@ -328,8 +422,8 @@ class Attention(nn.Module):
             # streams the pages that hold it out of the pool
             last = pos_q[:, -1]
             start = jnp.zeros_like(last)
-            if cfg.attention_window:
-                start = jnp.maximum(start, last - cfg.attention_window + 1)
+            if window:
+                start = jnp.maximum(start, last - window + 1)
             if pad_len is not None:
                 start = jnp.maximum(start, pad_len)
             return paged_decode_attention(
@@ -354,8 +448,8 @@ class Attention(nn.Module):
             mask = pos <= first + ((qpos - first) // B + 1) * B - 1
         else:
             mask = pos <= qpos
-        if cfg.attention_window:
-            mask = mask & (pos > qpos - cfg.attention_window)
+        if window:
+            mask = mask & (pos > qpos - window)
         if pad_len is not None:
             mask = mask & (pos >= pad_len[:, None, None, None, None])
         logits = jnp.where(mask, logits, -1e30)
@@ -379,7 +473,7 @@ class Attention(nn.Module):
         cfg = self.cfg
         b, lq = q.shape[0], q.shape[1]
         hkv, hd = cfg.n_kv_heads, cfg.head_dim
-        W = min(cfg.attention_window, cfg.max_seq_len)
+        W = min(self.window, cfg.max_seq_len)
         quant = cfg.kv_cache_dtype == "int8"
         cache_dt = jnp.int8 if quant else cfg.dtype
         ck = self.variable("cache", "cached_key",
@@ -516,8 +610,10 @@ class Attention(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, segment_ids=None, decode_index=None,
-                 pad_len=None, page_table=None, block_step=False):
+                 pad_len=None, page_table=None, block_step=False,
+                 fresh=False):
         cfg = self.cfg
+        window = self.window
         init = nn.initializers.normal(0.02)
         dense = lambda feats, names, name: nn.DenseGeneral(  # noqa: E731
             feats,
@@ -531,11 +627,15 @@ class Attention(nn.Module):
         q = dense((cfg.n_heads, cfg.head_dim), (AXIS_FSDP, AXIS_MODEL, None), "q")(x)
         k = dense((cfg.n_kv_heads, cfg.head_dim), (AXIS_FSDP, AXIS_MODEL, None), "k")(x)
         v = dense((cfg.n_kv_heads, cfg.head_dim), (AXIS_FSDP, AXIS_MODEL, None), "v")(x)
+        if cfg.attn_gate:
+            z = dense((cfg.n_heads, cfg.head_dim),
+                      (AXIS_FSDP, AXIS_MODEL, None), "gate")(x)
         if cfg.qk_norm:
-            q = RMSNorm(dtype=cfg.dtype, name="q_norm")(q)
-            k = RMSNorm(dtype=cfg.dtype, name="k_norm")(k)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+            q = RMSNorm(cfg.norm_eps, cfg.dtype, name="q_norm")(q)
+            k = RMSNorm(cfg.norm_eps, cfg.dtype, name="k_norm")(k)
+        if self.layer is None or self.layer.rope:
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
         # remat anchors for the "slim" whitelist policy: saving post-rope
         # q/k/v lets the flash backward run without recomputing the
         # projections (its own fwd replay still happens — lse is a
@@ -560,9 +660,9 @@ class Attention(nn.Module):
             # falls through to the SHARED output projection below, like
             # the rolling path — 'o' must stay single-sited
             out = self._decode_paged(q, k, v, decode_index, pad_len,
-                                     page_table, block_step)
+                                     page_table, block_step, fresh)
         elif decode_index is not None and cfg.rolling_kv_cache:
-            if not cfg.attention_window:
+            if not window:
                 raise ValueError(
                     "rolling_kv_cache requires attention_window > 0")
             if cfg.kv_cache_dtype not in ("auto", "int8"):
@@ -704,11 +804,11 @@ class Attention(nn.Module):
                 qpos = (idx[:, None] + jnp.arange(lq, dtype=jnp.int32)
                         [None, :])[:, None, None, :, None]
             mask = pos <= qpos
-            if cfg.attention_window:
+            if window:
                 # same sliding window as training (train/serve parity);
                 # this path keeps max_seq cache slots — set
                 # rolling_kv_cache for the O(window) bounded cache
-                mask = mask & (pos > qpos - cfg.attention_window)
+                mask = mask & (pos > qpos - window)
             if pad_len is not None:
                 # left-padded ragged prompts: positions before each row's
                 # real start are pad garbage and must not be attended to
@@ -725,8 +825,7 @@ class Attention(nn.Module):
             from kubeflow_tpu.ops.ring_attention import ring_attention
 
             out = ring_attention(q, k, v, axis_name=AXIS_SEQ,
-                                 segment_ids=segment_ids,
-                                 window=cfg.attention_window)
+                                 segment_ids=segment_ids, window=window)
         elif cfg.attention_impl == "ulysses":
             from kubeflow_tpu.ops.ulysses import ulysses_attention
 
@@ -734,7 +833,7 @@ class Attention(nn.Module):
                                     segment_ids=segment_ids,
                                     block_q=cfg.flash_block_q,
                                     block_k=cfg.flash_block_k,
-                                    window=cfg.attention_window)
+                                    window=window)
         else:
             from kubeflow_tpu.ops.attention import attention
 
@@ -742,8 +841,11 @@ class Attention(nn.Module):
                 q, k, v, causal=True, impl=cfg.attention_impl,
                 segment_ids=segment_ids,
                 block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
-                window=cfg.attention_window,
+                window=window,
             )
+        if cfg.attn_gate:
+            out = (out.astype(jnp.float32)
+                   * jax.nn.sigmoid(z.astype(jnp.float32))).astype(cfg.dtype)
         out = checkpoint_name(out, "attn_ctx")
         # Row-parallel output projection: contraction dim sharded over
         # `model` — GSPMD inserts the all-reduce here.
@@ -819,26 +921,31 @@ class LMHead(nn.Module):
 
 
 class Block(nn.Module):
+    """One layer, built from its description (`cfg.layers()`); None: a
+    dense layer with the model-wide window (a pipeline stage's)."""
+
     cfg: TransformerConfig
-    use_moe: bool = False
+    layer: Optional[LayerSpec] = None
 
     @nn.compact
     def __call__(self, x, positions, segment_ids=None, decode_index=None,
                  pad_len=None, page_table=None, block_step=False,
-                 live=None):
+                 live=None, fresh=False):
         cfg = self.cfg
+        norm = functools.partial(RMSNorm, cfg.norm_eps, cfg.dtype)
         # "block_norm" anchors both norm outputs: they are the weight-grad
         # inputs of the q/k/v and gate/up matmuls, so saving these d-wide
         # bf16 tensors (instead of the f32 RMSNorm internals a blacklist
         # policy keeps) is what lets the "slim" replay skip the norms.
-        ln1 = checkpoint_name(
-            RMSNorm(dtype=cfg.dtype, name="ln_attn")(x), "block_norm")
-        x = x + Attention(cfg, name="attn")(
+        ln1 = checkpoint_name(norm(name="ln_attn")(x), "block_norm")
+        attn_out = Attention(cfg, self.layer, name="attn")(
             ln1, positions, segment_ids, decode_index, pad_len, page_table,
-            block_step)
-        ln2 = checkpoint_name(
-            RMSNorm(dtype=cfg.dtype, name="ln_mlp")(x), "block_norm")
-        if self.use_moe:
+            block_step, fresh)
+        if cfg.sandwich_norm:
+            attn_out = norm(name="ln_attn_out")(attn_out)
+        x = x + attn_out
+        ln2 = checkpoint_name(norm(name="ln_mlp")(x), "block_norm")
+        if self.layer is not None and self.layer.moe:
             from kubeflow_tpu.ops.moe import MoEBlock
 
             mlp_out = MoEBlock(
@@ -846,6 +953,8 @@ class Block(nn.Module):
                 name="moe")(ln2, live)
         else:
             mlp_out = SwiGLU(cfg, name="mlp")(ln2)
+        if cfg.sandwich_norm:
+            mlp_out = norm(name="ln_mlp_out")(mlp_out)
         return x + mlp_out
 
 
@@ -880,9 +989,17 @@ class TransformerLM(nn.Module):
     @nn.compact
     def __call__(self, tokens, train: bool = True, segment_ids=None,
                  decode_index=None, pad_len=None, page_table=None,
-                 return_hidden=False, block_step=False):
+                 return_hidden=False, block_step=False, fresh=False):
+        """`page_table`: [B, MP], or where pages are kept by layer kind
+        (cfg.kv_window_pages) the pair (held kind's, window kind's), of
+        which each layer reads its own. `fresh`: the paged chunk is a
+        whole prompt with nothing real before it; its attention is over
+        its own q, k, v (`Attention._decode_paged`), and the logits are
+        the last position's alone, [B, 1, V]."""
         cfg = self.cfg
         del train  # no dropout in the speed-run configuration
+        specs = cfg.layers()
+        norm_f = RMSNorm(cfg.norm_eps, cfg.dtype, name="ln_f")
         emb = self.param(
             "embedding",
             # vocab over (model, fsdp), d unsharded: the gradient of a
@@ -897,6 +1014,8 @@ class TransformerLM(nn.Module):
             jnp.float32,
         )
         x = jnp.asarray(emb, cfg.dtype)[tokens]
+        if cfg.embed_scale != 1.0:
+            x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
         x = shard(x, HIDDEN_SPEC)
         if decode_index is not None:
             # KV-cache decode step: tokens [B, Lq] starting at absolute
@@ -916,15 +1035,19 @@ class TransformerLM(nn.Module):
             # left padding, and an idle slot's whole chunk (the decoder
             # gives it padding that begins past its position), are no
             # tokens: a mixture layer routes none of them
-            live = (None if pad_len is None or not cfg.moe_every
+            live = (None if pad_len is None
+                    or not any(s.moe for s in specs)
                     else positions >= pad_len[:, None])
-            for i in range(cfg.n_layers):
-                use_moe = cfg.moe_every > 0 and (i + 1) % cfg.moe_every == 0
-                x = Block(cfg, use_moe=use_moe, name=f"layer_{i}")(
-                    x, positions, None, decode_index, pad_len, page_table,
-                    block_step, live)
-            x = RMSNorm(dtype=cfg.dtype, name="ln_f")(x)
-            return LMHead(cfg, name="lm_head")(x)
+            tables = (page_table if isinstance(page_table, tuple)
+                      else (page_table, page_table))
+            for i, spec in enumerate(specs):
+                of_window = bool(spec.window and cfg.kv_window_pages)
+                x = Block(cfg, spec, name=f"layer_{i}")(
+                    x, positions, None, decode_index, pad_len,
+                    tables[of_window], block_step, live, fresh)
+            if fresh:
+                x = x[:, -1:]
+            return LMHead(cfg, name="lm_head")(norm_f(x))
         positions = jnp.broadcast_to(
             jnp.arange(tokens.shape[1], dtype=jnp.int32), tokens.shape
         )
@@ -934,7 +1057,8 @@ class TransformerLM(nn.Module):
                     f"n_layers={cfg.n_layers} not divisible by "
                     f"pipeline_stages={cfg.pipeline_stages}"
                 )
-            if (cfg.moe_every or cfg.attention_impl in ("ring", "ulysses")
+            if (cfg.moe_every or cfg.layer_pattern
+                    or cfg.attention_impl in ("ring", "ulysses")
                     or segment_ids is not None):
                 raise ValueError("pipeline stages support dense blocks with "
                                  "local attention only (no moe/ring/ulysses/"
@@ -958,13 +1082,13 @@ class TransformerLM(nn.Module):
                         f"remat_policy {cfg.remat_policy!r}: layer count "
                         f"must be in 1..{cfg.n_layers}")
                 rblock = nn.remat(Block, policy=_remat_policy(cfg))
-            for i in range(cfg.n_layers):
-                use_moe = cfg.moe_every > 0 and (i + 1) % cfg.moe_every == 0
+            for i, spec in enumerate(specs):
                 # mixed policy: first k_mix blocks remat, the rest save
                 # everything (remat never changes values, only residuals)
                 blk = rblock if (k_mix is None or i < k_mix) else Block
-                x = blk(cfg, use_moe=use_moe, name=f"layer_{i}")(x, positions, segment_ids)
-        x = RMSNorm(dtype=cfg.dtype, name="ln_f")(x)
+                x = blk(cfg, spec, name=f"layer_{i}")(
+                    x, positions, segment_ids)
+        x = norm_f(x)
         if return_hidden:
             # Chunked-loss path (ops.xent.chunked_lm_xent): the caller
             # projects through lm_head/kernel chunk-by-chunk so the
@@ -986,23 +1110,29 @@ class TransformerLM(nn.Module):
         cfg = self.cfg
         attn = cfg.d_model * cfg.head_dim * (cfg.n_heads * 2 + cfg.n_kv_heads * 2)
         mlp = 3 * cfg.d_model * cfg.d_ff          # SwiGLU: gate+up+down
-        n_moe = (cfg.n_layers // cfg.moe_every) if cfg.moe_every else 0
+        specs = cfg.layers()
+        n_moe = sum(s.moe for s in specs)
         n_dense = cfg.n_layers - n_moe
+        if cfg.attn_gate:
+            attn += cfg.d_model * cfg.head_dim * cfg.n_heads
         # MoE layer: top_k expert MLPs (of the experts' own width) execute
-        # per token, plus the router
+        # per token (a layer that holds a share computes its share of
+        # them), plus the router and the shared experts
         expert = 3 * cfg.d_model * (cfg.moe_d_ff or cfg.d_ff)
-        moe = cfg.expert_top_k * expert + cfg.d_model * cfg.n_experts
+        total = cfg.n_experts_total or cfg.n_experts
+        moe = (cfg.expert_top_k * expert * cfg.n_experts / total
+               + cfg.d_model * total + cfg.moe_shared_experts * expert)
         head = cfg.vocab_size * cfg.d_model
         flops = 6.0 * (cfg.n_layers * attn + n_dense * mlp + n_moe * moe
                        + head)
         if seq_len:
-            w = cfg.attention_window
-            if w and seq_len > w:   # the first w queries see 1..w keys
-                seen = w * (w + 1) / 2 + (seq_len - w) * w
-            else:
-                seen = seq_len * (seq_len + 1) / 2
-            flops += (12.0 * cfg.n_layers * cfg.n_heads * cfg.head_dim
-                      * seen / seq_len)
+            seen = 0.0
+            for w in (s.window for s in specs):
+                if w and seq_len > w:   # the first w queries see 1..w keys
+                    seen += w * (w + 1) / 2 + (seq_len - w) * w
+                else:
+                    seen += seq_len * (seq_len + 1) / 2
+            flops += 12.0 * cfg.n_heads * cfg.head_dim * seen / seq_len
         return flops
 
 
@@ -1014,6 +1144,11 @@ def _build(name: str, **overrides):
             cfg_kw[k] = overrides.pop(k)
     if overrides:
         raise ValueError(f"unknown transformer kwargs {sorted(overrides)}")
+    if cfg_kw.get("layer_pattern"):
+        # a configuration's file spells a layer as a dict of its fields
+        cfg_kw["layer_pattern"] = tuple(
+            LayerSpec(**s) if isinstance(s, dict) else s
+            for s in cfg_kw["layer_pattern"])
     cfg = TransformerConfig(**cfg_kw)
     # "auto" is settled here, once per model and in the log, not layer
     # by layer at trace time

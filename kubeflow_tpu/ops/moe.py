@@ -36,6 +36,25 @@ Three dispatch implementations behind one module:
   expert) only (fsdp/model/seq all 1 — the canonical EP regime);
   anything else falls back to dense.
 
+**A layer that holds a share** (cfg.n_experts_total > cfg.n_experts, the
+dropless path only): the router is as wide as the model's experts are
+many, and this layer holds the `n_experts` of them from id
+`cfg.expert_first` on, as one chip of an expert-parallel group does. It
+routes over all of them, scores, choice and gate weights alike, and
+computes the part of the sum that its own experts give: a pair whose
+expert lies elsewhere sorts behind every group, as a padding row's does,
+and comes back as zeros. Nothing stands in for the absent chips: no
+exchange, no stand-in weights. `moe_pairs`, `moe_expert_visits`,
+`moe_load_max` and `moe_kernel_pairs` then count the held pairs, and
+`moe_pairs_routed` every pair the live tokens were routed (tokens x k).
+
+Two scoring rules (cfg.moe_score): "softmax" (softmax over the experts,
+the k largest, renormalised) and "sigmoid" (sigmoid scores in float32,
+the k largest of score + `expert_bias`, a buffer that moves the choice
+only, then the chosen scores over their sum and times
+cfg.moe_route_scale). cfg.moe_shared_experts adds a plain SwiGLU of
+that many experts' width on every token (`shared`) to the routed sum.
+
 Tokens are BATCH-sharded over the `expert` axis outside this block
 (parallel/mesh.py BATCH_AXES): the expert axis would otherwise duplicate
 every dense layer's compute ep-fold.
@@ -51,7 +70,9 @@ capacity; the dropless path leaves padding rows out):
   moe_load_max — the fullest expert's pairs;
   moe_kernel_pairs — the pairs whose grouped matmuls the Pallas kernel
     took (all of `moe_pairs` or 0: the choice is made when the program
-    is traced).
+    is traced);
+  moe_pairs_routed — the pairs of live tokens whether their expert is
+    held here or not (`moe_pairs` where every expert is held).
 
 In the device trace the dropless path's expert matmuls are custom calls
 whose names start alike: the compiler's own for `ragged_dot`,
@@ -92,18 +113,31 @@ from kubeflow_tpu.parallel.mesh import (
 EXPERT_MATMUL_TRACE_NAME = "ragged-dot"
 
 
-def _router(cfg, x, init):
-    """Top-k routing (f32 softmax). Returns (probs [b,s,e],
-    gate_vals [b,s,k] renormalized, gate_idx [b,s,k])."""
+def _router(cfg, x, init, bias=None):
+    """Top-k routing in float32 over every expert of the model, held
+    here or not. Returns (probs [b,s,e], gate_vals [b,s,k], gate_idx
+    [b,s,k]). Softmax scores: the k largest, renormalised. Sigmoid
+    scores (`bias` [e] given): the k largest of score + bias, the
+    chosen scores (not the biased ones) over their sum, times
+    cfg.moe_route_scale."""
     router = nn.DenseGeneral(
-        cfg.n_experts, use_bias=False, dtype=jnp.float32,
+        cfg.n_experts_total or cfg.n_experts, use_bias=False,
+        dtype=jnp.float32,
         kernel_init=nn.with_partitioning(init, (AXIS_FSDP, None)),
         name="router",
     )
-    probs = jax.nn.softmax(router(x.astype(jnp.float32)), axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, cfg.expert_top_k)
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
+    logits = router(x.astype(jnp.float32))
+    if bias is None:
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, gate_idx = jax.lax.top_k(probs, cfg.expert_top_k)
+        gate_vals = gate_vals / jnp.maximum(
+            gate_vals.sum(-1, keepdims=True), 1e-9)
+        return probs, gate_vals, gate_idx
+    probs = jax.nn.sigmoid(logits)
+    _, gate_idx = jax.lax.top_k(probs + bias, cfg.expert_top_k)
+    gate_vals = jnp.take_along_axis(probs, gate_idx, axis=-1)
+    gate_vals = gate_vals / (gate_vals.sum(-1, keepdims=True) + 1e-20) \
+        * cfg.moe_route_scale
     return probs, gate_vals, gate_idx
 
 
@@ -115,18 +149,25 @@ def _expert_mlp(cfg, xin, w_gate, w_up, w_down):
 
 
 def dropless_mlp(cfg, x, gate_vals, gate_idx, w_gate, w_up, w_down,
-                 live=None, streamed=False):
+                 live=None, streamed=False, first=None):
     """Sort by expert, one grouped matmul each for gate, up and down over
     the contiguous groups, combine with the gates. x [t, d] flattened
     tokens, gate_* [t, k], weights [e, ...], `live` [t] bool or None
     (padding rows are routed nowhere and come back as zeros); `streamed`:
     the grouped matmuls are the Pallas kernel's and not `ragged_dot`'s
-    (the caller asks `grouped_matmul.use_kernel`). Returns (y [t, d],
-    counts [e]): the pairs each expert got."""
+    (the caller asks `grouped_matmul.use_kernel`); `first`: the weights
+    are the experts `first .. first + e - 1` of those `gate_idx` counts
+    over (None: all of them), and a pair whose expert is not among them
+    comes back as zeros like a dead row's. Returns (y [t, d], counts
+    [e]): the pairs each held expert got."""
     t, d = x.shape
     k = gate_idx.shape[-1]
     e = w_gate.shape[0]
     eidx = gate_idx.reshape(-1)                      # [t*k]
+    if first is not None:
+        # an absent expert's pair sorts behind every group, as a dead one
+        eidx = eidx - first
+        eidx = jnp.where((eidx >= 0) & (eidx < e), eidx, e)
     if live is not None:
         # a dead pair sorts behind every group and belongs to none
         eidx = jnp.where(jnp.repeat(live, k), eidx, e)
@@ -265,10 +306,17 @@ class MoEBlock(nn.Module):
         cfg = self.cfg
         b, s, d = x.shape
         e, k = cfg.n_experts, cfg.expert_top_k
+        e_all = cfg.n_experts_total or e
         d_ff = cfg.moe_d_ff or cfg.d_ff
         init = nn.initializers.normal(0.02)
 
-        probs, gate_vals, gate_idx = _router(cfg, x, init)
+        if cfg.moe_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown moe_score {cfg.moe_score!r} "
+                             "(softmax|sigmoid)")
+        bias = (self.param("expert_bias", nn.initializers.zeros, (e_all,),
+                           jnp.float32)
+                if cfg.moe_score == "sigmoid" else None)
+        probs, gate_vals, gate_idx = _router(cfg, x, init, bias)
 
         w_gate = self.param(
             "w_gate", nn.with_partitioning(init, (AXIS_EXPERT, AXIS_FSDP, AXIS_MODEL)),
@@ -282,6 +330,12 @@ class MoEBlock(nn.Module):
 
         mesh = current_mesh()
         dropless = self._dropless_ok(mesh)
+        share = e_all != e
+        if share and not dropless:
+            raise ValueError(
+                f"a layer that holds {e} of {e_all} experts runs the "
+                "dropless path only (moe_impl 'auto', one device): the "
+                "capacity paths hold every expert")
         use_sparse = not dropless and self._sparse_ok(mesh)
         # one rule for gate, up and down: it asks of k and n what holds
         # for them swapped
@@ -291,7 +345,8 @@ class MoEBlock(nn.Module):
             y, counts = dropless_mlp(
                 cfg, x.reshape(b * s, d), gate_vals.reshape(b * s, k),
                 gate_idx.reshape(b * s, k), w_gate, w_up, w_down,
-                None if live is None else live.reshape(b * s), streamed)
+                None if live is None else live.reshape(b * s), streamed,
+                cfg.expert_first if share else None)
             y = y.reshape(b, s, d)
             kept = routed = slots = jnp.sum(counts)
         elif use_sparse:
@@ -306,7 +361,12 @@ class MoEBlock(nn.Module):
             # its mesh (init) and one traced under it sow one structure
             counts = jnp.zeros((e,), jnp.int32).at[
                 gate_idx.reshape(-1)].add(1)
-        self.sow("diagnostics", "moe_pairs", jnp.sum(counts))
+        pairs = jnp.sum(counts)
+        self.sow("diagnostics", "moe_pairs", pairs)
+        self.sow("diagnostics", "moe_pairs_routed",
+                 pairs if not share else k * (
+                     jnp.int32(b * s) if live is None
+                     else jnp.sum(live.astype(jnp.int32))))
         self.sow("diagnostics", "moe_expert_visits",
                  jnp.sum((counts > 0).astype(jnp.int32)))
         self.sow("diagnostics", "moe_load_max", jnp.max(counts))
@@ -326,9 +386,9 @@ class MoEBlock(nn.Module):
         # gate_idx). NOTE round 3's dense path used the post-capacity
         # fraction; the conventions differ only when experts overflow.
         me = probs.mean(axis=(0, 1))                   # [e]
-        assign_pre = jax.nn.one_hot(gate_idx, e, dtype=jnp.float32)
+        assign_pre = jax.nn.one_hot(gate_idx, e_all, dtype=jnp.float32)
         ce = assign_pre.sum(axis=2).mean(axis=(0, 1))
-        aux = e * jnp.sum(me * ce)
+        aux = e_all * jnp.sum(me * ce)
         self.sow("losses", "moe_aux", aux)
         # dispatch diagnostics: how much of the capacity
         # buffer is padding, and how much routing overflowed. `slots` is
@@ -342,7 +402,20 @@ class MoEBlock(nn.Module):
         self.sow("diagnostics", "moe_drop",
                  1.0 - kept.astype(jnp.float32)
                  / jnp.maximum(routed.astype(jnp.float32), 1.0))
+        if cfg.moe_shared_experts:
+            y = y + self._shared(x, d_ff * cfg.moe_shared_experts, init)
         return y.astype(cfg.dtype)
+
+    def _shared(self, x, width: int, init):
+        """The shared experts: one SwiGLU of their summed width on every
+        token (padding rows too: plain matrices, nothing to route)."""
+        cfg = self.cfg
+        dense = lambda feats, names, name: nn.DenseGeneral(  # noqa: E731
+            feats, use_bias=False, dtype=cfg.dtype,
+            kernel_init=nn.with_partitioning(init, names), name=name)
+        h = nn.silu(dense(width, (AXIS_FSDP, AXIS_MODEL), "shared_gate")(x)) \
+            * dense(width, (AXIS_FSDP, AXIS_MODEL), "shared_up")(x)
+        return dense(x.shape[-1], (AXIS_MODEL, AXIS_FSDP), "shared_down")(h)
 
     # ---- dense (oracle) path --------------------------------------------
 

@@ -51,6 +51,29 @@ and at no other, with or without hits; a hit that would leave a length
 between two rungs is cut back to the rung, and the pages behind the cut
 are computed again into private pages.
 
+Pages kept by layer kind. A model whose layers differ keeps two kinds of
+page in this one allocator (``window`` and ``window_pages`` given): the
+HELD kind is everything above, a page held for the slot's whole life,
+and is the kind of the layers that attend to the whole context; the
+WINDOW kind is the kind of the layers with a sliding window. Each kind
+has its pool (on the device: its own ``[pages, PS, Hkv, D]`` arrays in
+each layer of that kind), its free list and its table (``table``,
+``window_table``: one row a slot, logical page j at entry j in both). A
+window page goes back to its free list while the request runs, in the
+round in which its last position falls behind every later query's
+window (``release``: position <= reads_from - window, `reads_from` the
+lowest position whose query will still read the slot's pages); its
+entry is TRASH_PAGE again, which no reader reaches: the paged kernel
+walks only ``start // PS .. last // PS`` and the gather masks the rest.
+So a window layer holds ``window / PS + 1`` pages a slot (plus what a
+round writes ahead) however long the context, and a prefill whose
+attention does not read the pages (``reads_from`` = the position of the
+first tick) never claims more. Admission is gated on both kinds. The
+window kind keeps no prefix index and needs none off: a prompt page of
+the index is handed to a later request whole, every layer's part of it,
+so with prefix reuse on every layer is of the held kind (the decoder's
+rule, serving/continuous.py).
+
 Copy-on-write: any write into a page that is shared (referenced by
 another slot or by the prefix index) first clones it to a fresh page —
 ``write_barrier`` returns the (src, dst) copies for the caller to apply
@@ -137,11 +160,28 @@ class PageAllocator:
     """
 
     def __init__(self, num_pages: int, page_size: int, slots: int,
-                 max_pages_per_slot: int, prefix_cache: bool = True):
+                 max_pages_per_slot: int, prefix_cache: bool = True,
+                 window: int = 0, window_pages: int = 0,
+                 window_ahead: int = 1):
+        """`window`, `window_pages`: the second kind of page (both or
+        neither): a pool of `window_pages` for the layers whose window
+        is `window` positions. `window_ahead`: the most positions one
+        round writes past its first query (a fused round's ticks, a
+        verify chunk), which a slot's window pages must also cover."""
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is trash)")
         if page_size < 1:
             raise ValueError("page_size must be >= 1")
+        if bool(window) != bool(window_pages):
+            raise ValueError("window and window_pages go together "
+                             f"(got {window}, {window_pages})")
+        if window and prefix_cache:
+            raise ValueError(
+                "pages kept by layer kind need prefix_cache=False: a "
+                "prompt page of the prefix index is handed to a later "
+                "request whole, a window layer's released part with it")
+        if window and window_pages < 2:
+            raise ValueError("window_pages must be >= 2 (page 0 is trash)")
         self.num_pages = num_pages
         self.page_size = page_size
         self.slots = slots
@@ -169,6 +209,21 @@ class PageAllocator:
         self.cow_clones = 0
         self.admits = 0
         self.evictions = 0
+        # the window kind (none where window == 0): its table, its free
+        # list, and for each slot the first logical page it still holds
+        # (it holds [_wlow, _slot_len)) and the most it may hold from now
+        # on (`_wquota`: what admission keeps free for it)
+        self.window = window
+        self.window_pages = window_pages
+        # the most a slot holds between two releases: the window, what a
+        # round writes ahead, and a page for where the window begins
+        self._wring = pages_for(window + window_ahead, page_size) + 1
+        self.window_table = (np.zeros((slots, max_pages_per_slot), np.int32)
+                             if window else None)
+        self._wfree: list[int] = list(range(1, window_pages))
+        self._wlow: list[int] = [0] * slots
+        self._wquota: list[int] = [0] * slots
+        self.window_released = 0      # pages returned while a request ran
 
     # -- introspection ----------------------------------------------------
 
@@ -179,6 +234,19 @@ class PageAllocator:
     @property
     def used_pages(self) -> int:
         return (self.num_pages - 1) - len(self._free)
+
+    @property
+    def window_used_pages(self) -> int:
+        return max(0, self.window_pages - 1) - len(self._wfree)
+
+    def window_held(self, slot: int) -> int:
+        """Window-kind pages the slot holds now."""
+        return self._slot_len[slot] - self._wlow[slot] if self.window else 0
+
+    def window_covered(self, slot: int) -> int:
+        """Pages the slot's context covers: what it would hold of the
+        window kind if nothing were released."""
+        return self._slot_len[slot] - self._slot_first[slot]
 
     def available(self) -> int:
         """Pages an admission may still claim: free + evictable prefix
@@ -270,19 +338,71 @@ class PageAllocator:
         lay = self._layout(row, pad, total_len)
         return lay.need, lay.claimed * self.page_size
 
-    def can_admit(self, row, pad: int, total_len: int) -> bool:
+    def _window_low(self, first: int, reads_from: int) -> int:
+        """The first logical page of the window kind that a query at
+        `reads_from` or later still sees: every position of an earlier
+        page is <= reads_from - window."""
+        return max(first, (reads_from - self.window + 1) // self.page_size)
+
+    def plan_window(self, row, pad: int, total_len: int,
+                    reads_from: int | None = None) -> int:
+        """Window-kind pages an admission must find free: what it holds
+        at once (the prompt's pages a query at `reads_from` or later
+        sees; None: the prefill's own first query) or, if that is more,
+        the most it holds later (the ring). 0 without a window kind."""
+        if not self.window:
+            return 0
+        lay = self._layout(row, pad, total_len)
+        return self._window_claim(lay, len(row), total_len, reads_from)[1]
+
+    def _window_claim(self, lay: _Layout, prompt_len: int, total_len: int,
+                      reads_from: int | None) -> tuple[int, int]:
+        """(the first logical page an admission holds of the window kind,
+        the most pages it holds at once or later: its quota)."""
+        n_prompt = pages_for(prompt_len, self.page_size)
+        low = min(n_prompt, self._window_low(
+            lay.first,
+            lay.compute_start if reads_from is None else reads_from))
+        return low, max(n_prompt - low, self._window_cap(
+            lay.first, pages_for(total_len, self.page_size)))
+
+    def _window_cap(self, first: int, n_total: int) -> int:
+        """The most window pages a slot holds once a round has released
+        behind its window: the ring, or its whole sequence if shorter."""
+        return min(self._wring, n_total - first)
+
+    def _window_spare(self) -> int:
+        """Free window pages that no live slot's quota still counts on."""
+        return len(self._wfree) - sum(
+            max(0, q - self.window_held(s))
+            for s, q in enumerate(self._wquota) if q)
+
+    def can_admit(self, row, pad: int, total_len: int,
+                  reads_from: int | None = None) -> bool:
         """True when the admission can claim every page it needs NOW
-        and lazily through decode: free pages plus prefix pages that
-        are genuinely evictable (unreferenced AND not this admission's
-        own claimed hits), minus what live slots have reserved."""
+        and lazily through decode, of both kinds: free pages plus prefix
+        pages that are genuinely evictable (unreferenced AND not this
+        admission's own claimed hits), minus what live slots have
+        reserved; and of the window kind `plan_window` of the free pages
+        that no live slot's quota counts on."""
         lay = self._layout(row, pad, total_len)
         hitset = set(lay.hits[:lay.claimed])
         evictable = sum(1 for p in self._prefix.values()
                         if self._ref[p] == 1 and p not in hitset)
-        return lay.need <= len(self._free) + evictable - self._reserved
+        if lay.need > len(self._free) + evictable - self._reserved:
+            return False
+        return not self.window or self.plan_window(
+            row, pad, total_len, reads_from) <= self._window_spare()
 
-    def admit(self, slot: int, row, pad: int, total_len: int) -> AdmitPlan:
-        """Claim pages for a request: none for the logical pages that
+    def admit(self, slot: int, row, pad: int, total_len: int,
+              reads_from: int | None = None) -> AdmitPlan:
+        """`reads_from` (the window kind only): the lowest position
+        whose query will read this slot's pages, the prefill's own first
+        query by default; a prefill that attends over its own keys gives
+        the position of the first tick, and the window kind then claims
+        no page behind that query's window.
+
+        Claim pages for a request: none for the logical pages that
         hold padding only (TRASH_PAGE stays in the row), shared prompt
         pages from the prefix index (refcounted, read-only) up to where
         the computed suffix starts, fresh pages for the rest of the
@@ -322,6 +442,12 @@ class PageAllocator:
         # private pages for the computed prompt suffix
         for j in range(first + k, n_prompt):
             self.table[slot, j] = self._alloc_page()
+        if self.window:
+            low, quota = self._window_claim(lay, prompt_len, total_len,
+                                            reads_from)
+            self._wlow[slot], self._wquota[slot] = low, quota
+            for j in range(low, n_prompt):
+                self.window_table[slot, j] = self._alloc_window_page()
         self.admits += 1
         plan = AdmitPlan(slot=slot, total_len=total_len,
                          prompt_len=prompt_len, cached_positions=k * ps,
@@ -356,8 +482,38 @@ class PageAllocator:
         while self._slot_len[slot] < need:
             j = self._slot_len[slot]
             self.table[slot, j] = self._alloc_page()
+            if self.window:
+                self.window_table[slot, j] = self._alloc_window_page()
             self._slot_len[slot] = j + 1
             self._reserved -= 1
+
+    def _alloc_window_page(self) -> int:
+        if not self._wfree:
+            raise RuntimeError("window page pool exhausted (caller must "
+                               "gate admission on can_admit())")
+        return heapq.heappop(self._wfree)
+
+    def release(self, slot: int, reads_from: int) -> int:
+        """Give back the slot's window-kind pages that no query at
+        `reads_from` or later sees (every position <= reads_from -
+        window): their entries are TRASH_PAGE again. Before the round's
+        `append`, so that a round claims no more than it returned.
+        Returns how many went back."""
+        if not self.window or not self._slot_total[slot]:
+            return 0
+        low = min(self._window_low(self._slot_first[slot], reads_from),
+                  self._slot_len[slot])
+        gone = low - self._wlow[slot]
+        if gone <= 0:
+            return 0
+        for j in range(self._wlow[slot], low):
+            heapq.heappush(self._wfree, int(self.window_table[slot, j]))
+            self.window_table[slot, j] = TRASH_PAGE
+        self._wlow[slot] = low
+        self._wquota[slot] = max(self.window_held(slot), self._window_cap(
+            self._slot_first[slot], self._slot_total[slot]))
+        self.window_released += gone
+        return gone
 
     def write_barrier(self, slot: int, start: int, end: int) -> list:
         """Copy-on-write guard: every page overlapping positions
@@ -395,6 +551,13 @@ class PageAllocator:
             if self._ref[page] == 0:
                 heapq.heappush(self._free, page)
         self._reserved -= self._slot_total[slot] - self._slot_len[slot]
+        if self.window:
+            for j in range(self._wlow[slot], self._slot_len[slot]):
+                page = int(self.window_table[slot, j])
+                if page != TRASH_PAGE:   # (an admission the pool cut short)
+                    heapq.heappush(self._wfree, page)
+            self.window_table[slot, :] = TRASH_PAGE
+            self._wlow[slot] = self._wquota[slot] = 0
         self.table[slot, :] = TRASH_PAGE
         self._slot_first[slot] = 0
         self._slot_len[slot] = 0
@@ -413,6 +576,11 @@ class PageAllocator:
         self._reserved = 0
         self._prefix.clear()
         self._page_key.clear()
+        if self.window:
+            self.window_table[:, :] = TRASH_PAGE
+            self._wfree = list(range(1, self.window_pages))
+            self._wlow = [0] * self.slots
+            self._wquota = [0] * self.slots
 
     # -- invariants (the property test's oracle) --------------------------
 
@@ -439,6 +607,28 @@ class PageAllocator:
         assert self._reserved == sum(
             t - l for t, l in zip(self._slot_total, self._slot_len))
         assert self._reserved >= 0
+        if not self.window:
+            return
+        # the window kind: every page free or in exactly one slot's row,
+        # a slot's pages exactly at [_wlow, _slot_len), within its quota
+        owned: list[int] = []
+        for s in range(self.slots):
+            low, end = self._wlow[s], self._slot_len[s]
+            assert self._slot_first[s] <= low <= max(end, low), (s, low, end)
+            row = self.window_table[s]
+            assert (row[:low] == TRASH_PAGE).all(), (s, row)
+            assert (row[max(end, low):] == TRASH_PAGE).all(), (s, row)
+            assert (row[low:end] != TRASH_PAGE).all(), (s, row)
+            owned.extend(int(p) for p in row[low:end])
+            assert self.window_held(s) <= self._wquota[s], (
+                s, self.window_held(s), self._wquota[s])
+        wfree = set(self._wfree)
+        assert len(wfree) == len(self._wfree), "window freelist duplicates"
+        assert len(set(owned)) == len(owned), "a window page in two owners"
+        assert not wfree & set(owned), "a window page both free and owned"
+        assert wfree | set(owned) == set(range(1, self.window_pages)), \
+            "a window page lost"
+        assert self._window_spare() >= 0
 
 
 # ---------------------------------------------------------------------------
@@ -454,6 +644,8 @@ def init_paged_cache(model, max_pages_per_slot: int):
 
     tok1 = jnp.zeros((1, 1), jnp.int32)
     pt = jnp.zeros((1, max_pages_per_slot), jnp.int32)
+    if getattr(model.cfg, "kv_window_pages", 0):
+        pt = (pt, pt)       # one table a kind
     shapes = jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0), tok1,
                            decode_index=jnp.zeros((1,), jnp.int32),
@@ -465,7 +657,9 @@ def init_paged_cache(model, max_pages_per_slot: int):
 def copy_pages(cache, src, dst):
     """Apply COW clones on-device: pool[dst] = pool[src] for every
     leaf of the paged cache pytree. src/dst are [m] int32 page ids;
-    jit at the call site (one compile per clone-batch size m)."""
+    jit at the call site (one compile per clone-batch size m). (Clones
+    come of shared pages, which a decoder that keeps pages by layer
+    kind never has: its prefix cache is off.)"""
     import jax
 
     return jax.tree.map(lambda pool: pool.at[dst].set(pool[src]), cache)

@@ -32,6 +32,21 @@ Three per-replica speed levers compose on top of the slot machinery
   [S, k+1] forward; output stays token-for-token equal to plain greedy
   decode.
 
+Two rules over what is there, each logged once at the build with its
+reason, and no option (`window_pages_for`, `_fresh_prefill_rule`):
+
+- **Pages kept by layer kind** (runtime/kvcache.py): where the prefix
+  cache is off, a layer with a sliding window keeps its pages in a pool
+  and a table of its own, and the allocator takes back, in the loop's
+  `pages` phase, the pages that have fallen behind the slot's window. With
+  the prefix cache on, a prompt page may be handed to a later request
+  whole, so every layer holds its pages for the slot's life, as ever.
+- **The prompt's attention through the flash kernel**: a decoder without
+  a prefix cache computes every real token of a prompt in the one rung,
+  so a causal one-token model whose attention is the flash kernel's
+  attends over the rung's own keys and writes the pages for the ticks
+  only; no score exists beyond a tile. Every other decoder gathers.
+
 What a round does is its **kind of step** (serving/steps.py): one token
 a slot; a speculative chunk; or, for a model that generates by diffusion
 over blocks (cfg.gen_block > 0), one pass over every slot's block. The
@@ -63,6 +78,65 @@ def _prom(name, kind, doc, **kw):
     from kubeflow_tpu.runtime.metrics import prom_metric
 
     return prom_metric(name, kind, doc, **kw)
+
+
+def window_pages_for(cfg, slots: int, prompt_len: int, max_new_tokens: int,
+                     *, prefix_cache: bool, draft: bool = False) -> int:
+    """The window kind's pool (`kv_window_pages` of the model's config),
+    by the first rule of the module docstring: 0 (every layer holds its
+    pages) unless the prefix cache is off, the model is served a token a
+    step without a draft, and some layer has a window shorter than a
+    slot's longest sequence. Else every slot's ring and the trash page:
+    the window, what a fused round writes ahead, a page for where the
+    window begins; or a whole sequence where the prompt's attention
+    reads the pages (the gather) and holds them until the first tick."""
+    from kubeflow_tpu.runtime.kvcache import pages_for
+    from kubeflow_tpu.serving.steps import TokenStep
+
+    ps = cfg.kv_page_size
+    total = prompt_len + max_new_tokens
+    window = max((s.window for s in cfg.layers()), default=0)
+    if not ps:
+        why = "no paged cache"
+    elif not window:
+        why = "no layer has a window"
+    elif prefix_cache:
+        why = "the prefix cache is on: a prompt page is handed on whole"
+    elif cfg.gen_block or draft:
+        why = "a block model or a draft"
+    elif window >= total:
+        why = f"the window {window} covers a slot's {total} positions"
+    else:
+        why = ""
+    if why:
+        log.info("pages by layer kind: every layer holds its pages (%s)", why)
+        return 0
+    ring = pages_for(window + TokenStep.FUSE, ps) + 1
+    if not _fresh_prefill_rule(cfg, prompt_len, prefix_cache=False,
+                               draft=False)[0]:
+        ring = max(ring, pages_for(total, ps))
+    log.info("pages by layer kind: window layers keep %d pages a slot of "
+             "%d positions a page and release behind the window of %d "
+             "(prefix cache off)", ring, ps, window)
+    return slots * ring + 1
+
+
+def _fresh_prefill_rule(cfg, prompt_len: int, *, prefix_cache: bool,
+                        draft: bool) -> tuple:
+    """(whether a paged prefill attends over the rung's own keys, why):
+    the second rule of the module docstring."""
+    from kubeflow_tpu.runtime.kvcache import prefill_ladder
+
+    ladder = prefill_ladder(prompt_len, cfg.kv_page_size)
+    if prefix_cache:
+        return False, "the prefix cache is on: a rung may start behind a hit"
+    if cfg.gen_block or draft:
+        return False, "a block model or a draft: no causal one-token prefill"
+    if cfg.attention_impl != "flash":
+        return False, f"attention impl is {cfg.attention_impl!r}, not flash"
+    if any(n % 128 for n in ladder):
+        return False, f"a rung of {list(ladder)} is no multiple of 128"
+    return True, "prefix cache off, causal, flash attention"
 
 
 class _DecodeMeter:
@@ -183,7 +257,7 @@ class _Request:
 
     __slots__ = ("prompt", "pad", "req", "ev", "result", "attrs", "deadline",
                  "t_submit", "t_admit", "t_first", "t_done", "slot",
-                 "prefill_tokens")
+                 "prefill_tokens", "released")
 
     def __init__(self, prompt, pad: int, req: int, deadline):
         self.prompt, self.pad, self.req = prompt, pad, req
@@ -194,6 +268,7 @@ class _Request:
         self.t_admit = self.t_first = self.t_done = 0.0
         self.slot = -1
         self.prefill_tokens = 0
+        self.released = 0      # window-kind pages given back while it ran
 
     def finish(self, result, attrs=None) -> None:
         """The one exit: the answer as its step gives it (or the error),
@@ -329,13 +404,26 @@ class SlotDecoder:
                     f"kv_pages={cfg.kv_pages} cannot hold even one "
                     f"sequence ({self._mp} pages of {self.page_size} "
                     "needed, page 0 is trash)")
+            wpages = getattr(cfg, "kv_window_pages", 0)
+            if wpages and (B or self.spec):
+                raise ValueError(
+                    "kv_window_pages (pages kept by layer kind) is for a "
+                    "one-token model without a draft")
             self.alloc = PageAllocator(
                 cfg.kv_pages, self.page_size, slots, self._mp,
-                prefix_cache=prefix_cache)
+                prefix_cache=prefix_cache,
+                window=(max(s.window for s in cfg.layers()) if wpages else 0),
+                window_pages=wpages, window_ahead=steps.TokenStep.FUSE)
             # the suffix lengths prefill runs at (runtime/kvcache.py)
             self._ladder = prefill_ladder(prompt_len, self.page_size)
+            self._fresh, why = _fresh_prefill_rule(
+                cfg, prompt_len, prefix_cache=prefix_cache, draft=self.spec)
+            log.info("paged prefill: %s (%s)",
+                     "the rung's own attention" if self._fresh
+                     else "gathers the slot's pages", why)
         else:
             self.alloc = None
+            self._fresh = False
         self.meter = _DecodeMeter(metrics_name) if metrics_name else None
         self._pages_published = -1   # free pages at the last publish
 
@@ -361,6 +449,13 @@ class SlotDecoder:
             # how much of the page table the one-token step's ticks walk
             # (steps.py `TokenStep._counts`)
             self._counters.update(kv_pages_walked=0, kv_pages_tabled=0)
+            if self.alloc.window:
+                # summed a round over the slots that hold a request: the
+                # window-kind pages held, and the pages their contexts
+                # cover (what would be held if nothing were released)
+                self._counters.update(kv_window_pages_held_sum=0,
+                                      kv_window_pages_covered_sum=0,
+                                      kv_pages_walked_window=0)
         if B:
             # counted on the device and read back with `remaining`
             self._counters.update(dict.fromkeys(steps.BLOCK_COUNTERS, 0))
@@ -384,7 +479,8 @@ class SlotDecoder:
         else:
             self.step = steps.TokenStep(
                 model, self._params, *geometry, temperature=temperature,
-                top_k=top_k, seed=seed)
+                top_k=top_k, seed=seed, fresh_prefill=self._fresh)
+            self._counters.update(dict.fromkeys(self.step.counted, 0))
         if self.paged:
             t0 = _stamp()
             self._prefill_at = self.step.prefill_programs(self._ladder, mesh)
@@ -476,7 +572,9 @@ class SlotDecoder:
             prompt_tokens=self.P - r.pad,
             prefill_tokens_computed=r.prefill_tokens,
             new_tokens=r.req if outcome == "ok" else 0, slot=r.slot,
-            outcome=outcome, **r.attrs)
+            outcome=outcome, **r.attrs,
+            **({"window_pages_released": r.released}
+               if self.alloc is not None and self.alloc.window else {}))
         if self.meter and first is not None:
             self.meter.request_waits(wait, first)
 
@@ -499,11 +597,22 @@ class SlotDecoder:
         out["speculative"] = self.spec
         out["cache_bytes"] = self._cache_bytes
         if self.paged:
+            a = self.alloc
+            if a.window:
+                # by kind; `kv_pages_used` / `kv_pages_total` below are
+                # then of both pools together
+                out.update(
+                    kv_pages_used_held=a.used_pages,
+                    kv_pages_total_held=a.num_pages - 1,
+                    kv_pages_used_window=a.window_used_pages,
+                    kv_pages_total_window=a.window_pages - 1,
+                    kv_window_pages_released=a.window_released)
             out.update(
-                kv_pages_total=self.alloc.num_pages - 1,  # sans trash
+                kv_pages_total=(a.num_pages - 1      # sans trash
+                                + max(0, a.window_pages - 1)),
                 kv_page_size=self.page_size,
-                kv_pages_free=self.alloc.free_pages,
-                kv_pages_used=self.alloc.used_pages,
+                kv_pages_free=a.free_pages,
+                kv_pages_used=a.used_pages + a.window_used_pages,
                 prefix_hit_pages=self.alloc.prefix_hit_pages,
                 prefix_hit_tokens=self.alloc.prefix_hit_tokens,
                 cow_clones=self.alloc.cow_clones,
@@ -530,6 +639,26 @@ class SlotDecoder:
             if free != self._pages_published:
                 self._pages_published = free
                 self.meter.pages(free, self.alloc.used_pages)
+
+    def _tables(self, slot: int | None = None):
+        """What a program is given as its page table: the allocator's
+        (one slot's row of it), or where pages are kept by layer kind the
+        pair (held kind's, window kind's)."""
+        rows = slice(None) if slot is None else slice(slot, slot + 1)
+        held = self.alloc.table[rows]
+        if not self.alloc.window:
+            return self._jnp.asarray(held) if slot is None else held
+        # copies: a release rewrites entries that a prefill still in
+        # flight reads, and the device may take the host's array as it is
+        return (self._jnp.asarray(held.copy()),
+                self._jnp.asarray(self.alloc.window_table[rows].copy()))
+
+    def _note_window_pages(self, owners) -> None:
+        if self.alloc.window:
+            c, a = self._counters, self.alloc
+            c["kv_window_pages_held_sum"] += sum(map(a.window_held, owners))
+            c["kv_window_pages_covered_sum"] += sum(
+                map(a.window_covered, owners))
 
     def _cow_copy(self, copies) -> None:
         """[(src, dst)] page clones, applied before a program writes:
@@ -693,16 +822,19 @@ class SlotDecoder:
                     ticks = (step.ticks(owners)
                              if not waiting or not self._free else 1)
                     if self.paged:
-                        # a round's writes march forward: hand out the
-                        # pages it will cross (reserved at admission)
-                        # and run the COW barrier over the write range
+                        # a round's writes march forward: take back the
+                        # window-kind pages behind its first query's
+                        # window, hand out the pages it will cross
+                        # (reserved at admission) and run the COW
+                        # barrier over the write range
                         for s_, r in owners.items():
                             start, end = step.writes(s_, r, ticks)
+                            r.released += self.alloc.release(s_, start)
                             self.alloc.append(s_, end)
                             self._cow_copy(
                                 self.alloc.write_barrier(s_, start, end))
-                    table = (self._jnp.asarray(self.alloc.table)
-                             if self.paged else None)
+                        self._note_window_pages(owners)
+                    table = self._tables() if self.paged else None
                 with phase("tick", fused=int(ticks > 1)), self._ctx:
                     step.dispatch(owners, ticks, table)
                 self._counters["rounds"] += 1
@@ -776,7 +908,10 @@ class SlotDecoder:
                     continue
                 # (the allocator reads the row's real pages only)
                 row, total = r.prompt, step.end(r)
-                if not self.alloc.can_admit(row, r.pad, total):
+                # a prefill that attends over its own keys leaves the
+                # pages to the ticks, the first of which is at prompt_len
+                reads_from = self.P if self._fresh else None
+                if not self.alloc.can_admit(row, r.pad, total, reads_from):
                     # head-of-line page gate: FIFO order is preserved (no
                     # bypass) — the request waits for completions to free
                     # pages, and everything behind it waits too
@@ -785,7 +920,8 @@ class SlotDecoder:
                 slot = self._free.pop()
             try:
                 with phase("admit"):
-                    plan = self.alloc.admit(slot, row, r.pad, total)
+                    plan = self.alloc.admit(slot, row, r.pad, total,
+                                            reads_from)
                     # the suffix is a rung of the ladder: compiled when
                     # the decoder was built
                     suffix = self.P - plan.compute_start
@@ -793,7 +929,7 @@ class SlotDecoder:
                     self._cow_copy(plan.copies)
                     first = step.install_paged(
                         self._prefill_at[suffix], r, slot,
-                        plan.compute_start, self.alloc.table[slot:slot + 1])
+                        plan.compute_start, self._tables(slot))
             except Exception as e:
                 # the slot's PAGES go back before the slot id does —
                 # recycling the slot while the allocator still holds

@@ -848,8 +848,21 @@ def serve_lm_generator(name: str, model_name: str, *, prompt_len: int = 128,
                              "cache (rolling_kv_cache evicts positions a "
                              "rejected draft must rewind over)")
     if kv_pages and getattr(model.cfg, "rolling_kv_cache", False):
-        raise ValueError("the paged KV cache is exclusive with "
-                         "rolling_kv_cache")
+        raise ValueError(
+            "the paged KV cache is exclusive with rolling_kv_cache: it "
+            "keeps a window layer's pages by itself (with prefix_cache "
+            "off it takes back the pages behind the window while the "
+            "request runs, runtime/kvcache.py)")
+    if kv_pages and hasattr(model.cfg, "layers"):
+        # pages kept by layer kind: a rule over what is there, no option
+        import dataclasses
+
+        from kubeflow_tpu.serving.continuous import window_pages_for
+
+        model = model.clone(cfg=dataclasses.replace(
+            model.cfg, kv_window_pages=window_pages_for(
+                model.cfg, decode_slots, prompt_len, max_new_tokens,
+                prefix_cache=prefix_cache, draft=bool(draft_model))))
     quantized = param_dtype in ("int8", "int4")
     if quantized and mesh is not None:
         raise ValueError(f"param_dtype={param_dtype!r} serving is "

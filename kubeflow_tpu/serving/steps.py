@@ -47,12 +47,22 @@ from kubeflow_tpu.runtime.speculative import (
 BLOCK_COUNTERS = ("block_passes", "blocks_committed", "kv_pages_walked",
                   "moe_pairs", "moe_expert_visits", "moe_load_max",
                   "moe_kernel_pairs")
+# What a one-token tick of a model with mixture layers counts on the
+# device, over the active slots and summed over the layers: the mixture's
+# four above (of the pairs whose experts this chip holds) and every pair
+# the tokens were routed, held here or not (ops/moe.py).
+MOE_COUNTERS = BLOCK_COUNTERS[3:] + ("moe_pairs_routed",)
 
 
 class TokenStep:
     """One token a slot a tick, over the dense slot cache or (the model
     built with kv_pages) the paged one. State: (cache, last logits, pos,
-    remaining, out, pads, req, rng), donated to every program."""
+    remaining, out, pads, req, rng), donated to every program; a model
+    with mixture layers adds a ninth, the ticks' MOE_COUNTERS since the
+    last dispatch. Where the model keeps pages by layer kind
+    (cfg.kv_window_pages) a page table is the pair (held kind's, window
+    kind's). `fresh_prefill`: the paged prefill attends over the rung's
+    own keys (the decoder's rule, serving/continuous.py)."""
 
     # -- FUSE ticks in one dispatched program. Each
     #    dispatch costs a host round-trip (launch, the readback of
@@ -73,15 +83,31 @@ class TokenStep:
     #    and every active slot has >= FUSE tokens to go. ------------
     FUSE = 8
     _opening_shapes = ()    # of `_opening`'s arguments to the paged install
+    # what a tick of a model with mixture layers counts in the state's
+    # ninth leaf (a block model's pass counts them among its own)
+    _tick_counters = MOE_COUNTERS
 
     def __init__(self, model, params, slots: int, prompt_len: int,
                  max_new_tokens: int, pages_per_row: int = 0, *,
-                 temperature: float = 0.0, top_k: int = 0, seed: int = 0):
+                 temperature: float = 0.0, top_k: int = 0, seed: int = 0,
+                 fresh_prefill: bool = False):
         self.model, self.params = model, params
         self.S, self.P, self.N = slots, prompt_len, max_new_tokens
         # a paged cache's table row, in pages; 0: the dense slot cache
         self.mp = pages_per_row
         self.page_size = model.cfg.kv_page_size if pages_per_row else 0
+        cfg = model.cfg
+        specs = cfg.layers() if hasattr(cfg, "layers") else ()
+        self.two_kinds = bool(pages_per_row
+                              and getattr(cfg, "kv_window_pages", 0))
+        # what a tick's attention walks, a table at a time: the windows
+        # of the layers that read it (`writes`)
+        self._walks = sorted({(int(bool(s.window and self.two_kinds)),
+                               s.window) for s in specs}) or [(0, 0)]
+        # the mixture's counters ride the state of a one-token model too
+        self.counted = (self._tick_counters
+                        if any(s.moe for s in specs) else ())
+        self._fresh_kw = {"fresh": True} if fresh_prefill else {}
         self.temperature, self.top_k, self.seed = temperature, top_k, seed
         # the most tokens a slot can finish in a fused round
         self.fuse_tokens = self.FUSE
@@ -112,7 +138,7 @@ class TokenStep:
         # -- compiled: install K prefilled rows into K slots in ONE
         #    program (K static, unrolled; slot ids traced) --------------
         def _install(state, cache_k, logits_k, slots_k, pads_k, news_k):
-            cache, last, pos, remaining, out, pads, req, rng = state
+            cache, last, pos, remaining, out, pads, req, rng, *more = state
             k = logits_k.shape[0]
             for i in range(k):  # static unroll: K is a compile-time size
                 si = slots_k[i]
@@ -129,17 +155,17 @@ class TokenStep:
                     out, jnp.zeros((1, self.N), jnp.int32), (si, 0))
                 pads = _set1(pads, si, pads_k[i])
                 req = _set1(req, si, news_k[i])
-            return (cache, last, pos, remaining, out, pads, req, rng)
+            return (cache, last, pos, remaining, out, pads, req, rng, *more)
 
         self._install = jax.jit(_install, donate_argnums=(0,))
 
         # -- compiled: deactivate slots (dummy prefill targets, cancels) -
         def _clear_slots(state, slots_k):
-            cache, last, pos, remaining, out, pads, req, rng = state
+            cache, last, pos, remaining, out, pads, req, rng, *more = state
             clear = (jnp.arange(self.S)[:, None]
                      == slots_k[None, :]).any(axis=1)
             remaining = jnp.where(clear, 0, remaining)
-            return (cache, last, pos, remaining, out, pads, req, rng)
+            return (cache, last, pos, remaining, out, pads, req, rng, *more)
 
         self._clear_slots = jax.jit(_clear_slots, donate_argnums=(0,))
 
@@ -154,11 +180,11 @@ class TokenStep:
         #    `block`: what a block model's request opens with ------------
         def _paged_prefill_install(params, state, toks, start, pt_row,
                                    pad, slot, req_n, *block):
-            cache, last, pos, remaining, out, pads, req, rng = state
+            cache, last, pos, remaining, out, pads, req, rng, *more = state
             logits, mut = model.apply(
                 params | {"cache": cache}, toks, train=False,
                 decode_index=start, mutable=["cache"], pad_len=pad,
-                page_table=pt_row)
+                page_table=pt_row, **self._fresh_kw)
             cache = mut["cache"]
             last, first_pos = self._open(last, logits, slot, *block)
             pos = _set1(pos, slot, first_pos)
@@ -167,7 +193,7 @@ class TokenStep:
                 out, jnp.zeros((1, self.N), jnp.int32), (slot, 0))
             pads = _set1(pads, slot, pad[0])
             req = _set1(req, slot, req_n)
-            return (cache, last, pos, remaining, out, pads, req, rng)
+            return (cache, last, pos, remaining, out, pads, req, rng, *more)
 
         self._paged_prefill_install = jax.jit(
             _paged_prefill_install, donate_argnums=(1,))
@@ -211,7 +237,7 @@ class TokenStep:
     # -- what the programs are made of (a block model's differ) ----------
 
     def _tick_once(self, params, state, page_table):
-        cache, last, pos, remaining, out, pads, req, rng = state
+        cache, last, pos, remaining, out, pads, req, rng, *more = state
         active = remaining > 0
         rng, sub = jax.random.split(rng)
         tok = _sample(last, self.temperature, self.top_k, sub)
@@ -230,16 +256,26 @@ class TokenStep:
         # then fetches no page for it.
         logits_next, mut = self.model.apply(
             params | {"cache": cache}, tok[:, None], train=False,
-            decode_index=pos, mutable=["cache"],
+            decode_index=pos,
+            mutable=["cache", "diagnostics"] if self.counted else ["cache"],
             pad_len=jnp.where(active, pads, pos + 1),
             **({"page_table": page_table}
                if page_table is not None else {}))
         pos = jnp.where(active, pos + 1, pos)
         remaining = jnp.where(active, remaining - 1, remaining)
         last = jnp.where(active[:, None], logits_next[:, 0], last)
-        return (mut["cache"], last, pos, remaining, out, pads, req, rng)
+        if self.counted:
+            more = [more[0] + jnp.stack([
+                jnp.asarray(_diag_sum(mut.get("diagnostics", {}), n),
+                            jnp.int32) for n in self.counted])]
+        return (mut["cache"], last, pos, remaining, out, pads, req, rng,
+                *more)
 
     def _counted_from_zero(self, state):
+        """A dispatched program counts from zero: the host adds each
+        round's counts to its own, which never wrap."""
+        if self.counted:
+            return tuple(state[:8]) + (jnp.zeros_like(state[8]),)
         return state
 
     def _open(self, last, logits, slot):
@@ -271,11 +307,13 @@ class TokenStep:
             jnp.zeros((self.S,), jnp.int32),            # pad_len
             jnp.zeros((self.S,), jnp.int32),            # req budget
             jax.random.PRNGKey(self.seed),
+            *((jnp.zeros((len(self.counted),), jnp.int32),)
+              if self.counted else ()),
         )
         # last readback; admission writes fresh slots' mirrors
         self.rem = np.zeros(self.S, np.int64)
         self.pos = np.zeros(self.S, np.int64)
-        self._walked = 0
+        self._walked = self._walked_window = 0
 
     def end(self, r) -> int:
         return self.P + r.req
@@ -295,10 +333,13 @@ class TokenStep:
         def i32(*shape):
             return jax.ShapeDtypeStruct(shape, jnp.int32)
 
+        row = i32(1, self.mp)
+
         def lowered(length):
             return self._paged_prefill_install.lower(
                 self.params, self.state, i32(1, length), i32(1),
-                i32(1, self.mp), i32(1), i32(), i32(), *self._opening_shapes)
+                (row, row) if self.two_kinds else row, i32(1), i32(), i32(),
+                *self._opening_shapes)
 
         with cf.ThreadPoolExecutor(len(ladder)) as pool, \
                 (mesh or contextlib.nullcontext()):
@@ -309,7 +350,8 @@ class TokenStep:
         block, first_pos = self._opening(slot, r)
         self.state = program(
             self.params, self.state, r.prompt[None, start:],
-            jnp.asarray([start], jnp.int32), jnp.asarray(table_row),
+            jnp.asarray([start], jnp.int32),
+            jax.tree.map(jnp.asarray, table_row),
             jnp.asarray([r.pad], jnp.int32), jnp.int32(slot),
             jnp.int32(r.req), *block)
         self.rem[slot], self.pos[slot] = r.req, first_pos
@@ -356,15 +398,20 @@ class TokenStep:
         start = int(self.pos[slot])
         # walked: the pages that hold what a query at `pos` sees behind
         # r.pad positions of left padding, the range `_decode_paged` hands
-        # the paged attention kernel (models/transformer.py)
-        window = self.model.cfg.attention_window
-        for pos in range(start, start + ticks):
-            first = max(r.pad, pos - window + 1) if window else r.pad
-            self._walked += pos // self.page_size - first // self.page_size + 1
+        # the paged attention kernel (models/transformer.py); where the
+        # layers' windows differ, once a window
+        for kind, window in self._walks:
+            pages = 0
+            for pos in range(start, start + ticks):
+                first = max(r.pad, pos - window + 1) if window else r.pad
+                pages += pos // self.page_size - first // self.page_size + 1
+            self._walked += pages
+            self._walked_window += kind * pages
         return start, start + ticks
 
     def dispatch(self, owners, ticks: int, table) -> None:
-        self._tabled = 0 if table is None else ticks * table.size
+        self._tabled = 0 if table is None else ticks * len(self._walks) * (
+            table[0] if self.two_kinds else table).size
         self.state = (self._step_fused if ticks > 1 else self._step)(
             self.params, self.state, *(() if table is None else (table,)))
 
@@ -386,8 +433,15 @@ class TokenStep:
         query sees, beside ticks x every entry of the table, which is
         what gathering the table touches."""
         walked, self._walked = self._walked, 0
-        return ({"kv_pages_walked": walked, "kv_pages_tabled": self._tabled}
-                if self.mp else {})
+        counts = ({"kv_pages_walked": walked, "kv_pages_tabled": self._tabled}
+                  if self.mp else {})
+        if self.two_kinds:      # of `walked`, the window kind's table's part
+            counts["kv_pages_walked_window"] = self._walked_window
+            self._walked_window = 0
+        if self.counted:    # the round's counts, from the same read-back
+            counts.update(zip(self.counted,
+                              np.asarray(self.state[8]).tolist()))
+        return counts
 
     def answer(self, slot: int, r) -> tuple:
         return [int(t) for t in self._out[slot][:r.req]], {}
@@ -407,6 +461,8 @@ class BlockStep(TokenStep):
     positions. In the place of the last logits the state holds the
     blocks (`_fresh_last`); an answer is its tokens and the step of its
     block at which each was fixed."""
+
+    _tick_counters = ()     # BLOCK_COUNTERS, in `blk["ctr"]`
 
     def __init__(self, model, params, *geometry, seed: int = 0):
         self.B = model.cfg.gen_block

@@ -265,9 +265,14 @@ def test_block_kernel_compiles_for_a_v5e_at_the_cells_shape(
     (8192, 2048, 768, 128), (8192, 768, 2048, 128),
     # the widest experts the rule can give it: the zoo's gpt-moe-8e at a
     # served batch's few rows (8 MB an expert, two in VMEM)
-    (256, 1024, 4096, 8), (256, 4096, 1024, 8)],
+    (256, 1024, 4096, 8), (256, 4096, 1024, 8),
+    # a chip's share of Trinity-Large's experts (32 of 3,072 x 3,072: one
+    # matrix is 18.9 MB, two of them in VMEM): a tick of 32 slots x 4 and
+    # the prefill's rung of 4,096, the longest the rule gives the kernel
+    (128, 3072, 3072, 32), (16384, 3072, 3072, 32)],
     ids=["pass-gate-up", "pass-down", "top-rung-gate-up", "top-rung-down",
-         "gpt-moe-8e-gate-up", "gpt-moe-8e-down"])
+         "gpt-moe-8e-gate-up", "gpt-moe-8e-down", "share-of-32-tick",
+         "share-of-32-rung-4096"])
 def test_grouped_matmul_compiles_for_a_v5e_at_the_cells_shape(
         v5e_chip, monkeypatch, rows, k, n, groups):
     """The experts' streamed grouped matmul (ops/grouped_matmul.py; its

@@ -15,7 +15,10 @@ and beyond (to 512 a group), which is where `grouped_matmul.
 MAX_MEAN_ROWS` comes from. Group sizes are a seeded multinomial over
 skewed expert probabilities (the fullest 2.4 times the mean, as
 `moe.load_max_over_mean.blockdiff` reads), with a twentieth of the rows
-left dead behind the last group. One JSON line a shape: the largest
+left dead behind the last group. `--groups G --k K --n N --rows-a-group
+a,b,..` tries another expert instead (a chip's share of Trinity-Large:
+`--groups 32 --k 3072 --n 3072 --rows-a-group 1,2,16,128`, one matrix
+18.9 MB, which is what `grouped_matmul.MAX_GROUP_BYTES` was read at). One JSON line a shape: the largest
 difference on the groups' rows over the largest reference value (both
 round a float32 sum to bfloat16 once: 2**-7 bounds it), the
 milliseconds of each, and the share of the chip's published bandwidth
@@ -23,6 +26,7 @@ milliseconds of each, and the share of the chip's published bandwidth
 bytes. Exits 69 when there is no TPU, 1 on a difference over the bound.
 """
 
+import argparse
 import json
 import os
 import sys
@@ -36,10 +40,10 @@ ROWS = (2048, 4096, 6144, 8192, 16384, 32768, 65536)
 REPEATS = 30
 
 
-def group_sizes(rng, rows: int):
+def group_sizes(rng, rows: int, groups: int = GROUPS):
     import numpy as np
 
-    p = rng.dirichlet(np.full(GROUPS, 3.0))
+    p = rng.dirichlet(np.full(groups, 3.0))
     return rng.multinomial(rows - rows // 20, p).astype(np.int32)
 
 
@@ -54,7 +58,23 @@ def timed(fn, *args) -> float:
     return (time.perf_counter() - t0) / REPEATS * 1e3
 
 
-def main() -> int:
+def shapes(argv):
+    """(groups, [(k, n), ...], rows): the block-diffusion cell's by
+    default, one expert of the caller's where `--k` is given."""
+    p = argparse.ArgumentParser()
+    p.add_argument("--groups", type=int, default=GROUPS)
+    p.add_argument("--k", type=int, default=0)
+    p.add_argument("--n", type=int, default=0)
+    p.add_argument("--rows-a-group", default="")
+    a = p.parse_args(argv)
+    if not a.k:
+        return GROUPS, ((2048, 768), (768, 2048)), ROWS
+    rows = tuple(max(int(float(r) * a.groups), 8)
+                 for r in a.rows_a_group.split(","))
+    return a.groups, ((a.k, a.n or a.k),), rows
+
+
+def main(argv=None) -> int:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -73,11 +93,12 @@ def main() -> int:
     reference = jax.jit(jax.lax.ragged_dot)
     rng = np.random.default_rng(32)
     ok = True
-    for k, n in ((2048, 768), (768, 2048)):
-        rhs = jax.random.normal(jax.random.PRNGKey(k), (GROUPS, k, n),
+    groups, experts, all_rows = shapes(argv)
+    for k, n in experts:
+        rhs = jax.random.normal(jax.random.PRNGKey(k), (groups, k, n),
                                 jnp.bfloat16) * k ** -0.5
-        for rows in ROWS:
-            sizes = group_sizes(rng, rows)
+        for rows in all_rows:
+            sizes = group_sizes(rng, rows, groups)
             lhs = jax.random.normal(jax.random.PRNGKey(rows), (rows, k),
                                     jnp.bfloat16)
             counts = jnp.asarray(sizes)
@@ -91,7 +112,8 @@ def main() -> int:
             nbytes = int((sizes > 0).sum()) * k * n * 2
             print(json.dumps({
                 "k": k, "n": n, "rows": rows,
-                "rows_a_group": rows / GROUPS, "fullest": int(sizes.max()),
+                "groups": groups,
+                "rows_a_group": rows / groups, "fullest": int(sizes.max()),
                 "max_err_rel_to_max": round(err, 6),
                 "kernel_ms": round(ms, 4),
                 "ragged_dot_ms": round(timed(reference, lhs, rhs, counts), 4),
