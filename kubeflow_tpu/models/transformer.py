@@ -320,7 +320,7 @@ class Attention(nn.Module):
                 else self.layer.window)
 
     def _decode_paged(self, q, k, v, decode_index, pad_len, page_table,
-                      block_step=False, fresh=False):
+                      block_step=False, fresh=False, hit_below=0):
         """Paged decode: the cache is a pool of [kv_pages, kv_page_size]
         position pages shared across slots; `page_table` [B, MP] maps
         each slot's logical page j (positions j*PS..(j+1)*PS-1) to a
@@ -347,6 +347,13 @@ class Attention(nn.Module):
         caller's word that the chunk IS one block (B queries from the
         block's first position): its rows then all see one range, which
         is what the kernel takes.
+
+        `fresh` is the caller's word that the chunk is a prompt's rung
+        which the flash kernel tiles: it takes neither path, but attends
+        over its own keys, behind the slot's pages of positions
+        [0, `hit_below`) where a prefix hit lies there (`pad_len` <
+        `hit_below`: static, the rung's first position where the
+        decoder's prefix cache is on, else 0), and only writes pages.
 
         Why it's safe that the gather sees unallocated (0 = trash-page)
         table entries: the allocator guarantees every position <= the
@@ -380,12 +387,11 @@ class Attention(nn.Module):
         k_w = k.astype(cfg.dtype)
         v_w = v.astype(cfg.dtype)
         if fresh:
-            # The chunk is a whole prompt and nothing real lies before
-            # it (the caller's word: a decoder without a prefix cache,
-            # one token a step): its attention is over its own q, k, v,
-            # the left padding a segment of its own, and no score exists
-            # beyond the kernel's tile. Only later ticks read the pages,
-            # and those of a window layer see its last `window`
+            # The chunk is a prompt's rung, attended through the flash
+            # kernel (the caller's word: one token a step, rungs the
+            # kernel tiles): no score exists beyond the kernel's tile.
+            # Only later ticks read the pages this writes, and those of
+            # a window layer that keeps its own see its last `window`
             # positions: the rest is not written.
             from kubeflow_tpu.ops.attention import attention
 
@@ -397,12 +403,37 @@ class Attention(nn.Module):
             for pool, new in ((ck, k_w), (cv, v_w)):
                 pool.value = pool.value.at[pages, offs].set(
                     new[:, lq - keep:].reshape(b * keep, hkv, hd))
+            attend = functools.partial(
+                attention, causal=True, impl=cfg.attention_impl,
+                block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
+                window=window)
             real = None if pad_len is None else (
                 pos_q >= pad_len[:, None]).astype(jnp.int32)
-            return attention(
-                q, k_w, v_w, causal=True, impl=cfg.attention_impl,
-                segment_ids=real, block_q=cfg.flash_block_q,
-                block_k=cfg.flash_block_k, window=window)
+
+            def own():
+                # nothing real lies before the rung: its own q, k, v, the
+                # left padding a segment of its own
+                return attend(q, k_w, v_w, segment_ids=real)
+
+            if not hit_below:
+                return own()
+            pools = ((ck.value, k_w), (cv.value, v_w))
+
+            def behind():
+                # a prefix hit lies before the rung: the keys are the
+                # slot's pages of positions [0, hit_below) and then the
+                # rung's own, the queries end-aligned on them
+                prior = page_table[:, :-(-hit_below // PS)]
+                k_all, v_all = (
+                    jnp.concatenate([pool[prior].reshape(
+                        b, -1, hkv, hd)[:, :hit_below], new], axis=1)
+                    for pool, new in pools)
+                kv_real = (jnp.arange(hit_below + lq)[None, :]
+                           >= pad_len[:, None]).astype(jnp.int32)
+                return attend(q, k_all, v_all, segment_ids=real,
+                              kv_segment_ids=kv_real)
+
+            return jax.lax.cond(jnp.all(pad_len >= hit_below), own, behind)
         # ---- write the chunk, THEN attend ----
         flat = pos_q.reshape(-1)                       # [b*lq] positions
         rows = jnp.repeat(jnp.arange(b, dtype=jnp.int32), lq)
@@ -611,7 +642,7 @@ class Attention(nn.Module):
     @nn.compact
     def __call__(self, x, positions, segment_ids=None, decode_index=None,
                  pad_len=None, page_table=None, block_step=False,
-                 fresh=False):
+                 fresh=False, hit_below=0):
         cfg = self.cfg
         window = self.window
         init = nn.initializers.normal(0.02)
@@ -660,7 +691,8 @@ class Attention(nn.Module):
             # falls through to the SHARED output projection below, like
             # the rolling path — 'o' must stay single-sited
             out = self._decode_paged(q, k, v, decode_index, pad_len,
-                                     page_table, block_step, fresh)
+                                     page_table, block_step, fresh,
+                                     hit_below)
         elif decode_index is not None and cfg.rolling_kv_cache:
             if not window:
                 raise ValueError(
@@ -930,7 +962,7 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, positions, segment_ids=None, decode_index=None,
                  pad_len=None, page_table=None, block_step=False,
-                 live=None, fresh=False):
+                 live=None, fresh=False, hit_below=0):
         cfg = self.cfg
         norm = functools.partial(RMSNorm, cfg.norm_eps, cfg.dtype)
         # "block_norm" anchors both norm outputs: they are the weight-grad
@@ -940,7 +972,7 @@ class Block(nn.Module):
         ln1 = checkpoint_name(norm(name="ln_attn")(x), "block_norm")
         attn_out = Attention(cfg, self.layer, name="attn")(
             ln1, positions, segment_ids, decode_index, pad_len, page_table,
-            block_step, fresh)
+            block_step, fresh, hit_below)
         if cfg.sandwich_norm:
             attn_out = norm(name="ln_attn_out")(attn_out)
         x = x + attn_out
@@ -989,13 +1021,18 @@ class TransformerLM(nn.Module):
     @nn.compact
     def __call__(self, tokens, train: bool = True, segment_ids=None,
                  decode_index=None, pad_len=None, page_table=None,
-                 return_hidden=False, block_step=False, fresh=False):
+                 return_hidden=False, block_step=False, fresh=False,
+                 hit_below=0):
         """`page_table`: [B, MP], or where pages are kept by layer kind
         (cfg.kv_window_pages) the pair (held kind's, window kind's), of
         which each layer reads its own. `fresh`: the paged chunk is a
-        whole prompt with nothing real before it; its attention is over
-        its own q, k, v (`Attention._decode_paged`), and the logits are
-        the last position's alone, [B, 1, V]."""
+        prompt's rung, attended through the flash kernel
+        (`Attention._decode_paged`), and the logits are the last
+        position's alone, [B, 1, V]. Its keys are its own q, k, v where
+        nothing real lies before it; `hit_below` (static: the rung's
+        first position, where the decoder's prefix cache is on) adds the
+        one case that is not so, a prefix hit's pages before the rung,
+        told in the program by `pad_len` < `hit_below`."""
         cfg = self.cfg
         del train  # no dropout in the speed-run configuration
         specs = cfg.layers()
@@ -1044,7 +1081,7 @@ class TransformerLM(nn.Module):
                 of_window = bool(spec.window and cfg.kv_window_pages)
                 x = Block(cfg, spec, name=f"layer_{i}")(
                     x, positions, None, decode_index, pad_len,
-                    tables[of_window], block_step, live, fresh)
+                    tables[of_window], block_step, live, fresh, hit_below)
             if fresh:
                 x = x[:, -1:]
             return LMHead(cfg, name="lm_head")(norm_f(x))

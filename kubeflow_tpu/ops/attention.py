@@ -46,11 +46,14 @@ def reference_attention(
     causal: bool = True,
     scale: float | None = None,
     segment_ids: jax.Array | None = None,
+    kv_segment_ids: jax.Array | None = None,
     window: int = 0,
 ) -> jax.Array:
     """XLA attention in f32 accumulation. BLHD in, BLHD out.
     window > 0 = sliding-window: query i attends keys in
-    (i - window, i] (end-aligned like the causal mask)."""
+    (i - window, i] (end-aligned like the causal mask).
+    kv_segment_ids: the keys' ids where there are more keys than
+    queries; the queries' own by default."""
     b, lq, h, d = q.shape
     lk = k.shape[1]
     scale = scale if scale is not None else d ** -0.5
@@ -67,7 +70,8 @@ def reference_attention(
         near = qpos - kpos < window
         logits = jnp.where(near[None, None], logits, -1e30)
     if segment_ids is not None:
-        seg_mask = segment_ids[:, :, None] == segment_ids[:, None, :]
+        kseg = segment_ids if kv_segment_ids is None else kv_segment_ids
+        seg_mask = segment_ids[:, :, None] == kseg[:, None, :]
         logits = jnp.where(seg_mask[:, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
@@ -126,6 +130,7 @@ def local_attention(
     block_q: int = 0,
     block_k: int = 0,
     window: int = 0,
+    kv_segment_ids: jax.Array | None = None,
 ) -> jax.Array:
     """Attention over arrays that live on ONE device (or inside a
     shard_map body). impl: flash | reference — `auto` is settled by the
@@ -133,11 +138,14 @@ def local_attention(
 
     segment_ids (sequence-packing masks) run through the Pallas kernel
     too — the reference path's [B, H, L, L] scores are unusable at
-    training lengths.
+    training lengths. More keys than queries (a prompt's rung behind the
+    pages of a prefix hit) are end-aligned, and bring `kv_segment_ids`.
     """
     if impl == "reference":
         return reference_attention(q, k, v, causal=causal,
-                                   segment_ids=segment_ids, window=window)
+                                   segment_ids=segment_ids,
+                                   kv_segment_ids=kv_segment_ids,
+                                   window=window)
     if impl != "flash":
         raise ValueError(f"unknown attention impl {impl!r} (flash|reference; "
                          "auto is for attention() or the model config)")
@@ -158,7 +166,8 @@ def local_attention(
                                        DEFAULT_BLOCK_K))
     return flash_attention(q, k, v, causal=causal,
                            block_q=bq, block_k=bk,
-                           segment_ids=segment_ids, window=window)
+                           segment_ids=segment_ids,
+                           kv_segment_ids=kv_segment_ids, window=window)
 
 
 def attention(
@@ -172,6 +181,7 @@ def attention(
     block_q: int = 0,
     block_k: int = 0,
     window: int = 0,
+    kv_segment_ids: jax.Array | None = None,
 ) -> jax.Array:
     """Dispatching attention for the model. impl: auto | flash | reference.
 
@@ -191,8 +201,12 @@ def attention(
     local = functools.partial(local_attention, causal=causal, impl=impl,
                               block_q=block_q, block_k=block_k, window=window)
     mesh = current_mesh()
+    # the ids that were given, under local_attention's names for them
+    segs = {name: ids for name, ids in (("segment_ids", segment_ids),
+                                        ("kv_segment_ids", kv_segment_ids))
+            if ids is not None}
     if impl != "flash" or mesh is None or mesh.size == 1:
-        return local(q, k, v, segment_ids=segment_ids)
+        return local(q, k, v, **segs)
     head_axis = mesh_head_axis(mesh, q.shape[2])
     if head_axis and k.shape[2] % mesh.shape[AXIS_MODEL]:
         # GQA with fewer KV heads than `model` is wide: repeat them up
@@ -200,14 +214,11 @@ def attention(
         k = _repeat_kv(k, q.shape[2])
         v = _repeat_kv(v, q.shape[2])
     qkv_spec = P(BATCH_AXES, None, head_axis, None)
-    args, in_specs = (q, k, v), (qkv_spec,) * 3
-    if segment_ids is not None:
-        args, in_specs = args + (segment_ids,), in_specs + (P(BATCH_AXES, None),)
+    in_specs = (qkv_spec,) * 3 + (P(BATCH_AXES, None),) * len(segs)
 
     @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=qkv_spec, check_vma=False)
-    def _sharded(q_blk, k_blk, v_blk, *maybe_seg):
-        return local(q_blk, k_blk, v_blk,
-                     segment_ids=maybe_seg[0] if maybe_seg else None)
+    def _sharded(q_blk, k_blk, v_blk, *ids):
+        return local(q_blk, k_blk, v_blk, **dict(zip(segs, ids)))
 
-    return _sharded(*args)
+    return _sharded(q, k, v, *segs.values())

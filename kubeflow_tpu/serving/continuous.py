@@ -41,11 +41,17 @@ reason, and no option (`window_pages_for`, `_fresh_prefill_rule`):
   `pages` phase, the pages that have fallen behind the slot's window. With
   the prefix cache on, a prompt page may be handed to a later request
   whole, so every layer holds its pages for the slot's life, as ever.
-- **The prompt's attention through the flash kernel**: a decoder without
-  a prefix cache computes every real token of a prompt in the one rung,
-  so a causal one-token model whose attention is the flash kernel's
-  attends over the rung's own keys and writes the pages for the ticks
-  only; no score exists beyond a tile. Every other decoder gathers.
+- **The prompt's attention through the flash kernel**: a causal
+  one-token model whose attention is the flash kernel's, at rungs the
+  kernel tiles, attends over the rung's own keys and writes the pages
+  for the ticks; no score exists beyond a tile. Without a prefix cache
+  that is the whole of it: every real token of a prompt is in the one
+  rung. With it a rung may start behind a hit, and where one does
+  (`pad_len` below the rung's first position, which the program sees)
+  the keys are the slot's pages before the rung and then the rung's own,
+  in the same program. Every other decoder (a block model, a draft's
+  verify chunk, another backend's attention, a rung that is no multiple
+  of 128) gathers the slot's pages and masks: the reference.
 
 What a round does is its **kind of step** (serving/steps.py): one token
 a slot; a speculative chunk; or, for a model that generates by diffusion
@@ -123,19 +129,20 @@ def window_pages_for(cfg, slots: int, prompt_len: int, max_new_tokens: int,
 
 def _fresh_prefill_rule(cfg, prompt_len: int, *, prefix_cache: bool,
                         draft: bool) -> tuple:
-    """(whether a paged prefill attends over the rung's own keys, why):
+    """(whether a paged prefill attends through the flash kernel, why):
     the second rule of the module docstring."""
     from kubeflow_tpu.runtime.kvcache import prefill_ladder
 
     ladder = prefill_ladder(prompt_len, cfg.kv_page_size)
-    if prefix_cache:
-        return False, "the prefix cache is on: a rung may start behind a hit"
     if cfg.gen_block or draft:
         return False, "a block model or a draft: no causal one-token prefill"
     if cfg.attention_impl != "flash":
         return False, f"attention impl is {cfg.attention_impl!r}, not flash"
     if any(n % 128 for n in ladder):
         return False, f"a rung of {list(ladder)} is no multiple of 128"
+    if prefix_cache:
+        return True, ("causal, flash attention; the prefix cache is on: the "
+                      "pages before a rung where a hit lies there")
     return True, "prefix cache off, causal, flash attention"
 
 
@@ -419,8 +426,8 @@ class SlotDecoder:
             self._fresh, why = _fresh_prefill_rule(
                 cfg, prompt_len, prefix_cache=prefix_cache, draft=self.spec)
             log.info("paged prefill: %s (%s)",
-                     "the rung's own attention" if self._fresh
-                     else "gathers the slot's pages", why)
+                     "the rung's keys through the flash kernel"
+                     if self._fresh else "gathers the slot's pages", why)
         else:
             self.alloc = None
             self._fresh = False
@@ -434,6 +441,10 @@ class SlotDecoder:
             # real tokens (prompt_len less the padding), and prompt_len
             "prefill_tokens_computed": 0, "prompt_tokens_real": 0,
             "prompt_tokens_submitted": 0,
+            # admissions whose prompt attended through the flash kernel
+            # (`_fresh_prefill_rule`: all of a decoder's or none), and
+            # those of them that read a prefix hit's pages before the rung
+            "prefill_flash": 0, "prefill_behind_hit": 0,
             "spec_rounds": 0, "spec_tokens_emitted": 0,
             "spec_tokens_accepted": 0, "spec_drafted": 0,
             "deadline_canceled": 0,
@@ -479,7 +490,8 @@ class SlotDecoder:
         else:
             self.step = steps.TokenStep(
                 model, self._params, *geometry, temperature=temperature,
-                top_k=top_k, seed=seed, fresh_prefill=self._fresh)
+                top_k=top_k, seed=seed, fresh_prefill=self._fresh,
+                prefix_hits=prefix_cache)
             self._counters.update(dict.fromkeys(self.step.counted, 0))
         if self.paged:
             t0 = _stamp()
@@ -705,7 +717,7 @@ class SlotDecoder:
         return True
 
     def _note_admitted(self, owners: dict, r: _Request, slot: int,
-                       prefill_tokens: int, first, hits=None) -> None:
+                       prefill_tokens: int, first, plan=None) -> None:
         """Admission's bookkeeping, once the request's prefill has been
         dispatched and the slot has its owner: the stamp, the counters. Where the
         install left a first token on the device (`first`: a speculative
@@ -722,10 +734,14 @@ class SlotDecoder:
             c["prompt_tokens_real"] += self.P - r.pad
             c["prompt_tokens_submitted"] += self.P
             self._prefill_lengths.add(prefill_tokens)
+            if self._fresh:
+                c["prefill_flash"] += 1
+                # what the program's own test says (`_decode_paged`)
+                c["prefill_behind_hit"] += r.pad < plan.compute_start
             if self.meter:
                 self.meter.prefill_tokens(prefill_tokens)
-                if hits is not None:
-                    self.meter.prefix_hits(hits)
+                if plan is not None:
+                    self.meter.prefix_hits(plan.shared_pages)
             if first is None:
                 self._publish_pages()
                 return
@@ -908,8 +924,9 @@ class SlotDecoder:
                     continue
                 # (the allocator reads the row's real pages only)
                 row, total = r.prompt, step.end(r)
-                # a prefill that attends over its own keys leaves the
-                # pages to the ticks, the first of which is at prompt_len
+                # (the window kind, so no prefix cache:) a prefill that
+                # attends over its own keys leaves the pages to the
+                # ticks, the first of which is at prompt_len
                 reads_from = self.P if self._fresh else None
                 if not self.alloc.can_admit(row, r.pad, total, reads_from):
                     # head-of-line page gate: FIFO order is preserved (no
@@ -940,6 +957,5 @@ class SlotDecoder:
                 fail_all(e, [r])
                 return
             owners[slot] = r    # whose completion or cancel frees the pages
-            self._note_admitted(owners, r, slot, suffix, first,
-                                plan.shared_pages)
+            self._note_admitted(owners, r, slot, suffix, first, plan)
             admitted += slot in owners

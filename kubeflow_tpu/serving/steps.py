@@ -61,8 +61,12 @@ class TokenStep:
     with mixture layers adds a ninth, the ticks' MOE_COUNTERS since the
     last dispatch. Where the model keeps pages by layer kind
     (cfg.kv_window_pages) a page table is the pair (held kind's, window
-    kind's). `fresh_prefill`: the paged prefill attends over the rung's
-    own keys (the decoder's rule, serving/continuous.py)."""
+    kind's). `fresh_prefill`: the paged prefill attends through the flash
+    kernel (the decoder's rule, serving/continuous.py) over the rung's
+    own keys, and where the decoder's prefix cache is on (`prefix_hits`)
+    behind the pages before the rung if a hit lies there: both cases in
+    the rung's one program, told apart by `pad` against the rung's first
+    position."""
 
     # -- FUSE ticks in one dispatched program. Each
     #    dispatch costs a host round-trip (launch, the readback of
@@ -90,7 +94,7 @@ class TokenStep:
     def __init__(self, model, params, slots: int, prompt_len: int,
                  max_new_tokens: int, pages_per_row: int = 0, *,
                  temperature: float = 0.0, top_k: int = 0, seed: int = 0,
-                 fresh_prefill: bool = False):
+                 fresh_prefill: bool = False, prefix_hits: bool = False):
         self.model, self.params = model, params
         self.S, self.P, self.N = slots, prompt_len, max_new_tokens
         # a paged cache's table row, in pages; 0: the dense slot cache
@@ -107,7 +111,7 @@ class TokenStep:
         # the mixture's counters ride the state of a one-token model too
         self.counted = (self._tick_counters
                         if any(s.moe for s in specs) else ())
-        self._fresh_kw = {"fresh": True} if fresh_prefill else {}
+        self._fresh_prefill, self._prefix_hits = fresh_prefill, prefix_hits
         self.temperature, self.top_k, self.seed = temperature, top_k, seed
         # the most tokens a slot can finish in a fused round
         self.fuse_tokens = self.FUSE
@@ -181,10 +185,16 @@ class TokenStep:
         def _paged_prefill_install(params, state, toks, start, pt_row,
                                    pad, slot, req_n, *block):
             cache, last, pos, remaining, out, pads, req, rng, *more = state
+            fresh = {}
+            if self._fresh_prefill:
+                fresh["fresh"] = True
+                # a rung ends at prompt_len: where it starts is static
+                if self._prefix_hits and toks.shape[1] < self.P:
+                    fresh["hit_below"] = self.P - toks.shape[1]
             logits, mut = model.apply(
                 params | {"cache": cache}, toks, train=False,
                 decode_index=start, mutable=["cache"], pad_len=pad,
-                page_table=pt_row, **self._fresh_kw)
+                page_table=pt_row, **fresh)
             cache = mut["cache"]
             last, first_pos = self._open(last, logits, slot, *block)
             pos = _set1(pos, slot, first_pos)

@@ -390,6 +390,49 @@ def test_flash_prefill_through_the_decoder():
         dec.close()
 
 
+# sha256 of `jax.make_jaxpr` of a two-kind model's paged prefill-install
+# on the own-keys path (prefix cache off) at the 128 and the 512 rung of
+# 512, addresses struck out, at commit 24075f6: the parent of the PR that
+# gave the prefix cache its case inside the flash path. With the prefix
+# cache off `longctx-saturated` compiles what it compiled.
+FRESH_PREFILL_JAXPRS_AT_PARENT = {
+    128: "123d2e58c582ac0aa0b83ddeae1971ed478cc9448e76c0561c7d9491d63d4afc",
+    512: "c74da6480aee7339d55e2dec0e7491ec7f80eb21f99b79e6021899e8f1056637",
+}
+
+
+@pytest.mark.parametrize("rung", list(FRESH_PREFILL_JAXPRS_AT_PARENT))
+def test_the_fresh_prefill_without_a_prefix_cache_is_what_it_was(rung):
+    import dataclasses
+    import hashlib
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from kubeflow_tpu.runtime.kvcache import pages_for
+    from kubeflow_tpu.serving import steps
+    from kubeflow_tpu.serving.continuous import window_pages_for
+
+    a, d = arch()
+    p, n = 512, 16
+    model = toy_model(kv_pages=2 * 140 + 1, kv_page_size=PAGE,
+                      attention_impl="flash", max_seq_len=p + n)
+    model = model.clone(cfg=dataclasses.replace(
+        model.cfg, kv_window_pages=window_pages_for(
+            model.cfg, 2, p, n, prefix_cache=False)))
+    params = {"params": a.make_program_params(d, SEED)}
+    mp = pages_for(p + n, PAGE)
+    step = steps.TokenStep(model, params, 2, p, n, mp, fresh_prefill=True)
+    row = jnp.zeros((1, mp), jnp.int32)
+    text = str(jax.make_jaxpr(step._paged_prefill_install)(
+        params, step.state, jnp.zeros((1, rung), jnp.int32),
+        jnp.zeros((1,), jnp.int32), (row, row), jnp.zeros((1,), jnp.int32),
+        jnp.int32(0), jnp.int32(1)))
+    assert hashlib.sha256(re.sub(r"0x[0-9a-f]+", "0x", text).encode()) \
+        .hexdigest() == FRESH_PREFILL_JAXPRS_AT_PARENT[rung]
+
+
 # -- the rules and the refusals ------------------------------------------------------
 
 def test_which_layers_release_is_a_rule_over_what_is_there():

@@ -420,6 +420,8 @@ STATS_KEYS = {
     "first_token_s_sum", "first_tokens", "mode", "peak_active",
     "phase_s.admit", "phase_s.complete", "phase_s.idle", "phase_s.pages",
     "phase_s.prefill", "phase_s.readback", "phase_s.tick",
+    # (the two counters of the flash prefill came with it)
+    "prefill_behind_hit", "prefill_flash",
     "prefill_tokens_computed", "prompt_tokens_real",
     "prompt_tokens_submitted", "queue_wait_s_sum", "rounds", "spec_drafted",
     "spec_rounds", "spec_tokens_accepted", "spec_tokens_emitted",

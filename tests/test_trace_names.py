@@ -213,3 +213,78 @@ def test_every_name_a_metric_reads_is_one_the_program_gives(
 
 def test_the_metric_files_do_name_something():
     assert len(patterns()) >= 8
+
+
+def test_the_flash_custom_calls_are_in_the_dense_paged_prefill():
+    """Where the decoder's rule says flash (`_fresh_prefill_rule`), the
+    paged prefill of a dense model calls the nested jit whose name the
+    flash kernels carry in the device trace, in both of a rung's cases;
+    the gather does not."""
+    import jax
+    import jax.numpy as jnp
+
+    from kubeflow_tpu.models.registry import get_model
+    from kubeflow_tpu.ops.attention import local_attention
+    from kubeflow_tpu.serving import steps
+
+    model = get_model("transformer-test", vocab_size=64, max_seq_len=264,
+                      kv_pages=34, kv_page_size=8, attention_impl="flash")
+    variables = model.init(jax.random.PRNGKey(0), np.zeros((1, 1), np.int32),
+                           train=False)
+    params = {"params": variables["params"]}
+    nested = f"@{local_attention.__wrapped__.__name__}"
+
+    def calls(rung, **kw):
+        step = steps.TokenStep(model, params, 2, 256, 8, 33, **kw)
+        shapes = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype),
+            (params, step.state, jnp.zeros((1, rung), jnp.int32),
+             jnp.zeros((1,), jnp.int32), jnp.zeros((1, 33), jnp.int32),
+             jnp.zeros((1,), jnp.int32), jnp.int32(0), jnp.int32(1)))
+        text = step._paged_prefill_install.lower(*shapes).as_text()
+        assert re.search(r"module @(\w+)", text).group(1) \
+            == "jit__paged_prefill_install"
+        return len(re.findall(rf"call {nested}\w*\(", text))
+
+    layers = model.cfg.n_layers
+    assert calls(128) == 0
+    assert calls(256, fresh_prefill=True, prefix_hits=True) == layers
+    # a rung that may start behind a hit holds both cases
+    assert calls(128, fresh_prefill=True, prefix_hits=True) == 2 * layers
+    assert calls(128, fresh_prefill=True) == layers
+
+
+@pytest.mark.parametrize("name", ["sched.prefill_flash_share.chat",
+                                  "sched.prefill_flash_share.doc"])
+def test_the_flash_share_reads_counters_the_decoder_has(name):
+    """The two counters the metric divides are keys of every decoder's
+    `stats()`, so the share is a number (0 here: the CPU's attention is
+    the reference's and the rule says gather), and None, not an error,
+    over a program that lacks them."""
+    import jax
+
+    from benchmarks.metrics import kvwalk
+    from kubeflow_tpu.models.registry import get_model
+    from kubeflow_tpu.serving.continuous import SlotDecoder
+
+    with open(os.path.join(ROOT, "benchmarks", "metrics",
+                           f"{name}.json")) as f:
+        spec = json.load(f)
+    assert spec["reader"] == "kvwalk:growth_share"
+    model = get_model("transformer-test", vocab_size=64, max_seq_len=24,
+                      kv_pages=25, kv_page_size=4)
+    variables = model.init(jax.random.PRNGKey(0), np.zeros((1, 1), np.int32),
+                           train=False)
+    dec = SlotDecoder(model, variables, slots=2, prompt_len=8,
+                      max_new_tokens=4)
+    try:
+        before = dec.stats()
+        dec.submit([1, 2, 3])
+        ctx = {"stats0": before, "stats1": dec.stats()}
+    finally:
+        dec.close()
+    assert set(spec["args"].values()) <= set(before)
+    assert kvwalk.growth_share(ctx, **spec["args"]) == 0.0
+    assert kvwalk.growth_share({"stats0": {"admitted": 0},
+                                "stats1": {"admitted": 1}},
+                               **spec["args"]) is None
