@@ -23,11 +23,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
@@ -66,6 +68,7 @@ class LayerSpec:
     window: int = 0      # sliding-window attention; 0 = full causal
     rope: bool = True    # rotary embedding on q and k; False = none at all
     moe: bool = False    # a mixture layer (ops/moe.py) or a dense SwiGLU
+    latent: bool = False  # latent attention (`LatentAttention`) or K and V heads
 
 
 @dataclasses.dataclass(frozen=True)
@@ -91,9 +94,10 @@ class TransformerConfig:
     # decode HBM traffic and int8 halves it.
     kv_cache_dtype: str = "auto"
     # Sliding-window attention (Mistral-style): keys further than
-    # window-1 positions in the past are masked; flash skips the COMPUTE
-    # of blocks left of the window (MXU work O(L * window); their DMA
-    # still runs — see ops/flash_attention.py). 0 = full causal.
+    # window-1 positions in the past are masked; flash walks a
+    # window-sized grid of key blocks, so the blocks left of the window
+    # are neither computed nor fetched (MXU work and HBM traffic
+    # O(L * window) — see ops/flash_attention.py). 0 = full causal.
     # Supported by every attention path: flash/reference/ring/ulysses
     # in training, and decode masks the cache identically (train/serve
     # parity).
@@ -187,6 +191,37 @@ class TransformerConfig:
     # of two page tables, whose pages behind the window the allocator
     # takes back while the request runs. 0: one pool size, one table.
     kv_window_pages: int = 0
+    # Latent attention (a layer with `LayerSpec.latent`; `LatentAttention`
+    # below): q through a latent of `q_lora_rank` (0 = one projection), k
+    # and v through one of `kv_lora_rank`, an RMSNorm on each latent; a
+    # head's query and key are `qk_nope_head_dim` values that carry no
+    # position beside `qk_rope_head_dim` rotated ones, the key's rotated
+    # part one for all heads; a head's value is `v_head_dim` wide.
+    # `head_dim` and `n_kv_heads` say nothing of such a layer.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN on a latent layer's rotary part (`yarn_inv_freq`): positions
+    # stretched by `rope_factor` (1 = plain rotary) beyond the
+    # `rope_original_max` the model was first trained at, dimension by
+    # dimension between those that turn `rope_beta_fast` and
+    # `rope_beta_slow` times in that many positions; cos and sin times
+    # mscale(factor, rope_mscale) / mscale(factor, rope_mscale_all_dim),
+    # the softmax scale times mscale(factor, rope_mscale_all_dim) ** 2.
+    rope_factor: float = 1.0
+    rope_original_max: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    # Group-limited routing (ops/moe.py, sigmoid scores): the experts lie
+    # in `moe_n_group` groups of consecutive ids and a token chooses inside
+    # the `moe_topk_group` groups whose two best scores sum highest.
+    # 1 / 1: no groups.
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
 
     def layers(self) -> tuple:
         """One LayerSpec a layer: the pattern, or the uniform stack."""
@@ -202,14 +237,46 @@ class TransformerConfig:
             for i in range(self.n_layers))
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary position embedding over the last dim. x: [B, L, H, D]."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's frequencies of a rotary embedding over `dim` values, [dim/2]
+    float32: theta ** (-2i / dim) where the pair turns more than
+    `beta_fast` times in `original_max` positions, that over `factor`
+    where it turns fewer than `beta_slow` times, and a linear ramp
+    between the two dimensions (floor and ceiling) at which it turns
+    exactly so often."""
+    def turns_at(rotations):
+        return (dim * math.log(original_max / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), dim - 1)
+    plain = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ramp = np.clip((np.arange(dim // 2) - low)
+                   / ((high if high != low else high + 0.001) - low), 0, 1)
+    return (plain / factor * ramp + plain * (1 - ramp)).astype(np.float32)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         inv_freq=None, mscale: float = 1.0) -> jax.Array:
+    """Rotary position embedding over the last dim. x: [B, L, H, D].
+    `inv_freq` [D/2]: other frequencies than theta's own (YaRN), cos and
+    sin times `mscale`."""
     d = x.shape[-1]
     half = d // 2
-    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if inv_freq is None:
+        freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, L, half]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
@@ -892,6 +959,193 @@ class Attention(nn.Module):
         return shard(out, HIDDEN_SPEC)
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (a layer with `LayerSpec.latent`): the
+    keys and values of every head are linear in one latent vector a
+    position, `ckv` (cfg.kv_lora_rank, RMS-normed), and the key's rotated
+    part `k_pe` (cfg.qk_rope_head_dim) is one for all heads, so the cache
+    holds `[ckv | k_pe]` a position and nothing else. One arithmetic in
+    two forms, of the same weights:
+
+    - up-projected (a sequence without a cache, and a prompt's rung whose
+      attention is the flash kernel's): `[k_nope | v]` a head = `ckv
+      W_kv_b`, `k = [k_nope | k_pe]`, attention of cfg.n_heads heads with
+      keys of qk_nope + qk_rope values and values of v_head_dim.
+    - absorbed (every read of the cache: a tick, a gathered chunk): the
+      query goes into the latent's space, `q_lat = q_nope W_kv_b[k part]`,
+      scores are `[q_lat | q_pe] . [ckv | k_pe]`, the probabilities weigh
+      `ckv` itself and `W_kv_b[v part]` takes the result out again. No
+      key or value of a head is ever made of a cached position.
+
+    The softmax scale is (qk_nope + qk_rope) ** -0.5 times YaRN's
+    mscale(factor, rope_mscale_all_dim) ** 2. Served through the paged
+    cache only (`_decode_paged`): one pool a layer of rows `[ckv | k_pe |
+    zeros]`, the row as wide as whole 128-lane tiles hold it
+    (`latent_row_width`)."""
+
+    cfg: TransformerConfig
+    layer: LayerSpec
+
+    def _up_projected(self, q_nope, q_pe, ckv, k_pe, w, scale, segment_ids):
+        """The up-projected form over a sequence's own positions: every
+        head's `[k_nope | v]` from the latent through `w` [r, heads,
+        nope + v], the rotated key part `k_pe` [b, l, rope] joined to
+        each head's, causal attention at keys of nope + rope and values
+        of v_head_dim."""
+        from kubeflow_tpu.ops.attention import attention
+
+        cfg, dn = self.cfg, self.cfg.qk_nope_head_dim
+        kv = jnp.einsum("blr,rhk->blhk", ckv, w)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(
+                k_pe[:, :, None, :], kv.shape[:3] + k_pe.shape[-1:])],
+            axis=-1)
+        return attention(
+            jnp.concatenate([q_nope, q_pe], axis=-1), k, kv[..., dn:],
+            causal=True, impl=cfg.attention_impl, segment_ids=segment_ids,
+            block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
+            scale=scale)
+
+    def _decode_paged(self, q_nope, q_pe, ckv, k_pe, w_kv_b, scale,
+                      decode_index, pad_len, page_table, fresh=False):
+        """The pool `latent_pages` [kv_pages, kv_page_size, W] behind the
+        page table that `Attention._decode_paged` describes: write the
+        chunk's rows, then attend. `fresh` (the caller's word: a prompt's
+        rung that the flash kernel tiles, nothing real before it): the
+        up-projected form over the rung's own positions; only the ticks
+        read what it writes. Every other call is the absorbed form over
+        the slot's pages: a tick on a TPU through the Pallas kernel that
+        streams the pages that hold what each slot's query sees
+        (ops/paged_latent_attention.py:use_kernel says, and logs, which),
+        anything else gathered and masked, which is the kernel's
+        reference."""
+        from kubeflow_tpu.ops import paged_latent_attention as pla
+
+        cfg = self.cfg
+        b, lq, heads = q_nope.shape[:3]
+        dn, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+        PS = cfg.kv_page_size
+        W = latent_row_width(cfg)
+        pool = self.variable(
+            "cache", "latent_pages",
+            lambda: jnp.zeros((cfg.kv_pages, PS, W), cfg.dtype))
+        idx = jnp.asarray(decode_index, jnp.int32)
+        if idx.ndim == 0:
+            idx = jnp.full((b,), idx, jnp.int32)
+        pos_q = idx[:, None] + jnp.arange(lq, dtype=jnp.int32)[None, :]
+        row = jnp.concatenate([ckv, k_pe], axis=-1).astype(cfg.dtype)
+        row = jnp.pad(row, ((0, 0), (0, 0), (0, W - row.shape[-1])))
+        flat = pos_q.reshape(-1)
+        slot = jnp.repeat(jnp.arange(b, dtype=jnp.int32), lq)
+        pool.value = pool.value.at[page_table[slot, flat // PS],
+                                   flat % PS].set(row.reshape(b * lq, W))
+        w = w_kv_b.astype(cfg.dtype)
+        if fresh:
+            real = None if pad_len is None else (
+                pos_q >= pad_len[:, None]).astype(jnp.int32)
+            return self._up_projected(q_nope, q_pe, ckv, k_pe, w, scale, real)
+        q_abs = jnp.concatenate(
+            [jnp.einsum("blhn,rhn->blhr", q_nope, w[..., :dn]), q_pe],
+            axis=-1)
+        q_abs = jnp.pad(q_abs, ((0, 0),) * 3 + ((0, W - q_abs.shape[-1]),))
+        if pla.use_kernel(lq, pool.value.shape, pool.value.dtype):
+            last = pos_q[:, -1]
+            start = jnp.zeros_like(last) if pad_len is None else pad_len
+            o_lat = pla.paged_latent_attention(
+                q_abs[:, 0], pool.value, page_table, start, last,
+                scale=scale, rank=r)[:, None]
+        else:
+            rows = pool.value[page_table].reshape(b, -1, W)
+            logits = jnp.einsum("bqhw,bsw->bhqs", q_abs, rows,
+                                preferred_element_type=jnp.float32) * scale
+            pos = jnp.arange(rows.shape[1])[None, None, None, :]
+            mask = pos <= pos_q[:, None, :, None]
+            if pad_len is not None:
+                mask = mask & (pos >= pad_len[:, None, None, None])
+            probs = jax.nn.softmax(jnp.where(mask, logits, -1e30), axis=-1)
+            o_lat = jnp.einsum("bhqs,bsr->bqhr", probs.astype(cfg.dtype),
+                               rows[..., :r])
+        return jnp.einsum("blhr,rhv->blhv", o_lat, w[..., dn:])
+
+    @nn.compact
+    def __call__(self, x, positions, segment_ids=None, decode_index=None,
+                 pad_len=None, page_table=None, block_step=False,
+                 fresh=False, hit_below=0):
+        cfg = self.cfg
+        heads, r = cfg.n_heads, cfg.kv_lora_rank
+        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        init = nn.initializers.normal(0.02)
+        norm = functools.partial(RMSNorm, cfg.norm_eps, cfg.dtype)
+        dense = lambda feats, names, name: nn.DenseGeneral(  # noqa: E731
+            feats, axis=-1, use_bias=False, dtype=cfg.dtype,
+            kernel_init=_part(init, names), name=name)
+        if cfg.q_lora_rank:
+            cq = norm(name="q_a_norm")(
+                dense(cfg.q_lora_rank, (AXIS_FSDP, None), "q_a")(x))
+            q = dense((heads, dn + dr), (None, AXIS_MODEL, None), "q_b")(cq)
+        else:
+            q = dense((heads, dn + dr), (AXIS_FSDP, AXIS_MODEL, None), "q")(x)
+        kv_a = dense(r + dr, (AXIS_FSDP, None), "kv_a")(x)
+        ckv = norm(name="kv_a_norm")(kv_a[..., :r])
+        q_nope, q_pe, k_pe = q[..., :dn], q[..., dn:], kv_a[..., None, r:]
+        scale = (dn + dr) ** -0.5
+        if self.layer.rope:
+            inv_freq, mscale = None, 1.0
+            if cfg.rope_factor > 1:
+                inv_freq = yarn_inv_freq(
+                    dr, cfg.rope_theta, cfg.rope_factor,
+                    cfg.rope_original_max, cfg.rope_beta_fast,
+                    cfg.rope_beta_slow)
+                all_dim = yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+                mscale = yarn_mscale(cfg.rope_factor, cfg.rope_mscale) / all_dim
+                scale *= all_dim * all_dim
+            q_pe = rope(q_pe, positions, cfg.rope_theta, inv_freq, mscale)
+            k_pe = rope(k_pe, positions, cfg.rope_theta, inv_freq, mscale)
+        k_pe = k_pe[..., 0, :]
+        # [k_nope | v] of every head from the latent: the rung's form
+        # multiplies by it whole, the absorbed form by its two parts
+        w_kv_b = self.param(
+            "kv_b", _part(init, (None, AXIS_MODEL, None)),
+            (r, heads, dn + dv), jnp.float32)
+        if decode_index is not None:
+            if page_table is None or not (cfg.kv_pages and cfg.kv_page_size):
+                raise ValueError(
+                    "latent attention is served through the paged KV cache "
+                    "only (build the model with kv_pages and kv_page_size): "
+                    "no dense slot cache of latents is there")
+            if cfg.rolling_kv_cache or cfg.kv_cache_dtype != "auto":
+                raise ValueError(
+                    "latent attention keeps one pool of latents in the "
+                    "model's dtype: no rolling_kv_cache, no int8 cache")
+            if block_step or cfg.gen_block or hit_below:
+                raise ValueError(
+                    "latent attention serves one token a step, and a "
+                    "prompt's rung over its own positions: no block step, "
+                    "no prefix hit's pages behind a flash rung")
+            out = self._decode_paged(q_nope, q_pe, ckv, k_pe, w_kv_b, scale,
+                                     decode_index, pad_len, page_table, fresh)
+        else:
+            out = self._up_projected(q_nope, q_pe, ckv, k_pe,
+                                     w_kv_b.astype(cfg.dtype), scale,
+                                     segment_ids)
+        out = checkpoint_name(out, "attn_ctx")
+        out = nn.DenseGeneral(
+            x.shape[-1], axis=(-2, -1), use_bias=False, dtype=cfg.dtype,
+            kernel_init=_part(init, (AXIS_MODEL, None, AXIS_FSDP)),
+            name="o")(out)
+        return shard(out, HIDDEN_SPEC)
+
+
+def latent_row_width(cfg: TransformerConfig) -> int:
+    """Values a position takes in a latent layer's pool: the latent and
+    the key's rotated part, in whole tiles of 128 lanes (512 + 64 -> 640:
+    the kernel fetches a page in one piece and the MXU takes whole
+    tiles; what that costs is `stats()["kv_latent_row_bytes"]` against
+    the 2 x 576 the model needs)."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+
+
 class SwiGLU(nn.Module):
     cfg: TransformerConfig
 
@@ -970,7 +1224,9 @@ class Block(nn.Module):
         # bf16 tensors (instead of the f32 RMSNorm internals a blacklist
         # policy keeps) is what lets the "slim" replay skip the norms.
         ln1 = checkpoint_name(norm(name="ln_attn")(x), "block_norm")
-        attn_out = Attention(cfg, self.layer, name="attn")(
+        attn = (LatentAttention if self.layer is not None
+                and self.layer.latent else Attention)
+        attn_out = attn(cfg, self.layer, name="attn")(
             ln1, positions, segment_ids, decode_index, pad_len, page_table,
             block_step, fresh, hit_below)
         if cfg.sandwich_norm:
@@ -1150,8 +1406,19 @@ class TransformerLM(nn.Module):
         specs = cfg.layers()
         n_moe = sum(s.moe for s in specs)
         n_dense = cfg.n_layers - n_moe
+        n_latent = sum(s.latent for s in specs)
         if cfg.attn_gate:
             attn += cfg.d_model * cfg.head_dim * cfg.n_heads
+        # a latent layer: q (through its latent, or whole), the kv latent
+        # and its up-projection, o
+        qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        latent = (
+            (cfg.d_model * cfg.q_lora_rank + cfg.q_lora_rank * cfg.n_heads * qk
+             if cfg.q_lora_rank else cfg.d_model * cfg.n_heads * qk)
+            + cfg.d_model * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+            + cfg.kv_lora_rank * cfg.n_heads
+            * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+            + cfg.n_heads * cfg.v_head_dim * cfg.d_model)
         # MoE layer: top_k expert MLPs (of the experts' own width) execute
         # per token (a layer that holds a share computes its share of
         # them), plus the router and the shared experts
@@ -1160,16 +1427,21 @@ class TransformerLM(nn.Module):
         moe = (cfg.expert_top_k * expert * cfg.n_experts / total
                + cfg.d_model * total + cfg.moe_shared_experts * expert)
         head = cfg.vocab_size * cfg.d_model
-        flops = 6.0 * (cfg.n_layers * attn + n_dense * mlp + n_moe * moe
-                       + head)
+        flops = 6.0 * ((cfg.n_layers - n_latent) * attn + n_latent * latent
+                       + n_dense * mlp + n_moe * moe + head)
         if seq_len:
             seen = 0.0
-            for w in (s.window for s in specs):
+            for s in specs:
+                w = s.window
                 if w and seq_len > w:   # the first w queries see 1..w keys
-                    seen += w * (w + 1) / 2 + (seq_len - w) * w
+                    keys = w * (w + 1) / 2 + (seq_len - w) * w
                 else:
-                    seen += seq_len * (seq_len + 1) / 2
-            flops += 12.0 * cfg.n_heads * cfg.head_dim * seen / seq_len
+                    keys = seq_len * (seq_len + 1) / 2
+                # QK^T over the key's size and PV over the value's, which
+                # a latent layer's up-projected form has apart
+                seen += keys * ((qk + cfg.v_head_dim) / 2 if s.latent
+                                else cfg.head_dim)
+            flops += 12.0 * cfg.n_heads * seen / seq_len
         return flops
 
 
@@ -1187,12 +1459,31 @@ def _build(name: str, **overrides):
             LayerSpec(**s) if isinstance(s, dict) else s
             for s in cfg_kw["layer_pattern"])
     cfg = TransformerConfig(**cfg_kw)
+    specs = cfg.layers()
+    latent = [s for s in specs if s.latent]
+    if latent and not (cfg.kv_lora_rank and cfg.qk_nope_head_dim
+                       and cfg.qk_rope_head_dim and cfg.v_head_dim):
+        raise ValueError(
+            "a latent layer needs kv_lora_rank, qk_nope_head_dim, "
+            "qk_rope_head_dim and v_head_dim")
+    if any(s.window for s in latent):
+        raise ValueError("a latent layer with a window is not there")
+    if cfg.rope_factor != 1 and not latent:
+        raise ValueError("rope_factor (YaRN) is a latent layer's: the "
+                         "layers of K and V heads rotate by theta alone")
+    if cfg.rope_factor > 1 and not cfg.rope_original_max:
+        raise ValueError("rope_factor needs rope_original_max")
     # "auto" is settled here, once per model and in the log, not layer
-    # by layer at trace time
+    # by layer at trace time: over every size of head the stack attends with
     from kubeflow_tpu.ops.attention import resolve_impl
 
-    cfg = dataclasses.replace(cfg, attention_impl=resolve_impl(
-        cfg.attention_impl, cfg.head_dim, who=name))
+    sizes = ([(cfg.head_dim, cfg.head_dim)] * (len(latent) < len(specs))
+             + [(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim,
+                 cfg.v_head_dim)] * bool(latent))
+    impl = cfg.attention_impl
+    for qk_dim, v_dim in sizes:
+        impl = resolve_impl(impl, qk_dim, who=name, v_dim=v_dim)
+    cfg = dataclasses.replace(cfg, attention_impl=impl)
     return TransformerLM(cfg)
 
 

@@ -78,21 +78,35 @@ def reference_attention(
 
 
 FLASH_HEAD_DIMS = (64, 128, 256)
+# (key size, value size) of heads whose values are narrower than their
+# keys, which the forward kernel takes as they are (latent attention's
+# up-projected form); forward only
+FLASH_HEAD_PAIRS = ((192, 128),)
 
 
-def resolve_impl(impl: str, head_dim: int, who: str = "attention") -> str:
+def resolve_impl(impl: str, head_dim: int, who: str = "attention",
+                 v_dim: int | None = None) -> str:
     """Turn ``auto`` into ``flash`` or ``reference`` and log which, with
     the reason: the flash kernel on a TPU backend for the head sizes it
-    tiles, the reference path (whose [B, H, L, L] float32 scores do not
-    fit at training lengths) otherwise. That is the whole rule: a
-    sequence length the kernel cannot tile raises in `flash_attention`
-    rather than quietly taking the reference path. Other values pass
+    tiles (`v_dim`: the values' size where it is not the keys'), the
+    reference path (whose [B, H, L, L] float32 scores do not fit at
+    training lengths) otherwise. That is the whole rule: a sequence
+    length the kernel cannot tile raises in `flash_attention` rather
+    than quietly taking the reference path. Other values pass
     through."""
     if impl != "auto":
         return impl
     backend = jax.default_backend()
+    pair = v_dim not in (None, head_dim)
     if backend != "tpu":
         choice, why = "reference", f"default backend is {backend!r}, not tpu"
+    elif pair and (head_dim, v_dim) not in FLASH_HEAD_PAIRS:
+        choice, why = "reference", (
+            f"keys of {head_dim} and values of {v_dim} not in "
+            f"{FLASH_HEAD_PAIRS}")
+    elif pair:
+        choice, why = "flash", (
+            f"tpu backend, keys of {head_dim} and values of {v_dim}")
     elif head_dim not in FLASH_HEAD_DIMS:
         choice, why = "reference", (
             f"head_dim {head_dim} not in {FLASH_HEAD_DIMS}")
@@ -118,7 +132,7 @@ def mesh_head_axis(mesh: Mesh, n_heads: int) -> str | None:
 # it (the instruction becomes `%<name>.N`) and silence the metric.
 @functools.partial(jax.jit,
                    static_argnames=("causal", "impl", "block_q", "block_k",
-                                    "window"))
+                                    "window", "scale"))
 def local_attention(
     q: jax.Array,
     k: jax.Array,
@@ -131,10 +145,12 @@ def local_attention(
     block_k: int = 0,
     window: int = 0,
     kv_segment_ids: jax.Array | None = None,
+    scale: float | None = None,
 ) -> jax.Array:
     """Attention over arrays that live on ONE device (or inside a
     shard_map body). impl: flash | reference — `auto` is settled by the
-    caller (`resolve_impl`), before any shard_map is entered.
+    caller (`resolve_impl`), before any shard_map is entered. `scale`:
+    the softmax scale where it is not head_dim ** -0.5.
 
     segment_ids (sequence-packing masks) run through the Pallas kernel
     too — the reference path's [B, H, L, L] scores are unusable at
@@ -142,7 +158,7 @@ def local_attention(
     pages of a prefix hit) are end-aligned, and bring `kv_segment_ids`.
     """
     if impl == "reference":
-        return reference_attention(q, k, v, causal=causal,
+        return reference_attention(q, k, v, causal=causal, scale=scale,
                                    segment_ids=segment_ids,
                                    kv_segment_ids=kv_segment_ids,
                                    window=window)
@@ -164,7 +180,7 @@ def local_attention(
                                        DEFAULT_BLOCK_Q))
     bk = block_k or int(os.environ.get("KFTPU_FLASH_BLOCK_K",
                                        DEFAULT_BLOCK_K))
-    return flash_attention(q, k, v, causal=causal,
+    return flash_attention(q, k, v, causal=causal, scale=scale,
                            block_q=bq, block_k=bk,
                            segment_ids=segment_ids,
                            kv_segment_ids=kv_segment_ids, window=window)
@@ -182,6 +198,7 @@ def attention(
     block_k: int = 0,
     window: int = 0,
     kv_segment_ids: jax.Array | None = None,
+    scale: float | None = None,
 ) -> jax.Array:
     """Dispatching attention for the model. impl: auto | flash | reference.
 
@@ -197,9 +214,11 @@ def attention(
     `auto` reaches here only from callers that did not go through the
     model registry, which settles it at build (`resolve_impl`).
     """
-    impl = resolve_impl(impl, q.shape[-1])
+    impl = resolve_impl(impl, q.shape[-1], v_dim=v.shape[-1])
     local = functools.partial(local_attention, causal=causal, impl=impl,
                               block_q=block_q, block_k=block_k, window=window)
+    if scale is not None:   # (a call without one is the call it was)
+        local = functools.partial(local, scale=scale)
     mesh = current_mesh()
     # the ids that were given, under local_attention's names for them
     segs = {name: ids for name, ids in (("segment_ids", segment_ids),

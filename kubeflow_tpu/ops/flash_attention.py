@@ -226,10 +226,11 @@ def _fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
                qseg=None, kseg=None, window=0):
-    """q,k,v: [BH, L, D] (kv already repeated to q heads); qseg/kseg:
+    """q,k,v: [BH, L, D] (kv already repeated to q heads; v's last
+    size may be another than q's and k's, and is the output's); qseg/kseg:
     optional [BH, 1, L] int32 segment ids (sequence packing)."""
     bh, lq, d = q.shape
-    lk = k.shape[1]
+    lk, dv = k.shape[1], v.shape[2]
     nq = pl.cdiv(lq, block_q)
     nk = pl.cdiv(lk, block_k)
     offset = lk - lq
@@ -262,14 +263,14 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
     scratch = [
         pltpu.VMEM((block_q, 1), jnp.float32),   # running max
         pltpu.VMEM((block_q, 1), jnp.float32),   # running sum
-        pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
+        pltpu.VMEM((block_q, dv), jnp.float32),  # output accumulator
     ]
     bs = _vmem_spec
 
     in_specs = [
         bs((1, block_q, d), lambda b, i, j: (b, i, 0)),
         bs((1, block_k, d), kj),
-        bs((1, block_k, d), kj),
+        bs((1, block_k, dv), kj),
     ]
     operands = [q, k, v]
     if has_seg:
@@ -284,14 +285,14 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
         grid=(bh, nq, nkw),
         in_specs=in_specs,
         out_specs=[
-            bs((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            bs((1, block_q, dv), lambda b, i, j: (b, i, 0)),
             # lse rides as [BH, 1, L] so the block's trailing dims are
             # (1, block_q) — legal under Mosaic's (8, 128) tiling rule
             # (1 == the full middle dim; block_q % 128 == 0).
             bs((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, lq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, lq, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, lq), jnp.float32),
         ],
         scratch_shapes=scratch,
@@ -638,15 +639,17 @@ def flash_attention(
     window: int = 0,
 ) -> jax.Array:
     """Fused attention. [B, L, H, D] in / out; GQA via fewer KV heads.
+    Values narrower than the keys (v [B, L, H, Dv], Dv != D: latent
+    attention's up-projected form) give [B, L, H, Dv], forward only: the
+    backward kernels are written for one size.
 
     window > 0 = sliding-window attention: keys further than window-1
     positions in the PAST are masked (one-sided; with causal=False,
     future keys stay fully attended — same convention as
-    reference_attention). Blocks fully left of the window skip their
-    COMPUTE via pl.when, so MXU work is O(L * window); their K/V blocks
-    are still DMA'd (the grid shape is static), so HBM traffic stays
-    O(L^2) — a window-sized k-grid with a qi-offset index map is the
-    follow-up that fixes the bandwidth term.
+    reference_attention). A causal windowed call walks a window-sized
+    grid of key blocks (`_flash_fwd`: `pruned`, `_kb_lo`), so the blocks
+    left of the window are neither computed nor fetched: MXU work and HBM
+    traffic are both O(L * window).
 
     segment_ids: optional [B, L] int32 sequence-packing ids — query i
     attends key j only when their ids match (on top of causality), so
@@ -677,7 +680,7 @@ def flash_attention(
     # [B, L, H, D] -> [B*H, L, D]
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, lq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, lk, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, lk, d)
+    vt = v.transpose(0, 2, 1, 3).reshape(b * h, lk, v.shape[-1])
     qseg = kseg = None
     if kv_segment_ids is not None and segment_ids is None:
         raise ValueError(
@@ -692,6 +695,11 @@ def flash_attention(
                           ).reshape(b * h, 1, lq)
         kseg = jnp.repeat(kv_segment_ids.astype(jnp.int32)[:, None], h, axis=1
                           ).reshape(b * h, 1, lk)
-    out = _flash(qt, kt, vt, qseg, kseg, scale, causal, block_q, block_k,
-                 window, interpret_mode())
-    return out.reshape(b, h, lq, d).transpose(0, 2, 1, 3)
+    if vt.shape[-1] != d:
+        # outside the custom_vjp: the forward kernel alone takes the pair
+        out, _ = _flash_fwd(qt, kt, vt, scale, causal, block_q, block_k,
+                            interpret_mode(), qseg, kseg, window)
+    else:
+        out = _flash(qt, kt, vt, qseg, kseg, scale, causal, block_q,
+                     block_k, window, interpret_mode())
+    return out.reshape(b, h, lq, -1).transpose(0, 2, 1, 3)

@@ -52,7 +52,10 @@ Two scoring rules (cfg.moe_score): "softmax" (softmax over the experts,
 the k largest, renormalised) and "sigmoid" (sigmoid scores in float32,
 the k largest of score + `expert_bias`, a buffer that moves the choice
 only, then the chosen scores over their sum and times
-cfg.moe_route_scale). cfg.moe_shared_experts adds a plain SwiGLU of
+cfg.moe_route_scale). Under sigmoid scores cfg.moe_n_group /
+cfg.moe_topk_group limit the choice to the best groups of consecutive
+experts (a group's score: the sum of its two largest score + bias); 1 / 1
+is no groups. cfg.moe_shared_experts adds a plain SwiGLU of
 that many experts' width on every token (`shared`) to the routed sum.
 
 Tokens are BATCH-sharded over the `expert` axis outside this block
@@ -119,7 +122,8 @@ def _router(cfg, x, init, bias=None):
     [b,s,k]). Softmax scores: the k largest, renormalised. Sigmoid
     scores (`bias` [e] given): the k largest of score + bias, the
     chosen scores (not the biased ones) over their sum, times
-    cfg.moe_route_scale."""
+    cfg.moe_route_scale; with cfg.moe_n_group > 1 the k largest inside
+    the best groups (`_within_best_groups`)."""
     router = nn.DenseGeneral(
         cfg.n_experts_total or cfg.n_experts, use_bias=False,
         dtype=jnp.float32,
@@ -134,11 +138,28 @@ def _router(cfg, x, init, bias=None):
             gate_vals.sum(-1, keepdims=True), 1e-9)
         return probs, gate_vals, gate_idx
     probs = jax.nn.sigmoid(logits)
-    _, gate_idx = jax.lax.top_k(probs + bias, cfg.expert_top_k)
+    choice = probs + bias
+    if getattr(cfg, "moe_n_group", 1) > 1:
+        choice = _within_best_groups(choice, cfg.moe_n_group,
+                                     cfg.moe_topk_group)
+    _, gate_idx = jax.lax.top_k(choice, cfg.expert_top_k)
     gate_vals = jnp.take_along_axis(probs, gate_idx, axis=-1)
     gate_vals = gate_vals / (gate_vals.sum(-1, keepdims=True) + 1e-20) \
         * cfg.moe_route_scale
     return probs, gate_vals, gate_idx
+
+
+def _within_best_groups(choice, n_group: int, topk_group: int):
+    """Group-limited choice: `choice` [..., e] with -inf outside the
+    `topk_group` groups, of the `n_group` groups of e / n_group
+    consecutive experts, whose two largest entries sum highest (the
+    first of equals). The k largest of what comes back lie in those
+    groups."""
+    groups = choice.reshape(choice.shape[:-1] + (n_group, -1))
+    score = jax.lax.top_k(groups, 2)[0].sum(-1)           # [..., n_group]
+    _, best = jax.lax.top_k(score, topk_group)
+    kept = jax.nn.one_hot(best, n_group, dtype=jnp.bool_).any(-2)
+    return jnp.where(kept[..., None], groups, -jnp.inf).reshape(choice.shape)
 
 
 def _expert_mlp(cfg, xin, w_gate, w_up, w_down):
@@ -313,6 +334,15 @@ class MoEBlock(nn.Module):
         if cfg.moe_score not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown moe_score {cfg.moe_score!r} "
                              "(softmax|sigmoid)")
+        n_group = getattr(cfg, "moe_n_group", 1)
+        if n_group > 1 and (cfg.moe_score != "sigmoid" or e_all % n_group
+                            or e_all < 2 * n_group
+                            or cfg.moe_topk_group > n_group):
+            raise ValueError(
+                f"moe_n_group {n_group} / moe_topk_group "
+                f"{cfg.moe_topk_group}: group-limited routing is over "
+                f"sigmoid scores, groups of two or more that divide the "
+                f"{e_all} experts, and no more groups than there are")
         bias = (self.param("expert_bias", nn.initializers.zeros, (e_all,),
                            jnp.float32)
                 if cfg.moe_score == "sigmoid" else None)

@@ -140,10 +140,19 @@ def _fresh_prefill_rule(cfg, prompt_len: int, *, prefix_cache: bool,
         return False, f"attention impl is {cfg.attention_impl!r}, not flash"
     if any(n % 128 for n in ladder):
         return False, f"a rung of {list(ladder)} is no multiple of 128"
+    if prefix_cache and _has_latent(cfg):
+        return False, ("latent attention with the prefix cache on: a hit's "
+                       "pages hold latents, which the absorbed form reads")
     if prefix_cache:
         return True, ("causal, flash attention; the prefix cache is on: the "
                       "pages before a rung where a hit lies there")
     return True, "prefix cache off, causal, flash attention"
+
+
+def _has_latent(cfg) -> bool:
+    """Whether some layer of the model attends over latents
+    (models/transformer.py `LatentAttention`)."""
+    return hasattr(cfg, "layers") and any(s.latent for s in cfg.layers())
 
 
 class _DecodeMeter:
@@ -378,6 +387,13 @@ class SlotDecoder:
         elif self.spec and getattr(draft_model.cfg, "gen_block", 0):
             raise ValueError("a block model (gen_block > 0) cannot draft "
                              "for a one-token model")
+        if _has_latent(model.cfg) and (B or self.spec or not self.paged
+                                       or mesh is not None):
+            raise ValueError(
+                "latent attention is served a token a step through the "
+                "paged KV cache on one device (build the model with "
+                "kv_pages and kv_page_size): no block model, no "
+                "draft_model, no dense slot cache, no mesh")
         # positions a slot may touch past its last token: the verify
         # chunk's overhang, or the rest of the answer's last block
         overhang = B or (draft_k if self.spec else 0)
@@ -493,6 +509,11 @@ class SlotDecoder:
                 top_k=top_k, seed=seed, fresh_prefill=self._fresh,
                 prefix_hits=prefix_cache)
             self._counters.update(dict.fromkeys(self.step.counted, 0))
+            if self.step.latent_row_bytes:
+                # the ticks dispatched, and those of them whose latent
+                # attention was the Pallas kernel's (its rule's choice:
+                # all of a decoder's or none)
+                self._counters.update(ticks=0, attn_latent_kernel_ticks=0)
         if self.paged:
             t0 = _stamp()
             self._prefill_at = self.step.prefill_programs(self._ladder, mesh)
@@ -632,6 +653,9 @@ class SlotDecoder:
                 # the ladder's size at the most
                 prefill_shapes=len(self._prefill_lengths),
             )
+            row = getattr(self.step, "latent_row_bytes", 0)
+            if row:     # what a position takes in one latent layer's pool
+                out["kv_latent_row_bytes"] = row
         return out
 
     # -- the loop's pieces ---------------------------------------------------

@@ -112,6 +112,19 @@ class TokenStep:
         self.counted = (self._tick_counters
                         if any(s.moe for s in specs) else ())
         self._fresh_prefill, self._prefix_hits = fresh_prefill, prefix_hits
+        # a model with latent layers: the bytes a position takes in a
+        # layer's pool, and whether a tick's latent attention is the
+        # Pallas kernel's (the rule's own answer, asked once more than
+        # the layers ask it)
+        self.latent_row_bytes, self._latent_kernel = 0, False
+        if pages_per_row and any(s.latent for s in specs):
+            from kubeflow_tpu.models.transformer import latent_row_width
+            from kubeflow_tpu.ops import paged_latent_attention as pla
+
+            width = latent_row_width(cfg)
+            self.latent_row_bytes = width * jnp.dtype(cfg.dtype).itemsize
+            self._latent_kernel = pla.use_kernel(
+                1, (cfg.kv_pages, cfg.kv_page_size, width), cfg.dtype)
         self.temperature, self.top_k, self.seed = temperature, top_k, seed
         # the most tokens a slot can finish in a fused round
         self.fuse_tokens = self.FUSE
@@ -422,6 +435,7 @@ class TokenStep:
     def dispatch(self, owners, ticks: int, table) -> None:
         self._tabled = 0 if table is None else ticks * len(self._walks) * (
             table[0] if self.two_kinds else table).size
+        self._ticks = ticks
         self.state = (self._step_fused if ticks > 1 else self._step)(
             self.params, self.state, *(() if table is None else (table,)))
 
@@ -451,6 +465,9 @@ class TokenStep:
         if self.counted:    # the round's counts, from the same read-back
             counts.update(zip(self.counted,
                               np.asarray(self.state[8]).tolist()))
+        if self.latent_row_bytes:
+            counts.update(ticks=self._ticks, attn_latent_kernel_ticks=(
+                self._ticks if self._latent_kernel else 0))
         return counts
 
     def answer(self, slot: int, r) -> tuple:

@@ -17,6 +17,7 @@ beforehand is four roundings' worth of the largest output, 2**-6.
 """
 
 import dataclasses
+import functools
 import json
 import logging
 import os
@@ -260,6 +261,62 @@ def test_block_kernel_compiles_for_a_v5e_at_the_cells_shape(
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
+def test_latent_kernel_compiles_for_a_v5e_at_the_cells_shape(
+        v5e_chip, monkeypatch):
+    """`latent-saturated`'s tick: 64 slots, 64 heads' absorbed queries of
+    640, rows of 560 pages, the 35,841-page latent pool, which goes into
+    the call as it lies (no copy of 734 MB in front of it); the custom
+    call carries the name the benchmark finds it by."""
+    import jax
+    import jax.numpy as jnp
+
+    from kubeflow_tpu.ops import flash_attention
+    from kubeflow_tpu.ops.paged_latent_attention import (
+        KERNEL_NAME, paged_latent_attention)
+
+    monkeypatch.setattr(flash_attention, "INTERPRET", False)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    compiled = jax.jit(functools.partial(
+        paged_latent_attention, scale=0.13, rank=512)).lower(
+        arg((64, 64, 640), jnp.bfloat16), arg((35841, 16, 640), jnp.bfloat16),
+        arg((64, 560), jnp.int32), arg((64,), jnp.int32),
+        arg((64,), jnp.int32)).compile()
+    assert f"%{KERNEL_NAME}" in compiled.as_text()
+    assert " = bf16[64,64,512]" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+@pytest.mark.parametrize("rung", [2048, 8192])
+def test_flash_forward_compiles_for_a_v5e_at_keys_192_and_values_128(
+        v5e_chip, monkeypatch, rung):
+    """A latent layer's rung in the up-projected form: Mosaic takes the
+    forward kernel with keys of 192 and values of 128 as they are, with
+    the padding's segment ids, through the nested jit whose name the
+    kernels carry in the trace."""
+    import jax
+    import jax.numpy as jnp
+
+    from kubeflow_tpu.ops import flash_attention
+    from kubeflow_tpu.ops.attention import local_attention
+
+    monkeypatch.setattr(flash_attention, "INTERPRET", False)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    qk = arg((1, rung, 64, 192), jnp.bfloat16)
+    text = jax.jit(functools.partial(
+        local_attention, causal=True, impl="flash", scale=0.13)).lower(
+        qk, qk, arg((1, rung, 64, 128), jnp.bfloat16),
+        segment_ids=arg((1, rung), jnp.int32)).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "%local_attention" in calls[0]
+    assert f"bf16[64,{rung},128]" in calls[0]
+
+
 @pytest.mark.parametrize("rows, k, n, groups", [
     (2048, 2048, 768, 128), (2048, 768, 2048, 128),
     (8192, 2048, 768, 128), (8192, 768, 2048, 128),
@@ -269,10 +326,14 @@ def test_block_kernel_compiles_for_a_v5e_at_the_cells_shape(
     # a chip's share of Trinity-Large's experts (32 of 3,072 x 3,072: one
     # matrix is 18.9 MB, two of them in VMEM): a tick of 32 slots x 4 and
     # the prefill's rung of 4,096, the longest the rule gives the kernel
-    (128, 3072, 3072, 32), (16384, 3072, 3072, 32)],
+    (128, 3072, 3072, 32), (16384, 3072, 3072, 32),
+    # a chip's share of A.X-K1's experts (12 of 7,168 x 2,048: 29.4 MB a
+    # matrix, two of them in VMEM): a tick of 64 slots x 8
+    (512, 7168, 2048, 12), (512, 2048, 7168, 12)],
     ids=["pass-gate-up", "pass-down", "top-rung-gate-up", "top-rung-down",
          "gpt-moe-8e-gate-up", "gpt-moe-8e-down", "share-of-32-tick",
-         "share-of-32-rung-4096"])
+         "share-of-32-rung-4096", "share-of-12-tick-gate-up",
+         "share-of-12-tick-down"])
 def test_grouped_matmul_compiles_for_a_v5e_at_the_cells_shape(
         v5e_chip, monkeypatch, rows, k, n, groups):
     """The experts' streamed grouped matmul (ops/grouped_matmul.py; its

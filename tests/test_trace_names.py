@@ -128,6 +128,15 @@ def program_names():
         names.add(f"%{kernel}.3 = bf16[64,128,128]{{2,1,0:T(8,128)(2,1)}} "
                   "custom-call(%table, %start, %last, %q, %k, %v), "
                   'custom_call_target="tpu_custom_call"')
+    # a latent layer's tick: the kernel over the latent pool
+    # (tests/test_paged_attention.py compiles it for a v5e and finds the
+    # name); its rung's flash kernels are `%local_attention.N` like any
+    from kubeflow_tpu.ops.paged_latent_attention import (
+        KERNEL_NAME as LATENT_NAME)
+
+    names.add(f"%{LATENT_NAME}.4 = bf16[64,64,512]{{2,1,0:T(8,128)(2,1)}} "
+              "custom-call(%table, %start, %last, %q, %pool), "
+              'custom_call_target="tpu_custom_call"')
     return names
 
 
@@ -181,6 +190,11 @@ def test_the_kernels_names_are_what_their_definitions_say():
     assert (KERNEL_NAME, BLOCK_KERNEL_NAME) == (
         "paged_decode_attention", "paged_block_attention")
     assert STREAMED_NAME == "ragged-dot-streamed"
+    from kubeflow_tpu.ops.paged_latent_attention import (
+        KERNEL_NAME as LATENT_NAME)
+
+    assert LATENT_NAME == "paged_latent_attention" \
+        and LATENT_NAME in looked_for
 
 
 def test_both_grouped_matmuls_are_found_by_the_experts_roofline(
