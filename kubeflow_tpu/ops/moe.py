@@ -47,6 +47,12 @@ and comes back as zeros. Nothing stands in for the absent chips: no
 exchange, no stand-in weights. `moe_pairs`, `moe_expert_visits`,
 `moe_load_max` and `moe_kernel_pairs` then count the held pairs, and
 `moe_pairs_routed` every pair the live tokens were routed (tokens x k).
+Where the call has many rows (`compact_bound`: a prompt's rung, never a
+tick) only the held pairs' rows are touched: the sort puts them first,
+and the gather, the three grouped matmuls and the gates' sum work on a
+static window of that many sorted positions, and on a second window in
+the freak call whose held pairs overrun the first (`moe_compact_calls`,
+`moe_compact_spills`). Nothing is dropped there either.
 
 Two scoring rules (cfg.moe_score): "softmax" (softmax over the experts,
 the k largest, renormalised) and "sigmoid" (sigmoid scores in float32,
@@ -75,7 +81,12 @@ capacity; the dropless path leaves padding rows out):
     took (all of `moe_pairs` or 0: the choice is made when the program
     is traced);
   moe_pairs_routed — the pairs of live tokens whether their expert is
-    held here or not (`moe_pairs` where every expert is held).
+    held here or not (`moe_pairs` where every expert is held);
+and from a layer that holds a share alone:
+  moe_compact_calls — 1 where this call's rows were compacted to the
+    held pairs (`compact_bound`: decided when the program is traced);
+  moe_compact_spills — 1 where its held pairs overran the window and
+    took a second one.
 
 In the device trace the dropless path's expert matmuls are custom calls
 whose names start alike: the compiler's own for `ragged_dot`,
@@ -96,7 +107,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from kubeflow_tpu.ops import grouped_matmul
+from kubeflow_tpu.ops import grouped_matmul, moe_combine
 from kubeflow_tpu.parallel.mesh import (
     AXIS_DCN,
     AXIS_DATA,
@@ -169,8 +180,30 @@ def _expert_mlp(cfg, xin, w_gate, w_up, w_down):
     return jnp.einsum("etf,efd->etd", h, w_down.astype(cfg.dtype))
 
 
+# A window of sorted positions for the held pairs of a layer that holds
+# a share: COMPACT_ROOM times their mean, and never under
+# COMPACT_MIN_ROWS (under a few hundred rows the gather is nothing, and
+# one tick's held share can be anything from none to all).
+COMPACT_ROOM = 2
+COMPACT_MIN_ROWS = 512
+
+
+def compact_bound(pairs: int, e: int, e_total: int):
+    """The rows `dropless_mlp` works on in the place of all `pairs`
+    routed pairs where the layer holds `e` of `e_total` experts, or None
+    where it works on all of them: a layer that holds every expert, and
+    a call with so few rows that the window would not be smaller (every
+    tick). A rule over shapes; nothing sets it."""
+    if e >= e_total:
+        return None
+    mean = -(-COMPACT_ROOM * pairs * e // e_total)
+    tile = grouped_matmul.ROW_TILE
+    bound = max(COMPACT_MIN_ROWS, -(-mean // tile) * tile)
+    return bound if bound < pairs else None
+
+
 def dropless_mlp(cfg, x, gate_vals, gate_idx, w_gate, w_up, w_down,
-                 live=None, streamed=False, first=None):
+                 live=None, streamed=False, first=None, bound=None):
     """Sort by expert, one grouped matmul each for gate, up and down over
     the contiguous groups, combine with the gates. x [t, d] flattened
     tokens, gate_* [t, k], weights [e, ...], `live` [t] bool or None
@@ -179,8 +212,10 @@ def dropless_mlp(cfg, x, gate_vals, gate_idx, w_gate, w_up, w_down,
     (the caller asks `grouped_matmul.use_kernel`); `first`: the weights
     are the experts `first .. first + e - 1` of those `gate_idx` counts
     over (None: all of them), and a pair whose expert is not among them
-    comes back as zeros like a dead row's. Returns (y [t, d], counts
-    [e]): the pairs each held expert got."""
+    comes back as zeros like a dead row's; `bound` (`compact_bound`'s
+    answer): the sorted positions a window holds, None for all t * k at
+    once. Returns (y [t, d], counts [e]): the pairs each held expert
+    got."""
     t, d = x.shape
     k = gate_idx.shape[-1]
     e = w_gate.shape[0]
@@ -194,12 +229,14 @@ def dropless_mlp(cfg, x, gate_vals, gate_idx, w_gate, w_up, w_down,
         eidx = jnp.where(jnp.repeat(live, k), eidx, e)
     order = jnp.argsort(eidx)                        # stable
     counts = jnp.zeros((e + 1,), jnp.int32).at[eidx].add(1)[:e]
-    xs = x[order // k].astype(cfg.dtype)
-    wg, wu, wd = (w.astype(cfg.dtype) for w in (w_gate, w_up, w_down))
     matmul = (grouped_matmul.grouped_matmul if streamed
               else jax.lax.ragged_dot)
-    h = nn.silu(matmul(xs, wg, counts)) * matmul(xs, wu, counts)
-    out = matmul(h, wd, counts)                      # [t*k, d], sorted
+    if bound is not None:
+        return _compacted(cfg, x, gate_vals, order, counts, matmul,
+                          (w_gate, w_up, w_down), bound), counts
+    xs = x[order // k].astype(cfg.dtype)
+    wg, wu, wd = (w.astype(cfg.dtype) for w in (w_gate, w_up, w_down))
+    out = _experts(matmul, xs, wg, wu, wd, counts)   # [t*k, d], sorted
     # rows behind the last group are no group's: whatever they hold
     out = jnp.where((eidx[order] < e)[:, None], out, 0)
     # back to (token, slot) order, then the gates' weighted sum
@@ -207,6 +244,52 @@ def dropless_mlp(cfg, x, gate_vals, gate_idx, w_gate, w_up, w_down,
     y = jnp.einsum("tkd,tk->td", out.astype(jnp.float32),
                    gate_vals.astype(jnp.float32))
     return y.astype(cfg.dtype), counts
+
+
+def _experts(matmul, rows, wg, wu, wd, sizes):
+    """SwiGLU over rows sorted by expert: three grouped matmuls."""
+    h = nn.silu(matmul(rows, wg, sizes)) * matmul(rows, wu, sizes)
+    return matmul(h, wd, sizes)
+
+
+def _compacted(cfg, x, gate_vals, order, counts, matmul, weights,
+               bound: int):
+    """`dropless_mlp` over the held pairs alone. The stable sort put them
+    first, `order[:sum(counts)]`, so a window of `bound` sorted positions
+    holds them all but in a freak call, which takes a second window (the
+    loop's turns are cdiv(held pairs, bound): every held pair is in one,
+    each group's size clipped to the window). A window gathers its rows,
+    runs the experts over them and adds each row, weighed by its gate,
+    into its token's row of a float32 [t, d] (`ops/moe_combine.py`: its
+    kernel or XLA's scatter-add, by its `use_kernel`): the addends of a
+    token are the whole-rows path's, and no step touches t * k rows.
+    Forward only: a loop of as many turns as the call needs."""
+    t, d = x.shape
+    k = gate_vals.shape[-1]
+    weights = [w.astype(cfg.dtype) for w in weights]
+    add = (moe_combine.combine if moe_combine.use_kernel(t, bound, d)
+           else moe_combine.scatter_add)
+    ends = jnp.cumsum(counts)
+    starts, held = ends - counts, ends[-1]
+    gates = gate_vals.reshape(-1).astype(jnp.float32)
+    # whole windows: a slice that starts inside `order` ends inside it
+    order = jnp.pad(order, (0, -(t * k) % bound))
+
+    def window(w, y):
+        lo = w * bound
+        pair = jax.lax.dynamic_slice(order, (lo,), (bound,))
+        sizes = (jnp.clip(ends - lo, 0, bound)
+                 - jnp.clip(starts - lo, 0, bound))
+        out = _experts(matmul, x[pair // k].astype(cfg.dtype), *weights,
+                       sizes)
+        # positions behind the last group are no group's, whatever
+        # they hold: token t is nobody
+        token = jnp.where(lo + jnp.arange(bound) < held, pair // k, t)
+        return add(y, out, token, gates[pair])
+
+    y = jax.lax.fori_loop(0, -(-held // bound), window,
+                          jnp.zeros((t, d), jnp.float32))
+    return y.astype(cfg.dtype)
 
 
 def sparse_dispatch_mlp(cfg, x_local, gate_vals, gate_idx, w_gate, w_up,
@@ -368,15 +451,17 @@ class MoEBlock(nn.Module):
                 "capacity paths hold every expert")
         use_sparse = not dropless and self._sparse_ok(mesh)
         # one rule for gate, up and down: it asks of k and n what holds
-        # for them swapped
+        # for them swapped, and of the rows that exist, the held pairs in
+        # the mean
         streamed = dropless and grouped_matmul.use_kernel(
-            b * s * k, d, d_ff, e, cfg.dtype)
+            b * s * k * e // e_all, d, d_ff, e, cfg.dtype)
+        bound = compact_bound(b * s * k, e, e_all)
         if dropless:
             y, counts = dropless_mlp(
                 cfg, x.reshape(b * s, d), gate_vals.reshape(b * s, k),
                 gate_idx.reshape(b * s, k), w_gate, w_up, w_down,
                 None if live is None else live.reshape(b * s), streamed,
-                cfg.expert_first if share else None)
+                cfg.expert_first if share else None, bound)
             y = y.reshape(b, s, d)
             kept = routed = slots = jnp.sum(counts)
         elif use_sparse:
@@ -402,6 +487,12 @@ class MoEBlock(nn.Module):
         self.sow("diagnostics", "moe_load_max", jnp.max(counts))
         self.sow("diagnostics", "moe_kernel_pairs",
                  jnp.sum(counts) if streamed else jnp.int32(0))
+        if share:
+            self.sow("diagnostics", "moe_compact_calls",
+                     jnp.int32(bound is not None))
+            self.sow("diagnostics", "moe_compact_spills",
+                     jnp.int32(0) if bound is None
+                     else (pairs > bound).astype(jnp.int32))
         # Ground truth for which dispatch path actually ran (ADVICE r4):
         # _sparse_ok silently falls back to dense on a meshless trace, so
         # a run labeled 'sparse' could measure dense with nothing in the

@@ -514,6 +514,10 @@ class SlotDecoder:
                 # attention was the Pallas kernel's (its rule's choice:
                 # all of a decoder's or none)
                 self._counters.update(ticks=0, attn_latent_kernel_ticks=0)
+        # what the rungs of a model that holds a share of its experts
+        # count (a draft's admission is its own program, and counts none)
+        self._counters.update(dict.fromkeys(
+            getattr(self.step, "rung_counted", ()), 0))
         if self.paged:
             t0 = _stamp()
             self._prefill_at = self.step.prefill_programs(self._ladder, mesh)

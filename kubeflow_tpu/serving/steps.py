@@ -52,6 +52,13 @@ BLOCK_COUNTERS = ("block_passes", "blocks_committed", "kv_pages_walked",
 # four above (of the pairs whose experts this chip holds) and every pair
 # the tokens were routed, held here or not (ops/moe.py).
 MOE_COUNTERS = BLOCK_COUNTERS[3:] + ("moe_pairs_routed",)
+# What a prompt's rung counts on the device where its mixture layers hold
+# a share of the experts and compact their rows to the held pairs
+# (ops/moe.py:compact_bound): the layers that did, and those of them
+# whose held pairs overran the window. The rung's program hands them out
+# beside the state: the ticks' counters are zeroed at every dispatch and
+# mean ticks only.
+RUNG_COUNTERS = ("moe_compact_calls", "moe_compact_spills")
 
 
 class TokenStep:
@@ -111,6 +118,10 @@ class TokenStep:
         # the mixture's counters ride the state of a one-token model too
         self.counted = (self._tick_counters
                         if any(s.moe for s in specs) else ())
+        # the rungs' own counters, where a mixture layer holds a share
+        self.rung_counted = (
+            RUNG_COUNTERS if any(s.moe for s in specs)
+            and getattr(cfg, "n_experts_total", 0) > cfg.n_experts else ())
         self._fresh_prefill, self._prefix_hits = fresh_prefill, prefix_hits
         # a model with latent layers: the bytes a position takes in a
         # layer's pool, and whether a tick's latent attention is the
@@ -204,10 +215,12 @@ class TokenStep:
                 # a rung ends at prompt_len: where it starts is static
                 if self._prefix_hits and toks.shape[1] < self.P:
                     fresh["hit_below"] = self.P - toks.shape[1]
+            compacts = self._compacts(toks.shape[1])
             logits, mut = model.apply(
                 params | {"cache": cache}, toks, train=False,
-                decode_index=start, mutable=["cache"], pad_len=pad,
-                page_table=pt_row, **fresh)
+                decode_index=start,
+                mutable=["cache", "diagnostics"] if compacts else ["cache"],
+                pad_len=pad, page_table=pt_row, **fresh)
             cache = mut["cache"]
             last, first_pos = self._open(last, logits, slot, *block)
             pos = _set1(pos, slot, first_pos)
@@ -216,7 +229,12 @@ class TokenStep:
                 out, jnp.zeros((1, self.N), jnp.int32), (slot, 0))
             pads = _set1(pads, slot, pad[0])
             req = _set1(req, slot, req_n)
-            return (cache, last, pos, remaining, out, pads, req, rng, *more)
+            state = (cache, last, pos, remaining, out, pads, req, rng, *more)
+            if compacts:
+                return state, jnp.stack([
+                    jnp.asarray(_diag_sum(mut["diagnostics"], n), jnp.int32)
+                    for n in RUNG_COUNTERS])
+            return state
 
         self._paged_prefill_install = jax.jit(
             _paged_prefill_install, donate_argnums=(1,))
@@ -301,6 +319,17 @@ class TokenStep:
             return tuple(state[:8]) + (jnp.zeros_like(state[8]),)
         return state
 
+    def _compacts(self, length: int) -> bool:
+        """Whether the rung of `length` positions compacts its mixture
+        layers' rows (the layers' own rule): its program then hands out
+        RUNG_COUNTERS beside the state."""
+        from kubeflow_tpu.ops.moe import compact_bound
+
+        cfg = self.model.cfg
+        return bool(self.rung_counted) and compact_bound(
+            length * cfg.expert_top_k, cfg.n_experts,
+            cfg.n_experts_total) is not None
+
     def _open(self, last, logits, slot):
         """The paged install's part that is the step's own: the slot's
         entry in `last`, and the position its first tick writes."""
@@ -337,6 +366,8 @@ class TokenStep:
         self.rem = np.zeros(self.S, np.int64)
         self.pos = np.zeros(self.S, np.int64)
         self._walked = self._walked_window = 0
+        # the admitted rungs' counts, on the device until a read-back
+        self._rung_counts: list = []
 
     def end(self, r) -> int:
         return self.P + r.req
@@ -377,6 +408,9 @@ class TokenStep:
             jax.tree.map(jnp.asarray, table_row),
             jnp.asarray([r.pad], jnp.int32), jnp.int32(slot),
             jnp.int32(r.req), *block)
+        if self._compacts(self.P - start):
+            self.state, counts = self.state
+            self._rung_counts.append(counts)
         self.rem[slot], self.pos[slot] = r.req, first_pos
 
     def install_dense(self, batch, slots, spare) -> list:
@@ -465,10 +499,20 @@ class TokenStep:
         if self.counted:    # the round's counts, from the same read-back
             counts.update(zip(self.counted,
                               np.asarray(self.state[8]).tolist()))
+        counts.update(self._rungs_read())
         if self.latent_row_bytes:
             counts.update(ticks=self._ticks, attn_latent_kernel_ticks=(
                 self._ticks if self._latent_kernel else 0))
         return counts
+
+    def _rungs_read(self) -> dict:
+        """RUNG_COUNTERS of the rungs admitted since the last read-back,
+        whose programs the round's ticks ran behind."""
+        rungs, self._rung_counts = self._rung_counts, []
+        total = np.zeros(len(self.rung_counted), np.int64)
+        for counts in rungs:
+            total += np.asarray(counts)
+        return dict(zip(self.rung_counted, total.tolist()))
 
     def answer(self, slot: int, r) -> tuple:
         return [int(t) for t in self._out[slot][:r.req]], {}
@@ -629,7 +673,7 @@ class BlockStep(TokenStep):
         # the round's counts, from the same read-back
         return dict(zip(BLOCK_COUNTERS,
                         np.asarray(self.state[1]["ctr"]).tolist()),
-                    kv_pages_tabled=self._tabled)
+                    kv_pages_tabled=self._tabled, **self._rungs_read())
 
     def readback(self, owners) -> tuple:
         done, counts = super().readback(owners)

@@ -399,15 +399,22 @@ FRESH_PREFILL_JAXPRS_AT_PARENT = {
     128: "123d2e58c582ac0aa0b83ddeae1971ed478cc9448e76c0561c7d9491d63d4afc",
     512: "c74da6480aee7339d55e2dec0e7491ec7f80eb21f99b79e6021899e8f1056637",
 }
+# The 512 rung since PR 36: its mixture layers (8 of 32 experts, 2,048
+# pairs) work on a window of 1,024 sorted positions (ops/moe.py:
+# `compact_bound`) and the program hands out the rung's two counters; the
+# 128 rung (512 pairs: the rule's floor) is the program it was. With the
+# rule switched off the 512 rung hashes to the value above, and the two
+# programs' logits and pages agree to rounding (the test below).
+FRESH_PREFILL_JAXPR_COMPACTED = {
+    512: "72d6b3f6fde08f4221e6e05bb45d642a7a6b1d0dce23cf21b7de595eb5b0e9fa",
+}
 
 
-@pytest.mark.parametrize("rung", list(FRESH_PREFILL_JAXPRS_AT_PARENT))
-def test_the_fresh_prefill_without_a_prefix_cache_is_what_it_was(rung):
+def fresh_prefill_step():
+    """The toy two-kind model's step with the flash prefill, and the
+    arguments of its 512-position program after `params` and `state`."""
     import dataclasses
-    import hashlib
-    import re
 
-    import jax
     import jax.numpy as jnp
 
     from kubeflow_tpu.runtime.kvcache import pages_for
@@ -425,12 +432,72 @@ def test_the_fresh_prefill_without_a_prefix_cache_is_what_it_was(rung):
     mp = pages_for(p + n, PAGE)
     step = steps.TokenStep(model, params, 2, p, n, mp, fresh_prefill=True)
     row = jnp.zeros((1, mp), jnp.int32)
-    text = str(jax.make_jaxpr(step._paged_prefill_install)(
-        params, step.state, jnp.zeros((1, rung), jnp.int32),
-        jnp.zeros((1,), jnp.int32), (row, row), jnp.zeros((1,), jnp.int32),
-        jnp.int32(0), jnp.int32(1)))
-    assert hashlib.sha256(re.sub(r"0x[0-9a-f]+", "0x", text).encode()) \
-        .hexdigest() == FRESH_PREFILL_JAXPRS_AT_PARENT[rung]
+
+    def args(rung, toks=None, table=(row, row)):
+        return (jnp.zeros((1, rung), jnp.int32) if toks is None else toks,
+                jnp.zeros((1,), jnp.int32), table,
+                jnp.zeros((1,), jnp.int32), jnp.int32(0), jnp.int32(1))
+
+    return d, params, step, args
+
+
+def jaxpr_sha(fn, *args):
+    import hashlib
+    import re
+
+    import jax
+
+    text = str(jax.make_jaxpr(fn)(*args))
+    return hashlib.sha256(
+        re.sub(r"0x[0-9a-f]+", "0x", text).encode()).hexdigest()
+
+
+@pytest.mark.parametrize("rung", list(FRESH_PREFILL_JAXPRS_AT_PARENT))
+def test_the_fresh_prefill_without_a_prefix_cache_is_what_it_was(
+        rung, monkeypatch):
+    from kubeflow_tpu.ops import moe
+
+    _, params, step, args = fresh_prefill_step()
+    got = jaxpr_sha(step._paged_prefill_install, params, step.state,
+                    *args(rung))
+    assert got == FRESH_PREFILL_JAXPR_COMPACTED.get(
+        rung, FRESH_PREFILL_JAXPRS_AT_PARENT[rung])
+    # the rule apart, the program is the parent's at every rung
+    monkeypatch.setattr(moe, "compact_bound", lambda *a: None)
+    _, params, step, args = fresh_prefill_step()
+    assert jaxpr_sha(step._paged_prefill_install, params, step.state,
+                     *args(rung)) == FRESH_PREFILL_JAXPRS_AT_PARENT[rung]
+
+
+def test_the_compacted_rung_gives_the_logits_and_pages_it_gave(monkeypatch):
+    """The 512 rung's re-pinned program against the program it was (the
+    rule switched off), on a padded prompt: the same last logits and the
+    same pages to float32's rounding, the mixture layers counted."""
+    import jax
+    import jax.numpy as jnp
+
+    from kubeflow_tpu.ops import moe
+
+    out = {}
+    for compacted in (True, False):
+        if not compacted:
+            monkeypatch.setattr(moe, "compact_bound", lambda *a: None)
+        d, params, step, args = fresh_prefill_step()
+        toks = jnp.asarray(np.random.default_rng(3).integers(
+            1, d.vocab, (1, 512)), jnp.int32).at[0, :37].set(0)
+        table = jnp.arange(1, step.mp + 1, dtype=jnp.int32)[None]
+        got = step._paged_prefill_install(
+            params, step.state, *args(512, toks, (table, table))[:3],
+            jnp.asarray([37], jnp.int32), jnp.int32(0), jnp.int32(1))
+        state, counts = got if compacted else (got, None)
+        out[compacted] = (np.asarray(state[1][0]), jax.tree.map(
+            np.asarray, state[0]), counts)
+    logits, pages, counts = out[True]
+    assert np.asarray(counts).tolist() == [d.layers - d.dense_layers, 0]
+    assert np.abs(logits).max() > 0.1
+    assert np.abs(logits - out[False][0]).max() <= GAP
+    for got, want in zip(jax.tree.leaves(pages), jax.tree.leaves(out[False][1])):
+        assert np.abs(got - want).max() <= GAP
 
 
 # -- the rules and the refusals ------------------------------------------------------

@@ -370,6 +370,40 @@ def test_grouped_matmul_compiles_for_a_v5e_at_the_cells_shape(
     assert f" = bf16[{rows},{n}]" in calls[0]
 
 
+@pytest.mark.parametrize("t,m,d", [
+    (8192, 8192, 7168), (2048, 2048, 7168), (16384, 16384, 3072)],
+    ids=["share-of-12-top-rung", "share-of-12-first-rung",
+         "share-of-32-top-rung"])
+def test_gates_sum_kernel_compiles_for_a_v5e_at_the_cells_shape(
+        v5e_chip, monkeypatch, t, m, d):
+    """The kernel that adds a compacted rung's results into their tokens'
+    rows (ops/moe_combine.py; its interpreter's tests are
+    tests/test_moe_combine.py): Mosaic takes it at the rungs of the two
+    configurations that hold a share, y is updated in place, and its
+    name is no grouped matmul's (`moe.expert_roofline.*` reads those)."""
+    import jax
+    import jax.numpy as jnp
+
+    from kubeflow_tpu.ops import flash_attention
+    from kubeflow_tpu.ops.moe import EXPERT_MATMUL_TRACE_NAME
+    from kubeflow_tpu.ops.moe_combine import KERNEL_NAME, combine
+
+    monkeypatch.setattr(flash_attention, "INTERPRET", False)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    text = jax.jit(combine, donate_argnums=(0,)).lower(
+        arg((t, d), jnp.float32), arg((m, d), jnp.bfloat16),
+        arg((m,), jnp.int32), arg((m,), jnp.float32)).compile().as_text()
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1 and calls[0].startswith(f"%{KERNEL_NAME}")
+    assert not KERNEL_NAME.startswith(EXPERT_MATMUL_TRACE_NAME)
+    assert f" = f32[{t},{d}]" in calls[0]
+    assert "output_to_operand_aliasing" in calls[0]
+
+
 def test_path_rule_follows_backend_and_chunk_length(caplog):
     """The gather path off the TPU and for chunks, the kernel for one
     query a slot on a TPU; which, and why, is logged."""
