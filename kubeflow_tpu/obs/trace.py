@@ -64,6 +64,16 @@ def annotation(name: str, **attrs):
     return profiler.TraceAnnotation(name, **attrs)
 
 
+def profiling() -> bool:
+    """Whether a ``jax.profiler`` session is open in this process: what
+    makes the annotations above host events. JAX is looked up as
+    `annotation` looks it up, and the call reads one flag. A hot loop
+    compares it with what it saw last and so sees a session open and
+    close by itself (the decoder's ``serve.profiled`` span)."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    return profiler is not None and profiler.TraceAnnotation.is_enabled()
+
+
 class _Phase:
     """One phase of a PhaseClock: the context manager it hands out."""
 

@@ -464,8 +464,11 @@ class SlotDecoder:
             "spec_rounds": 0, "spec_tokens_emitted": 0,
             "spec_tokens_accepted": 0, "spec_drafted": 0,
             "deadline_canceled": 0,
-            # passes of the loop that dispatched a step program
-            "rounds": 0,
+            # passes of the loop that dispatched a step program, the ticks
+            # they dispatched (a fused round counts FUSE; a block model's
+            # are its passes), and the tokens of answers their read-backs
+            # found fixed (a canceled request's stay counted)
+            "rounds": 0, "ticks": 0, "tokens_decoded": 0,
             # per request: submit to admission, submit to the first
             # read-back after it (seconds, summed; `admitted` and
             # `first_tokens` are the counts)
@@ -486,10 +489,24 @@ class SlotDecoder:
         if B:
             # counted on the device and read back with `remaining`
             self._counters.update(dict.fromkeys(steps.BLOCK_COUNTERS, 0))
+        # a round by what it held (`_round_class`): how many, and their
+        # wall seconds from the top of the pass to the end of `complete`
+        self._round_keys = {
+            cls: (f"rounds.{cls}", f"round_s.{cls}") for cls in (
+                "plain", "fused",
+                *(f"rung{n}" for n in (self._ladder if self.paged else ())),
+                "other")}
+        for n_key, s_key in self._round_keys.values():
+            self._counters.update({n_key: 0, s_key: 0.0})
         # the loop's host phases: phase_s.* in stats(), and kftpu.sched.*
         # annotations in the profiler's trace
         self._phase = obs_trace.PhaseClock(
             "sched", SCHED_PHASES, self._counters)
+        # the suffixes admitted in the loop's current pass
+        self._pass_rungs: list = []
+        # while a profiler session is open: (when the loop saw it open,
+        # the counters as they stood)
+        self._profiled: tuple | None = None
 
         # (jit arguments, never closure captures: steps.py says why)
         self._params = {"params": variables["params"]}
@@ -510,10 +527,9 @@ class SlotDecoder:
                 prefix_hits=prefix_cache)
             self._counters.update(dict.fromkeys(self.step.counted, 0))
             if self.step.latent_row_bytes:
-                # the ticks dispatched, and those of them whose latent
-                # attention was the Pallas kernel's (its rule's choice:
-                # all of a decoder's or none)
-                self._counters.update(ticks=0, attn_latent_kernel_ticks=0)
+                # of `ticks`, those whose latent attention was the Pallas
+                # kernel's (its rule's choice: all of a decoder's or none)
+                self._counters["attn_latent_kernel_ticks"] = 0
         # what the rungs of a model that holds a share of its experts
         # count (a draft's admission is its own program, and counts none)
         self._counters.update(dict.fromkeys(
@@ -700,6 +716,42 @@ class SlotDecoder:
             c["kv_window_pages_covered_sum"] += sum(
                 map(a.window_covered, owners))
 
+    def _note_profiler(self) -> None:
+        """Immediately before every dispatch of a step program, and in
+        every idle pass: whether a profiler session is open, against what
+        the loop saw last. After a read-back the device is idle, so what
+        is dispatched behind an open session runs inside it: from the
+        rising edge to the falling one the counters' growth is what the
+        session's device trace holds, to the one dispatch in flight at
+        either end, and goes out as one `serve.profiled` span."""
+        if obs_trace.profiling():
+            if self._profiled is None:
+                self._profiled = (_stamp(), dict(self._counters))
+        elif self._profiled is not None:
+            self._end_profiled()
+
+    def _end_profiled(self) -> None:
+        t_on, before = self._profiled
+        self._profiled = None
+        obs_trace.TRACER.record(
+            "serve.profiled", t_on, _stamp(),
+            **{k: v - before[k] for k, v in self._counters.items()})
+
+    def _round_class(self, ticks: int) -> str:
+        """What the pass that is about to dispatch `ticks` held: `plain`
+        (a single tick, no admission), `fused` (a fused dispatch, no
+        admission), `rung<R>` (a single tick behind exactly one admission
+        whose suffix is the ladder's R), `other` (the rest: several
+        admissions, an admission before a fused dispatch, a dense
+        decoder's batch)."""
+        rungs = self._pass_rungs
+        if not rungs:
+            return "fused" if ticks > 1 else "plain"
+        cls = f"rung{rungs[0]}"
+        if len(rungs) == 1 and ticks == 1 and cls in self._round_keys:
+            return cls
+        return "other"
+
     def _cow_copy(self, copies) -> None:
         """[(src, dst)] page clones, applied before a program writes:
         the ONE conversion every COW-apply site shares."""
@@ -755,6 +807,7 @@ class SlotDecoder:
         with phase("admit"):
             r.t_admit = _stamp()
             r.slot, r.prefill_tokens = slot, prefill_tokens
+            self._pass_rungs.append(prefill_tokens)
             c = self._counters
             c["admitted"] += 1
             c["queue_wait_s_sum"] += r.t_admit - r.t_submit
@@ -776,6 +829,7 @@ class SlotDecoder:
         with phase("readback"):
             met = self.step.first_token(slot, r, first)
         with phase("admit"):
+            c["tokens_decoded"] += 1     # the install's own first token
             self._note_first_tokens([r])
             if met:
                 self._complete(owners, slot)
@@ -842,14 +896,18 @@ class SlotDecoder:
             step.fresh()
 
         admit = self._admit_paged if self.paged else self._admit_dense
+        c = self._counters
         while not self._stop:
             try:
+                t_top = _stamp()
+                self._pass_rungs.clear()
                 admit(owners, fail_all)
                 with phase("admit"):
                     self._cancel_expired(owners)
                     self._note_active(owners)
                 if not owners:
                     with phase("idle"):
+                        self._note_profiler()
                         self._wake.wait(timeout=0.05)
                         self._wake.clear()
                     continue
@@ -879,13 +937,17 @@ class SlotDecoder:
                                 self.alloc.write_barrier(s_, start, end))
                         self._note_window_pages(owners)
                     table = self._tables() if self.paged else None
+                    n_key, s_key = self._round_keys[self._round_class(ticks)]
                 with phase("tick", fused=int(ticks > 1)), self._ctx:
+                    self._note_profiler()
                     step.dispatch(owners, ticks, table)
-                self._counters["rounds"] += 1
+                c["rounds"] += 1
+                c[n_key] += 1
                 with phase("readback"):
                     done, counts = step.readback(owners)
+                    c["ticks"] += ticks
                     for name, n in counts.items():
-                        self._counters[name] += n
+                        c[name] += n
                 with phase("complete"):
                     self._note_first_tokens(owners.values())
                     for s_ in done:
@@ -896,12 +958,16 @@ class SlotDecoder:
                             counts["spec_tokens_accepted"])
                     self._publish_pages()
                     self._note_active(owners)
+                c[s_key] += _stamp() - t_top
             except Exception as e:  # a broken step: poison + rebuild
                 log.exception("slot-decoder loop failed")
                 fail_all(e)
                 self._active = 0
-        # shutdown: fail any stragglers
+        # shutdown: fail any stragglers; a profiled stretch still open
+        # ends here
         self._drain_shutdown(owners)
+        if self._profiled is not None:
+            self._end_profiled()
 
     # -- admission: dense (batched idle-burst prefill) ---------------------
 
@@ -928,6 +994,7 @@ class SlotDecoder:
             slots = [self._free.pop() for _ in range(len(batch))]
         try:
             with phase("prefill"), self._ctx:
+                self._note_profiler()
                 firsts = self.step.install_dense(batch, slots, self._free)
         except Exception as e:
             self._free.extend(slots)
@@ -971,6 +1038,7 @@ class SlotDecoder:
                     # the decoder was built
                     suffix = self.P - plan.compute_start
                 with phase("prefill"), self._ctx:
+                    self._note_profiler()
                     self._cow_copy(plan.copies)
                     first = step.install_paged(
                         self._prefill_at[suffix], r, slot,

@@ -17,7 +17,9 @@ of the meter or of the clock. What the loop calls, on every kind:
     ticks(owners)           how many ticks the next round may fuse
     writes(slot, r, ticks)  the positions [start, end) the round writes
     dispatch(owners, ticks, table)    the round's programs
-    readback(owners)        (the slots that finished, the round's counts)
+    readback(owners)        (the slots that finished, the round's counts,
+                            `tokens_decoded` among them: the tokens of
+                            answers fixed since the last read-back)
     answer(slot, r)         (what `submit` returns, the span's extras)
     cancel(slots); fresh()  zero slots; rebuild after a failed round
 """
@@ -476,6 +478,10 @@ class TokenStep:
     def readback(self, owners) -> tuple:
         # the host blocks here until the device has caught up
         remaining = np.asarray(self.state[3])
+        # the tokens of answers the round fixed: the fall of `remaining`
+        # against the mirror as it stood at the dispatch (a slot without
+        # an owner stands at 0 in both)
+        decoded = int((self.rem - remaining).sum())
         # writable copies: admission writes fresh slots' mirrors
         self.rem = np.array(remaining)
         self.pos = np.array(self.state[2])
@@ -483,7 +489,7 @@ class TokenStep:
         # one readback of the tokens per round, and only where a slot
         # finished
         self._out = np.asarray(self.state[4]) if done else None
-        return done, self._counts()
+        return done, dict(self._counts(), tokens_decoded=decoded)
 
     def _counts(self) -> dict:
         """How much of the page table the round's ticks walked (one tick
@@ -500,9 +506,9 @@ class TokenStep:
             counts.update(zip(self.counted,
                               np.asarray(self.state[8]).tolist()))
         counts.update(self._rungs_read())
-        if self.latent_row_bytes:
-            counts.update(ticks=self._ticks, attn_latent_kernel_ticks=(
-                self._ticks if self._latent_kernel else 0))
+        if self.latent_row_bytes:   # of the round's ticks, the kernel's
+            counts["attn_latent_kernel_ticks"] = (
+                self._ticks if self._latent_kernel else 0)
         return counts
 
     def _rungs_read(self) -> dict:
@@ -855,6 +861,8 @@ class SpecStep:
             counts["spec_tokens_accepted"] += min(a, take)
             if self.rem_h[s_] <= 0:
                 done.append(s_)
+        # (an install's own first token is the loop's to count)
+        counts["tokens_decoded"] = counts["spec_tokens_emitted"]
         return done, counts
 
     def answer(self, slot: int, r) -> tuple:

@@ -425,13 +425,20 @@ STATS_KEYS = {
     "prefill_tokens_computed", "prompt_tokens_real",
     "prompt_tokens_submitted", "queue_wait_s_sum", "rounds", "spec_drafted",
     "spec_rounds", "spec_tokens_accepted", "spec_tokens_emitted",
-    "speculative"}
+    "speculative",
+    # (what the loop counts of every pass came with PR 37)
+    "ticks", "tokens_decoded", "rounds.plain", "round_s.plain",
+    "rounds.fused", "round_s.fused", "rounds.other", "round_s.other"}
 STATS_KEYS_PAGED = {
     "cow_clones", "kv_page_size", "kv_pages_free", "kv_pages_tabled",
     "kv_pages_total", "kv_pages_used", "kv_pages_walked", "prefill_shapes",
     "prefix_hit_pages", "prefix_hit_tokens"}
 STATS_KEYS_BLOCK = {"block_passes", "blocks_committed", "moe_expert_visits",
                     "moe_kernel_pairs", "moe_load_max", "moe_pairs"}
+# a paged decoder's rounds behind one admission, by the ladder's rung
+# (conftest's prompt_len 8 over pages of 4)
+STATS_KEYS_RUNGS = {f"{kind}.rung{n}" for kind in ("rounds", "round_s")
+                    for n in (4, 8)}
 
 
 @pytest.mark.parametrize("mode", DECODER_MODES)
@@ -447,7 +454,7 @@ def test_stats_keys_and_the_one_decoder_the_harness_finds(mode):
     try:
         want = set(STATS_KEYS)
         if dec.paged:
-            want |= STATS_KEYS_PAGED
+            want |= STATS_KEYS_PAGED | STATS_KEYS_RUNGS
         if mode == "block":
             want |= STATS_KEYS_BLOCK
         first = dec.stats()
@@ -465,6 +472,124 @@ def test_stats_keys_and_the_one_decoder_the_harness_finds(mode):
         assert jax.tree.leaves(dec.state) and dec.state is dec.step.state
     finally:
         dec.close()
+
+
+BUSY_PHASES = ("admit", "prefill", "pages", "tick", "readback", "complete")
+
+
+def round_classes(st: dict) -> dict:
+    """class -> (its rounds, their seconds), from a `stats()`."""
+    return {k[len("rounds."):]: (n, st["round_s." + k[len("rounds."):]])
+            for k, n in st.items() if k.startswith("rounds.")}
+
+
+@pytest.mark.parametrize("mode", DECODER_MODES)
+def test_the_loop_counts_ticks_tokens_and_rounds_by_class(mode):
+    """For every kind of step: `ticks` is the `ticks` of each
+    `step.dispatch` summed, `tokens_decoded` the tokens of the answers
+    once the decoder is empty, and a pass that dispatched is of exactly
+    one class: the classes' rounds add up to `rounds`, their seconds to
+    the six busy phases'."""
+    dec = slot_decoder(mode, slots=2, max_new_tokens=12)
+    dispatched, real = [], dec.step.dispatch
+    dec.step.dispatch = lambda owners, ticks, table: (
+        dispatched.append(ticks), real(owners, ticks, table))[1]
+    budgets = [([1, 2, 3], 12), ([4, 5], 3), ([7, 8, 9, 1], 1), ([3, 3], 5),
+               ([6], 12)]
+    answers = []
+    try:
+        first = dec.stats()
+        assert first["ticks"] == first["tokens_decoded"] == 0
+        threads = [threading.Thread(
+            target=lambda p=p, n=n: answers.append(
+                answer_tokens(dec.submit(p, max_new=n))))
+            for p, n in budgets]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        st = dec.stats()
+    finally:
+        dec.close()
+    assert sorted(map(len, answers)) == sorted(n for _, n in budgets)
+    assert st["tokens_decoded"] == sum(map(len, answers))
+    assert st["ticks"] == sum(dispatched) >= st["rounds"] == len(dispatched)
+    classes = round_classes(st)
+    assert set(classes) == {"plain", "fused", "other"} | (
+        {"rung4", "rung8"} if dec.paged else set())
+    assert sum(n for n, _ in classes.values()) == st["rounds"]
+    assert all((s > 0) == (n > 0) for n, s in classes.values())
+    # a fused dispatch is `fused`, or `other` where the pass admitted too
+    assert classes["fused"][0] <= sum(t > 1 for t in dispatched) \
+        <= classes["fused"][0] + classes["other"][0]
+    busy = sum(st[f"phase_s.{p}"] for p in BUSY_PHASES)
+    seconds = sum(s for _, s in classes.values())
+    # (apart: the stamps between two phases, and the idle passes' looks
+    # at the queue, microseconds each)
+    assert seconds == pytest.approx(busy, rel=0.02, abs=2e-3)
+
+
+def test_a_round_behind_one_admission_is_of_its_rungs_class():
+    """One request at a time, each with fewer tokens to go than a fused
+    round: its first pass holds one admission at its prompt's rung and
+    one tick, every other pass a single tick."""
+    dec = slot_decoder("token-paged", slots=2, max_new_tokens=6,
+                       prefix_cache=False)
+    try:
+        assert dec._ladder == (4, 8)
+        dec.submit([1, 2, 3])                 # three real tokens: rung 4
+        dec.submit([1, 2, 3, 4, 5, 6])        # six: rung 8
+        dec.submit([9, 8, 7, 6, 5])
+        st = dec.stats()
+    finally:
+        dec.close()
+    classes = {k: n for k, (n, _) in round_classes(st).items()}
+    assert classes == {"rung4": 1, "rung8": 2, "plain": st["rounds"] - 3,
+                       "fused": 0, "other": 0}
+    assert st["ticks"] == st["rounds"] and st["tokens_decoded"] == 18
+
+
+def test_an_admission_before_a_fused_dispatch_is_of_no_rungs_class():
+    """Nothing waits and the request has a fused round's tokens to go:
+    the pass that admits it dispatches FUSE ticks, and is `other`."""
+    from kubeflow_tpu.serving.steps import TokenStep
+
+    dec = slot_decoder("token-paged", slots=2, max_new_tokens=12)
+    try:
+        dec.submit([1, 2, 3])
+        st = dec.stats()
+    finally:
+        dec.close()
+    classes = {k: n for k, (n, _) in round_classes(st).items()}
+    assert classes["other"] == 1 and classes["rung4"] == 0
+    assert st["ticks"] == st["rounds"] + (TokenStep.FUSE - 1) * (
+        classes["other"] + classes["fused"])
+    assert st["tokens_decoded"] == 12
+
+
+def test_a_canceled_requests_tokens_stay_counted():
+    """`tokens_decoded` is what the device decoded: the tokens of a
+    request canceled in its slot were, and stay."""
+    from kubeflow_tpu.serving.router import DeadlineExceeded
+
+    now = [0.0]
+    dec = slot_decoder("token-paged", slots=2, max_new_tokens=6,
+                       clock=lambda: now[0])
+    real = dec.step.readback
+
+    def readback(owners):
+        now[0] += 1.0           # the deadline passes after three rounds
+        return real(owners)
+
+    dec.step.readback = readback
+    try:
+        with pytest.raises(DeadlineExceeded):
+            dec.submit([1, 2, 3], deadline=2.5)
+        st = dec.stats()
+    finally:
+        dec.close()
+    assert st["deadline_canceled"] == 1 and st["completed"] == 0
+    assert st["tokens_decoded"] == st["ticks"] == 3
 
 
 class TestPerRequestBudgets:

@@ -7,6 +7,7 @@ import json
 import subprocess
 import sys
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -18,9 +19,15 @@ REQUEST_ATTRS = {"queue_wait_s", "first_token_s", "prompt_tokens",
 
 
 class Recorder:
-    """Stands in for jax.profiler.TraceAnnotation."""
+    """Stands in for jax.profiler.TraceAnnotation; `enabled` for
+    whether a profiler session is open."""
 
     log: list = []
+    enabled = False
+
+    @staticmethod
+    def is_enabled() -> bool:
+        return Recorder.enabled
 
     def __init__(self, name, **attrs):
         self.name, self.attrs = name, attrs
@@ -38,7 +45,7 @@ class Recorder:
 def recorder(monkeypatch):
     import jax
 
-    Recorder.log = []
+    Recorder.log, Recorder.enabled = [], False
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
     return Recorder
 
@@ -85,10 +92,26 @@ class TestBridge:
                 "c = t.PhaseClock('sched', ('tick',), {}); "
                 "\nwith c('tick'): pass\n"
                 "\nwith t.TRACER.span('x'): pass\n"
-                "print('jax' in sys.modules)")
+                "print('jax' in sys.modules, t.profiling())")
         out = subprocess.run([sys.executable, "-c", code], text=True,
                              capture_output=True, check=True)
-        assert out.stdout.strip() == "False"
+        assert out.stdout.strip() == "False False"
+
+    def test_profiling_is_the_sessions_own_flag(self, recorder, tmp_path):
+        assert tr.profiling() is False
+        recorder.enabled = True
+        assert tr.profiling() is True
+
+    def test_profiling_follows_a_real_session(self, tmp_path):
+        import jax
+
+        assert tr.profiling() is False
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            assert tr.profiling() is True
+        finally:
+            jax.profiler.stop_trace()
+        assert tr.profiling() is False
 
 
 class TestPhaseClock:
@@ -303,6 +326,147 @@ class TestDecoderPhases:
         for phase in ("tick", "readback", "prefill", "complete"):
             assert any(n.startswith(f"kftpu.sched.{phase}") for n in names), (
                 phase, sorted(n for n in names if "kftpu" in n))
+
+
+def profiled_spans(since: int = 0):
+    return [s for s in tr.COLLECTOR.spans()[since:]
+            if s.name == "serve.profiled"]
+
+
+def wait_for(what, timeout=10.0):
+    """Polls until `what()` is truthy (the loop sees an edge at its next
+    dispatch or idle pass, 50 ms at the most)."""
+    t_end = time.monotonic() + timeout
+    while not what() and time.monotonic() < t_end:
+        time.sleep(0.01)
+    return what()
+
+
+class TestProfiledStretch:
+    """The loop sees a profiler session open and close by itself and
+    writes what it dispatched in between as one `serve.profiled` span."""
+
+    def test_a_real_session_leaves_one_span_of_what_it_held(self, lm,
+                                                            tmp_path):
+        import jax
+
+        dec = paged_decoder(lm)
+        try:
+            dec.submit([1, 2, 3])            # compile outside the session
+            mark = len(tr.COLLECTOR.spans())
+            before = dec.stats()
+            t_before = tr._EPOCH + time.perf_counter()
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                for p in ([4, 5, 6], [7, 8], [9, 1, 2, 3]):
+                    dec.submit(p, max_new=4)
+                inside = dec.stats()
+            finally:
+                jax.profiler.stop_trace()
+            assert wait_for(lambda: profiled_spans(mark))
+            t_after = tr._EPOCH + time.perf_counter()
+            dec.submit([5, 5, 5])            # behind the session: no part
+            st = dec.stats()
+        finally:
+            dec.close()
+        (span,) = profiled_spans(mark)
+        assert span.attrs["admitted"] == 3
+        assert span.attrs["tokens_decoded"] == 12
+        for key in ("ticks", "rounds", "rounds.plain", "rounds.rung4",
+                    "prefill_tokens_computed", "prompt_tokens_real",
+                    "kv_pages_walked", "phase_s.tick", "round_s.plain"):
+            assert span.attrs[key] == pytest.approx(
+                inside[key] - before[key]), key
+            assert span.attrs[key] > 0, key
+        assert set(span.attrs) == set(dec._counters)   # all, no list
+        assert st["admitted"] == before["admitted"] + 4
+        # its ends lie inside the session's and bracket the admissions
+        # and ends of the requests it held, and of those only
+        assert t_before <= span.start < span.end <= t_after
+        held = [s for s in request_spans()
+                if s.attrs["queue_wait_s"] is not None and span.start
+                <= s.start + s.attrs["queue_wait_s"] <= span.end]
+        assert len(held) == 3 and all(s.end <= span.end for s in held)
+        json.dumps(tr.to_chrome_trace([span]))
+
+    def test_edges_in_mid_flight_count_what_was_dispatched_between(
+            self, lm, recorder):
+        """The session opens and closes while requests are in their
+        slots: the span's `ticks` and `admitted` are those of the
+        dispatches the loop made while it saw the session open."""
+        dec = paged_decoder(lm)
+        seen, real = [], dec.step.dispatch
+
+        def dispatch(owners, ticks, table):
+            # (the loop has just looked: what it dispatches now is in)
+            seen.append((dec._profiled is not None, ticks))
+            real(owners, ticks, table)
+            if len(seen) == 3:
+                recorder.enabled = True
+            if len(seen) == 9:
+                recorder.enabled = False
+
+        dec.step.dispatch = dispatch
+        try:
+            mark = len(tr.COLLECTOR.spans())
+            threads = [threading.Thread(
+                target=lambda p=p: dec.submit(p, max_new=6))
+                for p in ([1, 2, 3], [4, 5], [6, 7, 8, 9], [2, 4, 6])]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+            assert wait_for(lambda: profiled_spans(mark))
+        finally:
+            dec.close()
+        (span,) = profiled_spans(mark)
+        assert span.attrs["ticks"] == sum(t for on, t in seen if on) > 0
+        assert span.attrs["rounds"] == sum(on for on, _ in seen) == 6
+        assert 0 <= span.attrs["admitted"] <= 4
+
+    def test_no_session_no_span(self, lm):
+        mark = len(tr.COLLECTOR.spans())
+        dec = paged_decoder(lm)
+        try:
+            dec.submit([1, 2, 3])
+            dec.submit([4, 5, 6])
+        finally:
+            dec.close()
+        assert profiled_spans(mark) == []
+
+    def test_a_stretch_open_at_close_is_recorded(self, lm, recorder):
+        mark = len(tr.COLLECTOR.spans())
+        dec = paged_decoder(lm)
+        try:
+            recorder.enabled = True
+            dec.submit([1, 2, 3], max_new=5)
+        finally:
+            dec.close()
+        (span,) = profiled_spans(mark)
+        assert span.attrs["admitted"] == 1
+        assert span.attrs["tokens_decoded"] == 5
+
+    def test_a_dense_and_a_speculative_decoder_write_it_too(self, lm,
+                                                            recorder):
+        from kubeflow_tpu.serving.continuous import SlotDecoder
+
+        model, variables = lm
+        mark = len(tr.COLLECTOR.spans())
+        dec = SlotDecoder(model, variables, slots=2, prompt_len=8,
+                          max_new_tokens=4, draft_model=model,
+                          draft_variables=variables, draft_k=2)
+        try:
+            recorder.enabled = True
+            assert len(dec.submit([1, 2, 3])) == 4
+            recorder.enabled = False
+            assert wait_for(lambda: profiled_spans(mark))
+        finally:
+            dec.close()
+        (span,) = profiled_spans(mark)
+        assert span.attrs["admitted"] == 1
+        assert span.attrs["tokens_decoded"] == 4
+        assert span.attrs["ticks"] == span.attrs["rounds"] \
+            == span.attrs["spec_rounds"]
 
 
 def host_event_names(path) -> set:
