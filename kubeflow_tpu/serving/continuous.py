@@ -65,6 +65,7 @@ whatever mesh the variables are sharded over.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import queue
 import threading
@@ -492,11 +493,13 @@ class SlotDecoder:
             "spec_rounds": 0, "spec_tokens_emitted": 0,
             "spec_tokens_accepted": 0, "spec_drafted": 0,
             "deadline_canceled": 0,
-            # passes of the loop that dispatched a step program, the ticks
-            # they dispatched (a fused round counts FUSE; a block model's
-            # are its passes), and the tokens of answers their read-backs
-            # found fixed (a canceled request's stay counted)
-            "rounds": 0, "ticks": 0, "tokens_decoded": 0,
+            # passes of the loop that dispatched a step program, those of
+            # them dispatched while the round before was still unread
+            # (`_loop`), the ticks they dispatched (a fused round counts
+            # FUSE; a block model's are its passes), and the tokens of
+            # answers their read-backs found fixed (a canceled request's
+            # stay counted)
+            "rounds": 0, "rounds_ahead": 0, "ticks": 0, "tokens_decoded": 0,
             # per request: submit to admission, submit to the first
             # read-back after it (seconds, summed; `admitted` and
             # `first_tokens` are the counts)
@@ -518,7 +521,8 @@ class SlotDecoder:
             # counted on the device and read back with `remaining`
             self._counters.update(dict.fromkeys(steps.BLOCK_COUNTERS, 0))
         # a round by what it held (`_round_class`): how many, and their
-        # wall seconds from the top of the pass to the end of `complete`
+        # wall seconds: those of the passes from the end of the last
+        # read-back to the end of its own `complete` (`_read_round`)
         self._round_keys = {
             cls: (f"rounds.{cls}", f"round_s.{cls}") for cls in (
                 "plain", "fused",
@@ -535,6 +539,8 @@ class SlotDecoder:
         # while a profiler session is open: (when the loop saw it open,
         # the counters as they stood)
         self._profiled: tuple | None = None
+        # the top of the first pass whose seconds no round has taken yet
+        self._t_charge: float | None = None
 
         # (jit arguments, never closure captures: steps.py says why)
         self._params = {"params": variables["params"]}
@@ -729,12 +735,12 @@ class SlotDecoder:
         (one slot's row of it), or where pages are kept by layer kind the
         pair (held kind's, window kind's)."""
         rows = slice(None) if slot is None else slice(slot, slot + 1)
-        held = self.alloc.table[rows]
+        # copies: the allocator rewrites entries that a program still in
+        # flight reads, and the device may take the host's array as it is
+        held = self.alloc.table[rows].copy()
         if not self.alloc.window:
             return self._jnp.asarray(held) if slot is None else held
-        # copies: a release rewrites entries that a prefill still in
-        # flight reads, and the device may take the host's array as it is
-        return (self._jnp.asarray(held.copy()),
+        return (self._jnp.asarray(held),
                 self._jnp.asarray(self.alloc.window_table[rows].copy()))
 
     def _note_window_pages(self, owners) -> None:
@@ -747,11 +753,12 @@ class SlotDecoder:
     def _note_profiler(self) -> None:
         """Immediately before every dispatch of a step program, and in
         every idle pass: whether a profiler session is open, against what
-        the loop saw last. After a read-back the device is idle, so what
+        the loop saw last. At most one round is in flight there, so what
         is dispatched behind an open session runs inside it: from the
         rising edge to the falling one the counters' growth is what the
-        session's device trace holds, to the one dispatch in flight at
-        either end, and goes out as one `serve.profiled` span."""
+        session's device trace holds, to the one round in flight at
+        either end (a fused one at the most), and goes out as one
+        `serve.profiled` span."""
         if obs_trace.profiling():
             if self._profiled is None:
                 self._profiled = (_stamp(), dict(self._counters))
@@ -881,14 +888,42 @@ class SlotDecoder:
                 c["first_tokens"] += 1
 
     def _complete(self, owners: dict, slot: int) -> None:
-        """A finished slot: the answer to its waiter, the slot and
-        (paged) its pages back to the pool."""
+        """A finished slot: the slot and (paged) its pages back to the
+        pool, then the answer to its waiter, who may read `stats()`."""
         r = owners.pop(slot)
-        r.finish(*self.step.answer(slot, r))
+        answer = self.step.answer(slot, r)
         self._free.append(slot)
         self._counters["completed"] += 1
         if self.paged:
             self.alloc.free(slot)
+        self._note_active(owners)
+        r.finish(*answer)
+
+    def _read_round(self, owners: dict, flown: tuple) -> None:
+        """Read back the oldest round in flight and complete what it
+        finished. `flown`: the round's seconds key and when it was
+        dispatched. The passes since the last read-back are its
+        seconds: where the loop runs ahead, the pass that read it back
+        and, for the first round ahead, the pass that dispatched it."""
+        phase, c = self._phase, self._counters
+        s_key, t_sent = flown
+        with phase("readback"):
+            done, counts = self.step.readback(owners)
+            for name, n in counts.items():
+                c[name] += n
+        with phase("complete"):
+            # the requests admitted before the round was dispatched: it
+            # decoded their tokens
+            self._note_first_tokens(
+                [r for r in owners.values() if r.t_admit <= t_sent])
+            for s_ in done:
+                self._complete(owners, s_)
+            if self.meter and "spec_rounds" in counts:
+                self.meter.spec_round(
+                    counts["spec_rounds"], counts["spec_tokens_accepted"])
+            self._publish_pages()
+        c[s_key] += _stamp() - self._t_charge
+        self._t_charge = None
 
     def _cancel_expired(self, owners: dict) -> None:
         """Cancel the mid-decode slots whose deadline has passed, at the
@@ -901,20 +936,26 @@ class SlotDecoder:
         if not expired:
             return
         self.step.cancel(expired)
+        gone = [owners.pop(slot) for slot in expired]
         for slot in expired:
-            owners.pop(slot).finish(
-                DeadlineExceeded("deadline exceeded during decode"))
             self._free.append(slot)
             self._counters["deadline_canceled"] += 1
             if self.paged:
                 self.alloc.free(slot)
         self._publish_pages()
+        self._note_active(owners)
+        # the waiters last: each may read `stats()` once woken
+        for r in gone:
+            r.finish(DeadlineExceeded("deadline exceeded during decode"))
 
     # -- the scheduler loop: one, whatever the kind of step ----------------
 
     def _loop(self) -> None:
         phase, step = self._phase, self.step
         owners: dict[int, _Request] = {}   # slot -> the request it serves
+        # the rounds dispatched and not read back, oldest first: each
+        # one's seconds key and when it was dispatched
+        flight: collections.deque = collections.deque()
 
         def fail_all(err, batch=()):
             """Poison every waiter and REBUILD device state: continuing
@@ -924,6 +965,8 @@ class SlotDecoder:
             for r in (*batch, *owners.values()):
                 r.finish(err)
             owners.clear()
+            flight.clear()
+            self._t_charge = None
             self._free = list(range(self.S))
             if self.alloc is not None:
                 self.alloc.reset()
@@ -933,17 +976,39 @@ class SlotDecoder:
         c = self._counters
         while not self._stop:
             try:
-                t_top = _stamp()
+                if self._t_charge is None:
+                    self._t_charge = _stamp()
                 self._pass_rungs.clear()
                 admit(owners, fail_all)
                 with phase("admit"):
                     self._cancel_expired(owners)
                     self._note_active(owners)
+                # Run ahead: dispatch this round before the last one is
+                # read back, so the host's work between the two runs
+                # behind the device's. Where the step's next round needs
+                # nothing the read-back gives, and every slot is owned:
+                # no waiter could then enter sooner. Else the round in
+                # flight is read back first, in a pass of its own.
+                # A round in flight writes no page that its predecessor's
+                # read-back gives away, but for a slot that finished
+                # there: that slot idles, and its ticks write at its
+                # frozen position, one past its answer: into the trash
+                # page or a page of its own answer (the row's last, where
+                # the position lies past the row), never a prompt page
+                # that `_complete` keeps for the prefix cache. Whatever
+                # is dispatched later, an install into that page among
+                # it, consumes the state the round in flight hands on,
+                # so the device runs it after that write.
+                ahead = step.runs_ahead and not self._free
+                if flight and not ahead:
+                    self._read_round(owners, flight.popleft())
+                    continue
                 if not owners:
                     with phase("idle"):
                         self._note_profiler()
                         self._wake.wait(timeout=0.05)
                         self._wake.clear()
+                    self._t_charge = None
                     continue
                 with phase("pages"):
                     # fuse ticks when the step has a full window left
@@ -977,28 +1042,25 @@ class SlotDecoder:
                     step.dispatch(owners, ticks, table)
                 c["rounds"] += 1
                 c[n_key] += 1
-                with phase("readback"):
-                    done, counts = step.readback(owners)
-                    c["ticks"] += ticks
-                    for name, n in counts.items():
-                        c[name] += n
-                with phase("complete"):
-                    self._note_first_tokens(owners.values())
-                    for s_ in done:
-                        self._complete(owners, s_)
-                    if self.meter and "spec_rounds" in counts:
-                        self.meter.spec_round(
-                            counts["spec_rounds"],
-                            counts["spec_tokens_accepted"])
-                    self._publish_pages()
-                    self._note_active(owners)
-                c[s_key] += _stamp() - t_top
+                c["ticks"] += ticks
+                c["rounds_ahead"] += bool(flight)
+                flight.append((s_key, _stamp()))
+                if len(flight) > 1 or not ahead:
+                    self._read_round(owners, flight.popleft())
             except Exception as e:  # a broken step: poison + rebuild
                 log.exception("slot-decoder loop failed")
                 fail_all(e)
                 self._active = 0
-        # shutdown: fail any stragglers; a profiled stretch still open
-        # ends here
+        # shutdown: the round in flight is read back, so what it finished
+        # is answered; then fail any stragglers; a profiled stretch still
+        # open ends here
+        try:
+            while flight:
+                if self._t_charge is None:
+                    self._t_charge = _stamp()
+                self._read_round(owners, flight.popleft())
+        except Exception:
+            log.exception("slot-decoder read-back at shutdown failed")
         self._drain_shutdown(owners)
         if self._profiled is not None:
             self._end_profiled()

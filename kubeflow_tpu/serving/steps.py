@@ -9,6 +9,9 @@ of a round, and what a round writes and yields. It is handed requests
 nothing of the queue, of a request's event, of the allocator's books,
 of the meter or of the clock. What the loop calls, on every kind:
 
+    runs_ahead              whether the next round may be dispatched before
+                            this one is read back (its programs need
+                            nothing the host reads of a round)
     end(r)                  one past the last position r may ever write
     prefill_programs(ladder, mesh)    the paged install at each rung
     install_paged(...), install_dense(...)    a first token still on the
@@ -17,15 +20,24 @@ of the meter or of the clock. What the loop calls, on every kind:
     ticks(owners)           how many ticks the next round may fuse
     writes(slot, r, ticks)  the positions [start, end) the round writes
     dispatch(owners, ticks, table)    the round's programs
-    readback(owners)        (the slots that finished, the round's counts,
+    readback(owners)        the oldest round not read back: (the slots
+                            that finished, the round's counts,
                             `tokens_decoded` among them: the tokens of
-                            answers fixed since the last read-back)
+                            answers it fixed)
     answer(slot, r)         (what `submit` returns, the span's extras)
     cancel(slots); fresh()  zero slots; rebuild after a failed round
+
+A kind that runs ahead hands the host what it reads of a round in a
+report beside the state (`TokenStep._handed_out`), whose transfer starts at
+the dispatch, and the answers' tokens in a buffer no later program is
+handed: the state itself goes on to the next program, donated. Its
+mirrors of `remaining` and `pos` stand at the last read-back, and
+`ticks` and `writes` count the rounds still in flight on top of them.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 
 import jax
@@ -77,24 +89,25 @@ class TokenStep:
     the rung's one program, told apart by `pad` against the rung's first
     position."""
 
-    # -- FUSE ticks in one dispatched program. Each
-    #    dispatch costs a host round-trip (launch, the readback of
-    #    `remaining`, the loop's bookkeeping); where that exceeds the
-    #    tick's own compute, decode is bound by the host. Fusing
-    #    amortizes the round-trip FUSE-fold. Measured on a v5e (PERF.md
-    #    section 5, `sched.host_ms_per_round.*`): without an admission
-    #    the host's share of a round is about 1.3 ms (page bookkeeping
-    #    and the table's upload 0.6-0.7, the dispatch 0.5-0.6, completion
-    #    0.1) against a tick of 3.7-5.2 ms of an 8-layer Mistral-7B,
-    #    so the round-trip is a quarter to a third of a single tick
-    #    and a thirtieth of a fused round; an admission adds 6-15 ms
-    #    of host time. Correctness is
-    #    unchanged — the tick body masks on remaining>0, so a slot
-    #    finishing mid-window just idles until the window ends; the
-    #    cost is admission/completion latency bounded at FUSE ticks,
-    #    which is why the loop only fuses when nothing is waiting
-    #    and every active slot has >= FUSE tokens to go. ------------
+    # -- FUSE ticks in one dispatched program. Each dispatch costs a
+    #    round of the host: the launch, the read-back, the loop's
+    #    bookkeeping. By the loop's own books on a v5e (PERF.md section
+    #    5, an 8-layer Mistral-7B over 32 slots), a plain round took
+    #    8.3-8.6 ms of wall time around a tick of 5.2 ms: about 3 ms of
+    #    the host's a round, and `sched.host_ms_per_round.chat` reads
+    #    4.1-4.7 ms over every round; an admission adds 9-10 ms. Fusing
+    #    pays that once for FUSE ticks; where the loop keeps a round in
+    #    flight (a full decoder, a kind that runs ahead) the host's round
+    #    runs behind the device's instead. Correctness is unchanged: the
+    #    tick body masks on remaining>0, so a slot finishing mid-window
+    #    just idles until the window ends; the cost is admission and
+    #    completion latency bounded at FUSE ticks, which is why the loop
+    #    only fuses when nothing is waiting (or no slot is free) and
+    #    every active slot has >= FUSE tokens to go. ------------------
     FUSE = 8
+    # a round's programs take nothing from the host but the page table,
+    # so the next one may be dispatched before this one is read back
+    runs_ahead = True
     _opening_shapes = ()    # of `_opening`'s arguments to the paged install
     # what a tick of a model with mixture layers counts in the state's
     # ninth leaf (a block model's pass counts them among its own)
@@ -251,10 +264,12 @@ class TokenStep:
         #    of them in one program. The names are a contract too: the
         #    modules are `jit__tick` and `jit__step_fused` in the device
         #    trace, and the benchmark reads them by that (a block model's
-        #    pass takes the tick's place under the same names) -----------
+        #    pass takes the tick's place under the same names). Each
+        #    hands out the state, then the round's report and its answers'
+        #    tokens (`_handed_out`) ------------------------------------
         def _tick(params, state, page_table=None):
-            return self._tick_once(params, self._counted_from_zero(state),
-                                   page_table)
+            return self._handed_out(state, self._tick_once(
+                params, self._counted_from_zero(state), page_table))
 
         def _step_fused(params, state, page_table=None):
             def body(st, _):
@@ -262,7 +277,7 @@ class TokenStep:
 
             st, _ = jax.lax.scan(body, self._counted_from_zero(state), None,
                                  length=self.FUSE)
-            return st
+            return self._handed_out(state, st)
 
         if self.mp:
             self._step = jax.jit(_tick, donate_argnums=(1,))
@@ -321,6 +336,24 @@ class TokenStep:
             return tuple(state[:8]) + (jnp.zeros_like(state[8]),)
         return state
 
+    def _handed_out(self, before, after) -> tuple:
+        """What a round's program returns: the state it leaves, which the
+        next program is handed and consumes; then the report, all the
+        host reads of every round in one array (each slot's `remaining`,
+        then its `pos`, then the tokens of answers the round fixed, then
+        what it counted on the device); then the answers' tokens, which
+        no later program is handed, read where a slot finished."""
+        report = jnp.concatenate([
+            after[3], after[2], jnp.sum(before[3] - after[3])[None],
+            self._device_counts(after)])
+        return (*after, report, self._answers(after))
+
+    def _device_counts(self, state):
+        return state[8] if self.counted else jnp.zeros((0,), jnp.int32)
+
+    def _answers(self, state):
+        return state[4]
+
     def _compacts(self, length: int) -> bool:
         """Whether the rung of `length` positions compacts its mixture
         layers' rows (the layers' own rule): its program then hands out
@@ -368,8 +401,11 @@ class TokenStep:
         self.rem = np.zeros(self.S, np.int64)
         self.pos = np.zeros(self.S, np.int64)
         self._walked = self._walked_window = 0
-        # the admitted rungs' counts, on the device until a read-back
+        # the admitted rungs' counts, on the device until the read-back
+        # of the round dispatched behind them
         self._rung_counts: list = []
+        # the rounds dispatched and not read back, oldest first
+        self._flight: collections.deque = collections.deque()
 
     def end(self, r) -> int:
         return self.P + r.req
@@ -414,6 +450,7 @@ class TokenStep:
             self.state, counts = self.state
             self._rung_counts.append(counts)
         self.rem[slot], self.pos[slot] = r.req, first_pos
+        self._leave([slot])
 
     def install_dense(self, batch, slots, spare) -> list:
         """One batched prefill and one install for an idle burst, padded
@@ -443,78 +480,118 @@ class TokenStep:
             self.cancel(dummies)
         for s_, r in zip(slots, batch):
             self.rem[s_] = r.req
+        self._leave(slots)
         return [None] * len(batch)
 
     def copy_pages(self, src, dst) -> None:
         self.state = self._apply_copies(self.state, src, dst)
 
+    def _leave(self, slots) -> None:
+        """The slots' requests have gone (an install put another in, or
+        a cancel none): the rounds in flight no longer serve them."""
+        for rnd in self._flight:
+            rnd.slots.difference_update(slots)
+
+    def _most(self, ticks: int) -> int:
+        """The most tokens a slot can finish in a round of `ticks`."""
+        return ticks
+
+    def _flying(self, slot: int) -> int:
+        """The ticks of the rounds in flight that serve the slot's
+        request."""
+        return sum(rnd.ticks for rnd in self._flight if slot in rnd.slots)
+
+    def _left(self, slot: int) -> int:
+        """The least the slot's request can have to go when the next
+        round starts: its mirror, less the most the rounds in flight can
+        take (exactly what they take, a token a tick)."""
+        return max(int(self.rem[slot]) - self._most(self._flying(slot)), 0)
+
     def ticks(self, owners) -> int:
-        # the host's mirror: last readback, req for fresh installs
-        return (self.FUSE if all(int(self.rem[s_]) >= self.fuse_tokens
+        return (self.FUSE if all(self._left(s_) >= self.fuse_tokens
                                  for s_ in owners) else 1)
 
     def writes(self, slot: int, r, ticks: int) -> tuple:
-        start = int(self.pos[slot])
+        # the rounds in flight moved the slot on a position a token; one
+        # that finished there idles, and its ticks write only its
+        # frozen position (past the answer's end: its own last page or
+        # the trash page)
+        left = self._left(slot)
+        start = int(self.pos[slot]) + int(self.rem[slot]) - left
+        end = start + min(ticks, left)
         # walked: the pages that hold what a query at `pos` sees behind
         # r.pad positions of left padding, the range `_decode_paged` hands
         # the paged attention kernel (models/transformer.py); where the
         # layers' windows differ, once a window
         for kind, window in self._walks:
             pages = 0
-            for pos in range(start, start + ticks):
+            for pos in range(start, end):
                 first = max(r.pad, pos - window + 1) if window else r.pad
                 pages += pos // self.page_size - first // self.page_size + 1
             self._walked += pages
             self._walked_window += kind * pages
-        return start, start + ticks
+        return start, end
 
     def dispatch(self, owners, ticks: int, table) -> None:
-        self._tabled = 0 if table is None else ticks * len(self._walks) * (
-            table[0] if self.two_kinds else table).size
-        self._ticks = ticks
-        self.state = (self._step_fused if ticks > 1 else self._step)(
+        n = len(self.state)
+        handed = (self._step_fused if ticks > 1 else self._step)(
             self.params, self.state, *(() if table is None else (table,)))
+        self.state, (report, answers) = tuple(handed[:n]), handed[n:]
+        report.copy_to_host_async()
+        self._flight.append(_Round(owners, ticks, report, answers,
+                                   self._host_counts(ticks, table),
+                                   self._rung_counts))
+        self._rung_counts = []
 
-    def readback(self, owners) -> tuple:
-        # the host blocks here until the device has caught up
-        remaining = np.asarray(self.state[3])
-        # the tokens of answers the round fixed: the fall of `remaining`
-        # against the mirror as it stood at the dispatch (a slot without
-        # an owner stands at 0 in both)
-        decoded = int((self.rem - remaining).sum())
-        # writable copies: admission writes fresh slots' mirrors
-        self.rem = np.array(remaining)
-        self.pos = np.array(self.state[2])
-        done = [s_ for s_ in owners if remaining[s_] <= 0]
-        # one readback of the tokens per round, and only where a slot
-        # finished
-        self._out = np.asarray(self.state[4]) if done else None
-        return done, dict(self._counts(), tokens_decoded=decoded)
-
-    def _counts(self) -> dict:
-        """How much of the page table the round's ticks walked (one tick
+    def _host_counts(self, ticks: int, table) -> dict:
+        """How much of the page table the round's ticks walk (one tick
         = one query a slot): the pages that hold what each active slot's
         query sees, beside ticks x every entry of the table, which is
-        what gathering the table touches."""
-        walked, self._walked = self._walked, 0
-        counts = ({"kv_pages_walked": walked, "kv_pages_tabled": self._tabled}
-                  if self.mp else {})
+        what gathering the table touches; counted by `writes`."""
+        counts = {}
+        if self.mp:
+            counts = {"kv_pages_walked": self._walked,
+                      "kv_pages_tabled": ticks * len(self._walks) * (
+                          table[0] if self.two_kinds else table).size}
         if self.two_kinds:      # of `walked`, the window kind's table's part
             counts["kv_pages_walked_window"] = self._walked_window
-            self._walked_window = 0
-        if self.counted:    # the round's counts, from the same read-back
-            counts.update(zip(self.counted,
-                              np.asarray(self.state[8]).tolist()))
-        counts.update(self._rungs_read())
+        self._walked = self._walked_window = 0
         if self.latent_row_bytes:   # of the round's ticks, the kernel's
             counts["attn_latent_kernel_ticks"] = (
-                self._ticks if self._latent_kernel else 0)
+                ticks if self._latent_kernel else 0)
         return counts
 
-    def _rungs_read(self) -> dict:
-        """RUNG_COUNTERS of the rungs admitted since the last read-back,
-        whose programs the round's ticks ran behind."""
-        rungs, self._rung_counts = self._rung_counts, []
+    def readback(self, owners) -> tuple:
+        rnd = self._flight.popleft()
+        # the round's one transfer, started at its dispatch: the host
+        # blocks here until the device has caught up
+        report = np.asarray(rnd.report)
+        S = self.S
+        remaining, pos = report[:S], report[S:2 * S]
+        # the slots whose requests the round served and still hold them
+        held = [s_ for s_ in owners if s_ in rnd.slots]
+        self.rem[held], self.pos[held] = remaining[held], pos[held]
+        done = [s_ for s_ in held if remaining[s_] <= 0]
+        # the tokens are read in a round where a slot finished, only
+        self._out = np.asarray(rnd.answers) if done else None
+        self._served(held, rnd.ticks)
+        counts = dict(rnd.counts, tokens_decoded=int(report[2 * S]))
+        counts.update(zip(self._device_counted,
+                          report[2 * S + 1:].tolist()))
+        counts.update(self._rungs_read(rnd.rungs))
+        return done, counts
+
+    @property
+    def _device_counted(self) -> tuple:
+        return self.counted
+
+    def _served(self, slots, ticks: int) -> None:
+        """The read-back found these slots' requests were served by a
+        round of `ticks` (they were active at its start)."""
+
+    def _rungs_read(self, rungs) -> dict:
+        """RUNG_COUNTERS of the rungs admitted before a round's
+        dispatch, whose programs it ran behind."""
         total = np.zeros(len(self.rung_counted), np.int64)
         for counts in rungs:
             total += np.asarray(counts)
@@ -529,6 +606,23 @@ class TokenStep:
         self.state = self._clear_slots(self.state,
                                        jnp.asarray(slots, jnp.int32))
         self.rem[list(slots)] = 0
+        self._leave(slots)
+
+
+class _Round:
+    """A round from its dispatch to its read-back: the slots whose
+    requests it serves (`_leave` takes out those that go before it is
+    read), its ticks, what its program handed out beside the state (the
+    report, whose transfer started at the dispatch, and the answers'
+    tokens), what the host counted of it, and the counts of the rungs
+    admitted before it."""
+
+    __slots__ = ("slots", "ticks", "report", "answers", "counts", "rungs")
+
+    def __init__(self, owners, ticks, report, answers, counts, rungs):
+        self.slots = set(owners)
+        self.ticks, self.report, self.answers = ticks, report, answers
+        self.counts, self.rungs = counts, rungs
 
 
 class BlockStep(TokenStep):
@@ -667,30 +761,43 @@ class BlockStep(TokenStep):
         self.passes[slot] = 0
         return ((jnp.asarray(toks), jnp.int32(tail)),), self.P - tail
 
+    def _most(self, ticks: int) -> int:
+        # a block is a denoising pass at the least and a committing one:
+        # every other pass can commit B tokens
+        return self.B * -(-ticks // 2)
+
     def writes(self, slot: int, r, ticks: int) -> tuple:
         # a pass writes its block's B positions, and a round of `ticks`
         # passes can commit every other pass: the pages are there
-        # before a block's first pass
-        self.passes[slot] += ticks
+        # before a block's first pass, and before that of every block
+        # the rounds in flight may have opened
         start = int(self.pos[slot])
-        return start, min(start + self.B * (1 + ticks // 2), self.end(r))
+        ahead = self._most(self._flying(slot))
+        return start, min(start + ahead + self.B * (1 + ticks // 2),
+                          self.end(r))
 
-    def _counts(self) -> dict:
-        # the round's counts, from the same read-back
-        return dict(zip(BLOCK_COUNTERS,
-                        np.asarray(self.state[1]["ctr"]).tolist()),
-                    kv_pages_tabled=self._tabled, **self._rungs_read())
+    def _device_counts(self, state):
+        return state[1]["ctr"]
 
-    def readback(self, owners) -> tuple:
-        done, counts = super().readback(owners)
-        self._out_at = (np.asarray(self.state[1]["out_at"]) if done
-                        else None)
-        return done, counts
+    @property
+    def _device_counted(self) -> tuple:
+        return BLOCK_COUNTERS
+
+    def _answers(self, state):
+        # the tokens and the step each was fixed at, in one buffer
+        return jnp.stack([state[4], state[1]["out_at"]])
+
+    def _host_counts(self, ticks: int, table) -> dict:
+        # (the pages a pass walks are counted on the device)
+        return {"kv_pages_tabled": ticks * len(self._walks) * table.size}
+
+    def _served(self, slots, ticks: int) -> None:
+        for s_ in slots:
+            self.passes[s_] += ticks
 
     def answer(self, slot: int, r) -> tuple:
-        tokens, _ = super().answer(slot, r)
-        return ({"tokens": tokens,
-                 "fixed_at": [int(t) for t in self._out_at[slot][:r.req]]},
+        tokens, fixed_at = self._out[:, slot, :r.req].tolist()
+        return ({"tokens": tokens, "fixed_at": fixed_at},
                 {"blocks": -(-(self._tail(r) + r.req) // self.B),
                  "passes": int(self.passes[slot])})
 
@@ -701,6 +808,10 @@ class SpecStep:
     chunk in ONE [S, k+1] forward. State: the target's cache (dense or
     paged) and the draft's dense one; the positions, budgets and tokens
     live on the host, which reads every round's proposals and targets."""
+
+    # the verify chunk is built from what the host reads of the round
+    # before: each round is read back before the next is dispatched
+    runs_ahead = False
 
     def __init__(self, model, params, draft, d_params, k: int,
                  slots: int, prompt_len: int, max_new_tokens: int,

@@ -430,7 +430,9 @@ STATS_KEYS = {
     "speculative",
     # (what the loop counts of every pass came with PR 37)
     "ticks", "tokens_decoded", "rounds.plain", "round_s.plain",
-    "rounds.fused", "round_s.fused", "rounds.other", "round_s.other"}
+    "rounds.fused", "round_s.fused", "rounds.other", "round_s.other",
+    # (the rounds dispatched while the one before was unread)
+    "rounds_ahead"}
 STATS_KEYS_PAGED = {
     "cow_clones", "kv_page_size", "kv_pages_free", "kv_pages_tabled",
     "kv_pages_total", "kv_pages_used", "kv_pages_walked", "prefill_shapes",
@@ -592,6 +594,263 @@ def test_a_canceled_requests_tokens_stay_counted():
         dec.close()
     assert st["deadline_canceled"] == 1 and st["completed"] == 0
     assert st["tokens_decoded"] == st["ticks"] == 3
+
+
+# -- a round in flight: the loop dispatches before it reads back ------------
+
+# (prompt, tokens asked for): ten requests on three slots, budgets from
+# one token to a fused round and more, prompts that share their first
+# page, so that slots finish inside the rounds in flight, are admitted
+# again while one is in flight, and hit each other's prompt pages
+AHEAD_REQUESTS = [([1, 2, 3, 4, 5], 12), ([1, 2, 3, 4, 6, 7], 3),
+                  ([1, 2, 3, 4], 1), ([9, 8, 7], 9), ([1, 2, 3, 4, 5, 6], 5),
+                  ([2], 12), ([1, 2, 3, 4, 9], 2), ([5, 5, 5, 5, 5], 10),
+                  ([7, 1], 4), ([1, 2, 3, 4, 5, 7, 7], 8)]
+# a decoder that runs ahead, by what it keeps: the one-token step with
+# the prefix cache on, the one-token step with the window kind's pages,
+# the block step
+AHEAD_MODES = ("token-prefix", "token-window", "block")
+
+
+def ahead_decoder(mode: str, **kw):
+    import dataclasses
+
+    from conftest import _toy_lm
+
+    from kubeflow_tpu.serving.continuous import SlotDecoder, window_pages_for
+
+    if mode != "token-window":
+        return slot_decoder("token-paged" if mode == "token-prefix" else mode,
+                            slots=3, max_new_tokens=12, **kw)
+    model, variables = _toy_lm(kv_pages=41, kv_page_size=4,
+                               attention_window=6)
+    model = model.clone(cfg=dataclasses.replace(
+        model.cfg, kv_window_pages=window_pages_for(
+            model.cfg, 3, 8, 12, prefix_cache=False)))
+    dec = SlotDecoder(model, variables, slots=3, prompt_len=8,
+                      max_new_tokens=12, prefix_cache=False, **kw)
+    assert dec.alloc.window == 6
+    return dec
+
+
+def wait_until(what, timeout=30.0) -> bool:
+    import time
+
+    t_end = time.monotonic() + timeout
+    while not what() and time.monotonic() < t_end:
+        time.sleep(0.005)
+    return what()
+
+
+def serve_at_once(dec, requests=AHEAD_REQUESTS) -> list:
+    """Every request submitted at once, each from its own thread: the
+    answers in the requests' order."""
+    got = [None] * len(requests)
+
+    def go(i, prompt, n):
+        try:
+            got[i] = dec.submit(prompt, max_new=n)
+        except Exception as e:  # pragma: no cover
+            got[i] = e
+
+    threads = [threading.Thread(target=go, args=(i, p, n))
+               for i, (p, n) in enumerate(requests)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not any(t.is_alive() for t in threads)
+    return got
+
+
+@pytest.mark.parametrize("mode", AHEAD_MODES)
+def test_running_ahead_answers_as_the_serial_loop(mode):
+    """A full decoder of a kind that runs ahead dispatches round n+1
+    before it reads round n back. Its answers (a block model's
+    `fixed_at` among them) are the serial loop's token for token, with
+    slots that finished inside a round in flight; the pages all come
+    back; the classes' rounds add up to `rounds` and their seconds to
+    the busy phases'."""
+    got, stats = {}, {}
+    for ahead in (True, False):
+        dec = ahead_decoder(mode)
+        if not ahead:        # the loop as it was: every round read back
+            dec.step.runs_ahead = False
+        finished_in_flight, real = [], dec.step.readback
+
+        def readback(owners, real=real, dec=dec, seen=finished_in_flight):
+            done, counts = real(owners)
+            seen.append(bool(done) and bool(dec.step._flight))
+            return done, counts
+
+        dec.step.readback = readback
+        try:
+            got[ahead] = serve_at_once(dec)
+            stats[ahead] = st = dec.stats()
+            dec.alloc.check()
+        finally:
+            dec.close()
+        assert (st["rounds_ahead"] > 0) == ahead
+        assert any(finished_in_flight) == ahead
+        assert st["completed"] == len(AHEAD_REQUESTS)
+        assert st["tokens_decoded"] == sum(n for _, n in AHEAD_REQUESTS)
+        classes = round_classes(st)
+        assert sum(n for n, _ in classes.values()) == st["rounds"]
+        busy = sum(st[f"phase_s.{p}"] for p in BUSY_PHASES)
+        assert sum(s for _, s in classes.values()) == pytest.approx(
+            busy, rel=0.02, abs=2e-3)
+    assert got[True] == got[False]
+    assert [len(answer_tokens(a)) for a in got[True]] == [
+        n for _, n in AHEAD_REQUESTS]
+    if mode == "token-prefix":
+        assert stats[True]["prefix_hit_pages"] > 0
+    if mode == "token-window":
+        assert stats[True]["kv_window_pages_released"] > 0
+
+
+@pytest.mark.parametrize("mode", ["spec-dense", "spec-paged"])
+def test_a_speculative_decoder_never_runs_ahead(mode):
+    """Its verify chunk is built from the round before's read-back."""
+    dec = slot_decoder(mode, slots=2, max_new_tokens=6)
+    try:
+        got = serve_at_once(dec, [(p, min(n, 6)) for p, n in AHEAD_REQUESTS])
+        st = dec.stats()
+    finally:
+        dec.close()
+    assert all(isinstance(a, list) for a in got)
+    assert st["rounds"] > 0 and st["rounds_ahead"] == 0
+
+
+@pytest.mark.parametrize("mode", ["token-paged", "block"])
+def test_a_decoder_with_a_free_slot_never_runs_ahead(mode):
+    """Two requests at a time on four slots: a waiter could enter sooner
+    than a round in flight would let it, so every round is read back
+    before the next is dispatched."""
+    dec = slot_decoder(mode, slots=4, max_new_tokens=12)
+    try:
+        for pair in zip(AHEAD_REQUESTS[::2], AHEAD_REQUESTS[1::2]):
+            serve_at_once(dec, list(pair))
+        st = dec.stats()
+    finally:
+        dec.close()
+    assert st["completed"] == len(AHEAD_REQUESTS)
+    assert st["rounds"] > 0 and st["rounds_ahead"] == 0
+
+
+def test_a_deadline_that_passes_with_a_round_in_flight_cancels_cleanly():
+    """The slot is cancelled at the top of a pass while the round before
+    is still unread: its waiter gets DeadlineExceeded, the others their
+    answers, and every page comes back."""
+    from kubeflow_tpu.serving.router import DeadlineExceeded
+
+    now = [0.0]
+    dec = slot_decoder("token-paged", slots=2, max_new_tokens=12,
+                       clock=lambda: now[0])
+    real_readback, real_cancel = dec.step.readback, dec.step.cancel
+    in_flight = []
+
+    def readback(owners):
+        now[0] += 1.0
+        return real_readback(owners)
+
+    def cancel(slots):
+        in_flight.append(bool(dec.step._flight))
+        real_cancel(slots)
+
+    dec.step.readback, dec.step.cancel = readback, cancel
+    want = {}
+    try:
+        ref = slot_decoder("token-paged", slots=2, max_new_tokens=12)
+        try:
+            for p, n in AHEAD_REQUESTS[3:6]:
+                want[tuple(p)] = ref.submit(p, max_new=n)
+        finally:
+            ref.close()
+        caught, got = [], {}
+
+        def go(p, n, deadline=None):
+            try:
+                got[tuple(p)] = dec.submit(p, max_new=n, deadline=deadline)
+            except DeadlineExceeded as e:
+                caught.append(e)
+
+        # admitted first, it would still be decoding when the clock
+        # passes its deadline
+        threads = [threading.Thread(target=go, args=([3, 1, 4], 12, 3.5))]
+        threads[0].start()
+        assert wait_until(lambda: dec.stats()["admitted"] == 1)
+        threads += [threading.Thread(target=go, args=(p, n))
+                    for p, n in AHEAD_REQUESTS[3:6]]
+        for t in threads[1:]:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        st = dec.stats()
+        dec.alloc.check()
+    finally:
+        dec.close()
+    assert len(caught) == 1 and got == want
+    assert st["deadline_canceled"] == 1 and st["rounds_ahead"] > 0
+    assert True in in_flight
+    assert st["kv_pages_free"] + st["kv_pages_used"] == st["kv_pages_total"]
+
+
+def test_a_round_in_flight_writes_no_page_its_predecessors_read_back_gives():
+    """Where a read-back completes a slot while the next round is in
+    flight, the pages it gives away (back to the pool, or kept as the
+    prefix cache's) are written by that round only where the completed
+    slot idles: its frozen position, past its answer's end. No prompt
+    page the prefix cache keeps is written by a round in flight."""
+    dec = slot_decoder("token-paged", slots=3, max_new_tokens=12)
+    ps, writes, rounds = dec.page_size, {}, {}
+    # the last page of a row holds an index past it (the device clamps)
+    last, prompt_pages = dec.alloc.table.shape[1] - 1, dec.P // ps
+    real_writes, real_dispatch = dec.step.writes, dec.step.dispatch
+    real_complete = dec._complete
+    checked = []
+
+    def spy_writes(slot, r, ticks):
+        start, end = real_writes(slot, r, ticks)
+        # an idle slot's ticks write its frozen position
+        writes[slot] = (range(start, end) if end > start else [start],
+                        end == start, r)
+        return start, end
+
+    def spy_dispatch(owners, ticks, table):
+        real_dispatch(owners, ticks, table)
+        table = np.asarray(table)
+        rounds[id(dec.step._flight[-1])] = {
+            (int(table[s_, min(p // ps, last)]), s_, p, idle, r)
+            for s_, (at, idle, r) in writes.items() for p in at}
+        writes.clear()
+
+    def spy_complete(owners, slot):
+        r, a = owners[slot], dec.alloc
+        free_before = set(a._free)
+        real_complete(owners, slot)
+        given = (set(a._free) - free_before) | set(a._page_key)
+        for rnd in dec.step._flight:        # the rounds still in flight
+            for page, s_, p, idle, r_ in rounds[id(rnd)]:
+                assert page not in a._page_key, (page, s_, p)
+                if page in given:
+                    # ... and in a page of the completed slot's answer
+                    assert s_ == slot and r_ is r and idle \
+                        and p >= dec.step.end(r) \
+                        and min(p // ps, last) >= prompt_pages, (page, s_, p)
+                    checked.append(page)
+
+    dec.step.writes, dec.step.dispatch = spy_writes, spy_dispatch
+    dec._complete = spy_complete
+    try:
+        got = serve_at_once(dec)
+        st = dec.stats()
+        dec.alloc.check()
+    finally:
+        dec.close()
+    assert [len(a) for a in got] == [n for _, n in AHEAD_REQUESTS]
+    assert st["rounds_ahead"] > 0 and st["prefix_hit_pages"] > 0
+    # the idle writes did land in given pages: the check had work
+    assert checked
 
 
 class TestPerRequestBudgets:

@@ -328,9 +328,18 @@ class TestDecoderPhases:
                 phase, sorted(n for n in names if "kftpu" in n))
 
 
-def profiled_spans(since: int = 0):
-    return [s for s in tr.COLLECTOR.spans()[since:]
-            if s.name == "serve.profiled"]
+def span_mark():
+    """The collector's newest span (None while it is empty). Where an
+    index would stop marking anything once the collector's ring is full
+    and its oldest spans age out, the span itself still does."""
+    spans = tr.COLLECTOR.spans()
+    return spans[-1] if spans else None
+
+
+def profiled_spans(since=None):
+    spans = tr.COLLECTOR.spans()
+    at = next((i for i, s in enumerate(spans) if s is since), -1)
+    return [s for s in spans[at + 1:] if s.name == "serve.profiled"]
 
 
 def wait_for(what, timeout=10.0):
@@ -353,7 +362,7 @@ class TestProfiledStretch:
         dec = paged_decoder(lm)
         try:
             dec.submit([1, 2, 3])            # compile outside the session
-            mark = len(tr.COLLECTOR.spans())
+            mark = span_mark()
             before = dec.stats()
             t_before = tr._EPOCH + time.perf_counter()
             jax.profiler.start_trace(str(tmp_path))
@@ -408,7 +417,7 @@ class TestProfiledStretch:
 
         dec.step.dispatch = dispatch
         try:
-            mark = len(tr.COLLECTOR.spans())
+            mark = span_mark()
             threads = [threading.Thread(
                 target=lambda p=p: dec.submit(p, max_new=6))
                 for p in ([1, 2, 3], [4, 5], [6, 7, 8, 9], [2, 4, 6])]
@@ -425,7 +434,7 @@ class TestProfiledStretch:
         assert 0 <= span.attrs["admitted"] <= 4
 
     def test_no_session_no_span(self, lm):
-        mark = len(tr.COLLECTOR.spans())
+        mark = span_mark()
         dec = paged_decoder(lm)
         try:
             dec.submit([1, 2, 3])
@@ -435,7 +444,7 @@ class TestProfiledStretch:
         assert profiled_spans(mark) == []
 
     def test_a_stretch_open_at_close_is_recorded(self, lm, recorder):
-        mark = len(tr.COLLECTOR.spans())
+        mark = span_mark()
         dec = paged_decoder(lm)
         try:
             recorder.enabled = True
@@ -451,7 +460,7 @@ class TestProfiledStretch:
         from kubeflow_tpu.serving.continuous import SlotDecoder
 
         model, variables = lm
-        mark = len(tr.COLLECTOR.spans())
+        mark = span_mark()
         dec = SlotDecoder(model, variables, slots=2, prompt_len=8,
                           max_new_tokens=4, draft_model=model,
                           draft_variables=variables, draft_k=2)
